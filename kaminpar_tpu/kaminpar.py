@@ -25,6 +25,7 @@ from .presets import create_context_by_preset_name
 from .utils import rng as rng_mod
 from .utils import timer
 from .utils.logger import OutputLevel, log, set_output_level
+from .utils.platform import configure_compile_cache
 
 
 class KaMinPar:
@@ -38,6 +39,8 @@ class KaMinPar:
     """
 
     def __init__(self, ctx: Union[Context, str, None] = None):
+        # before the first compile of any run this instance starts
+        configure_compile_cache()
         if ctx is None:
             ctx = create_context_by_preset_name("default")
         elif isinstance(ctx, str):

@@ -42,11 +42,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-# version-portable shard_map (mesh.shard_map_compat): the
-# replication-check flag is spelled check_vma / check_rep depending on
-# the installed jax — the compat shim keeps every dist kernel usable on
-# both instead of dying with a TypeError at the first collective
-from .mesh import shard_map_compat as _shard_map
+from jax import shard_map as _shard_map
 
 from ..graphs.host import HostGraph
 from ..ops.segments import ACC_DTYPE, aggregate_by_key, hash_u32

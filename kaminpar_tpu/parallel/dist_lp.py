@@ -39,11 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-# version-portable shard_map (mesh.shard_map_compat): the
-# replication-check flag is spelled check_vma / check_rep depending on
-# the installed jax — the compat shim keeps every dist kernel usable on
-# both instead of dying with a TypeError at the first collective
-from .mesh import shard_map_compat as _shard_map
+from jax import shard_map as _shard_map
 
 from ..ops.lp import LPConfig
 from ..telemetry import progress as progress_mod
@@ -271,9 +267,7 @@ def _dist_lp_round(
         # the convergence count/active set.
         # NOTE: INT32_MIN must stay the module-level import — a local
         # re-import here would shadow it for the WHOLE function and
-        # break the scatter engine's earlier use (UnboundLocalError,
-        # surfaced once the shard_map compat shim made this path
-        # reachable on check_rep-era jax)
+        # break the scatter engine's earlier use (UnboundLocalError)
         from ..ops.segments import afterburner_filter
 
         gain_cand_l = jnp.where(target_l >= 0, gain, INT32_MIN)

@@ -49,6 +49,7 @@ from .dist_contraction import dist_contract_clustering
 from ..ops.segments import MAX_FUSED_EDGE_SLOTS
 from ..utils import timer
 from ..utils.logger import log
+from ..utils.platform import configure_compile_cache
 from .dist_context import (
     DistContext,
     create_dist_clusterer,
@@ -74,6 +75,8 @@ class dKaMinPar:
         mesh: Optional[Mesh] = None,
         n_devices: Optional[int] = None,
     ):
+        # before the first compile of any run this instance starts
+        configure_compile_cache()
         if ctx is None:
             ctx = create_dist_context_by_preset_name("default")
         elif isinstance(ctx, str):

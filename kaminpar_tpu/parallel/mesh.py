@@ -35,49 +35,6 @@ NODE_AXIS_Y = "nodes_y"
 NODE_AXIS = (NODE_AXIS_X, NODE_AXIS_Y)
 
 
-def _resolve_shard_map():
-    """``shard_map`` plus the name of its replication-check flag across
-    jax versions: ``check_vma`` (new), ``check_rep`` (older), or None
-    (oldest — no flag at all).  Before this shim every dist kernel
-    passed ``check_vma=False`` unconditionally, so on a check_rep-era
-    jax the ENTIRE dist pipeline died with a TypeError at the first
-    collective — the seed's documented env-failure class, and exactly
-    the kind of avoidable hard failure the resilience layer exists to
-    remove."""
-    try:  # jax >= 0.6 exposes shard_map at top level
-        from jax import shard_map as sm
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as sm
-    import inspect
-
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):  # pragma: no cover
-        params = {}
-    if "check_vma" in params:
-        flag = "check_vma"
-    elif "check_rep" in params:
-        flag = "check_rep"
-    else:  # pragma: no cover
-        flag = None
-    return sm, flag
-
-
-_SHARD_MAP, _SHARD_MAP_FLAG = _resolve_shard_map()
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma=False):
-    """Version-portable ``shard_map``: the dist kernels always disable
-    the replication check (their psum'd scalars are replicated by
-    construction and the check costs trace time), and this wrapper
-    spells the flag however the installed jax does."""
-    kwargs = {}
-    if _SHARD_MAP_FLAG is not None:
-        kwargs[_SHARD_MAP_FLAG] = check_vma
-    return _SHARD_MAP(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
-
 # --- communication accounting -------------------------------------------
 #
 # A static per-phase model of the collective traffic (the dist layer's

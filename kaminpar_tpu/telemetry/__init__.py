@@ -113,30 +113,19 @@ def enabled() -> bool:
 def enable() -> None:
     global _enabled
     _enabled = True
-    # compile-cost accounting listens on jax.monitoring; installation is
-    # idempotent and the listeners no-op while telemetry is disabled
-    try:
-        from . import compile_account
+    # The three accountings hook jax at install time: compile-cost
+    # accounting listens on jax.monitoring, the perf observatory wraps
+    # the backend-compile boundary and the execution ledger the
+    # executable-call boundary.  Each install is idempotent and its
+    # hooks pass straight through while telemetry is disabled (or
+    # KAMINPAR_TPU_PERF=0 / KAMINPAR_TPU_LEDGER=0).  A hook that no
+    # longer fits the installed jax raises here rather than leaving an
+    # empty section in the report.
+    from . import compile_account, ledger, perf
 
-        compile_account.install()
-    except Exception:
-        pass
-    # the perf observatory hooks the backend-compile boundary the same
-    # way (idempotent, no-op while disabled / KAMINPAR_TPU_PERF=0)
-    try:
-        from . import perf
-
-        perf.install()
-    except Exception:
-        pass
-    # the execution ledger hooks the executable-call boundary
-    # (idempotent, no-op while disabled / KAMINPAR_TPU_LEDGER=0)
-    try:
-        from . import ledger
-
-        ledger.install()
-    except Exception:
-        pass
+    compile_account.install()
+    perf.install()
+    ledger.install()
 
 
 def disable() -> None:

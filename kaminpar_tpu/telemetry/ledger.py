@@ -126,58 +126,40 @@ def reset() -> None:
 
 
 def install() -> None:
-    """Wrap the compiled-executable call boundary (idempotent; the
-    wrappers no-op while the ledger is disabled, so installation is
-    free).  Best effort: a jax refactor that moves either entry point
-    degrades to "launch counts unavailable", never an import error."""
+    """Wrap jax 0.9.0's compiled-executable call boundary,
+    ``pxla.ExecuteReplicated.__call__``, and gate the C++ pjit fastpath
+    (``jax._src.pjit._get_fastpath_data``) while the ledger is armed
+    (idempotent; both wrappers pass straight through while the ledger
+    is disabled, so installation is free).  Both names are private to
+    jax: an installation that moved either raises here, at
+    ``telemetry.enable()``, instead of reporting zero launches."""
     global _installed
     if _installed:
         return
-    try:
-        from jax._src.interpreters import pxla
-    except Exception:
-        return
-    orig_call = getattr(pxla.ExecuteReplicated, "__call__", None)
-    if orig_call is None or getattr(
-        orig_call, "_kaminpar_ledger_wrapped", False
-    ):
-        _installed = True
-        return
+    from jax._src import pjit as _pjit
+    from jax._src.interpreters import pxla
+
+    orig_call = pxla.ExecuteReplicated.__call__
 
     def _wrapped_call(self, *args: Any, **kwargs: Any):
-        try:
-            if enabled():
-                _record_launch(getattr(self, "xla_executable", None))
-        except Exception:
-            pass  # the ledger must never break a dispatch
+        if enabled():
+            _record_launch(self.xla_executable)
         return orig_call(self, *args, **kwargs)
 
-    _wrapped_call._kaminpar_ledger_wrapped = True  # type: ignore[attr-defined]
     pxla.ExecuteReplicated.__call__ = _wrapped_call
 
     # Warm pjit calls are dispatched from C++ and never reach the
     # Python wrapper above; returning None here keeps the fastpath
     # uncached so every dispatch stays countable while the ledger is
     # armed.  Disabled, the original fastpath is untouched.
-    try:
-        from jax._src import pjit as _pjit
+    orig_fastpath = _pjit._get_fastpath_data
 
-        orig_fastpath = getattr(_pjit, "_get_fastpath_data", None)
-        if orig_fastpath is not None and not getattr(
-            orig_fastpath, "_kaminpar_ledger_wrapped", False
-        ):
-            def _gated_fastpath(*args: Any, **kwargs: Any):
-                try:
-                    if enabled():
-                        return None
-                except Exception:
-                    pass
-                return orig_fastpath(*args, **kwargs)
+    def _gated_fastpath(*args: Any, **kwargs: Any):
+        if enabled():
+            return None
+        return orig_fastpath(*args, **kwargs)
 
-            _gated_fastpath._kaminpar_ledger_wrapped = True  # type: ignore[attr-defined]
-            _pjit._get_fastpath_data = _gated_fastpath
-    except Exception:
-        pass
+    _pjit._get_fastpath_data = _gated_fastpath
     _installed = True
 
 

@@ -75,14 +75,10 @@ def _compile_section() -> dict:
 def _perf_section(levels, perf_ranks=None) -> dict:
     """Schema v5 `perf` section: roofline rows, memory watermarks (with
     the per-level CSR buffer accounting folded in), pad-waste rows.
-    Well-formed disabled default when the observatory is unavailable."""
-    try:
-        from . import perf
+    Raises perf.UnknownDeviceError on a device without published peaks."""
+    from . import perf
 
-        section = perf.snapshot()
-    except Exception:
-        return {"enabled": False,
-                "caveat": "perf observatory unavailable"}
+    section = perf.snapshot()
     mem = section.setdefault("memory", {})
     # per-level resident CSR/partition buffer bytes, from the
     # coarsener's level events (host-side metadata, never a device pull)

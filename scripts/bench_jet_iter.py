@@ -3,7 +3,8 @@
 import os, sys, time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
+from kaminpar_tpu.utils.platform import configure_compile_cache
+configure_compile_cache()
 import jax.numpy as jnp
 import numpy as np
 from kaminpar_tpu.graphs.csr import device_graph_from_host

@@ -32,7 +32,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
+from kaminpar_tpu.telemetry.perf import device_peaks
+from kaminpar_tpu.utils.platform import configure_compile_cache
+
+configure_compile_cache()
 
 import jax.numpy as jnp
 import numpy as np
@@ -42,7 +45,7 @@ LOG_N = int(sys.argv[2]) if len(sys.argv) > 2 else 20
 M = 1 << LOG_M
 N = 1 << LOG_N
 REPS = 4
-HBM_PEAK_GBS = 819.0  # v5e single core
+HBM_PEAK_GBS = device_peaks(jax.devices()[0].device_kind)[0]
 
 
 def timeit(name, fn, useful_bytes, *args):

@@ -30,9 +30,13 @@ import os
 if len(sys.argv) > 1 and sys.argv[1] == "cpu":
     os.environ["JAX_PLATFORMS"] = "cpu"
 
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
+from kaminpar_tpu.utils.platform import configure_compile_cache
+
+configure_compile_cache()
 
 import jax.numpy as jnp
 import numpy as np

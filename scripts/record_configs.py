@@ -24,7 +24,6 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/root/repo/.jax_cache")
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "docs",
                    "recorded_configs.jsonl")

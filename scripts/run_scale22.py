@@ -3,8 +3,6 @@
 Usage: python scripts/run_scale22.py [reps]"""
 import os, sys, time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import jax
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
 import numpy as np
 
 reps = int(sys.argv[1]) if len(sys.argv) > 1 else 1

@@ -542,9 +542,7 @@ def test_halo_exchange_delivers_ghost_labels(n_devices):
     from jax.sharding import PartitionSpec as P
 
     from kaminpar_tpu.parallel.mesh import halo_exchange
-    # the version-portable shim (check_vma vs check_rep) the dist
-    # kernels route through
-    from kaminpar_tpu.parallel.mesh import shard_map_compat as shard_map_fn
+    from jax import shard_map as shard_map_fn
 
     host = make_rmat(1 << 10, 8_000, seed=17)
     mesh = make_mesh(n_devices)

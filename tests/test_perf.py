@@ -197,6 +197,14 @@ def test_peaks_env_override(monkeypatch):
     assert p["gbps"] > 0 and p["gflops"] > 0
 
 
+def test_peaks_come_from_the_device_kind_table():
+    """One table keyed by device_kind; an unlisted device is an error,
+    never a default."""
+    assert perf.device_peaks("TPU v5 lite") == (819.0, 197_000.0)
+    with pytest.raises(perf.UnknownDeviceError, match="TPU v9"):
+        perf.device_peaks("TPU v9")
+
+
 # ---------------------------------------------------------------------------
 # pad-waste attribution
 # ---------------------------------------------------------------------------

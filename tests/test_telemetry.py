@@ -35,8 +35,14 @@ def _load_checker():
 
 @pytest.fixture(autouse=True)
 def _clean_telemetry():
+    from kaminpar_tpu.telemetry import tracing
+
     telemetry.disable()
     telemetry.reset()
+    # request traces outlive telemetry.reset() by design (the serving
+    # layer owns their lifetime); earlier serving test modules leave
+    # some behind, and the exporters below would render them
+    tracing.reset_traces()
     yield
     telemetry.disable()
     telemetry.reset()
