@@ -1,0 +1,14 @@
+"""Sum of the jet timer nodes under partitioning, median over the run's
+untraced partitions."""
+
+from perfbench.harness import timer_tree
+
+LAYER = "refinement"
+UNIT = "s"
+MOVES = "partition_s"
+SOURCE = "program_span"
+CELLS = None  # every cell
+
+
+def read(run):
+    return timer_tree.median_total(run["trees"], ("jet",))
