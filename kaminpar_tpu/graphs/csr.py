@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..caching import pad_size
+from ..utils.timer import scoped_timer
 from .host import HostGraph
 
 from ..dtypes import ACC_DTYPE, WEIGHT_DTYPE  # int64 under
@@ -263,12 +264,15 @@ def host_graph_from_device(graph: DeviceGraph) -> HostGraph:
     """Download a DeviceGraph back into a compact HostGraph (DLPack-free copy;
     used when the coarsest graph moves to the CPU initial partitioner, per
     BASELINE.json's north star)."""
-    n = int(graph.n)
-    m = int(graph.m)
-    xadj = np.asarray(graph.row_ptr[: n + 1], dtype=np.int64)
-    adjncy = np.asarray(graph.dst[:m], dtype=np.int32)
-    edge_w = np.asarray(graph.edge_w[:m], dtype=np.int64)
-    node_w = np.asarray(graph.node_w[:n], dtype=np.int64)
+    # a readback scope of its own: the host blocks on the device here,
+    # whichever phase asks for the graph
+    with scoped_timer("graph-download", sync=True):
+        n = int(graph.n)
+        m = int(graph.m)
+        xadj = np.asarray(graph.row_ptr[: n + 1], dtype=np.int64)
+        adjncy = np.asarray(graph.dst[:m], dtype=np.int32)
+        edge_w = np.asarray(graph.edge_w[:m], dtype=np.int64)
+        node_w = np.asarray(graph.node_w[:n], dtype=np.int64)
     from ..caching import record_transfer
 
     record_transfer(

@@ -140,6 +140,23 @@ class KaMinPar:
     ) -> np.ndarray:
         if self._graph is None:
             raise RuntimeError("no graph set; call set_graph() first")
+        # one root profiler span per request: every timer scope below is
+        # a span inside it (utils/timer.py); k as PartitionContext.setup
+        # will resolve it
+        if max_block_weights is not None:
+            span_k = len(max_block_weights)
+        else:
+            span_k = self.ctx.partition.k if k is None else k
+        with timer.request_span(
+            k=int(span_k), n=int(self._graph.n), m=int(self._graph.m)
+        ):
+            return self._compute_partition(
+                k, epsilon, max_block_weights, min_block_weights, seed
+            )
+
+    def _compute_partition(
+        self, k, epsilon, max_block_weights, min_block_weights, seed
+    ) -> np.ndarray:
         from .graphs.compressed import CompressedHostGraph
         from .ops.lane_gather import clear_plan_cache
 
@@ -318,7 +335,10 @@ class KaMinPar:
                     and not still_compressed
                     and not streaming_src
                 ):
-                    core, perm, _ = remove_isolated_nodes(graph)
+                    # host-only, before the first launch: the device
+                    # idles meanwhile, so the span says what for
+                    with timer.scoped_timer("isolated-nodes"):
+                        core, perm, _ = remove_isolated_nodes(graph)
                     core_ctx = ctx  # weights already set up from the full graph
                     if self._warm_part is not None:
                         # warm seed follows the core permutation (the

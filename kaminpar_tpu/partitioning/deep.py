@@ -290,14 +290,19 @@ class DeepMultilevelPartitioner:
             )
 
         refiner = RefinerPipeline(self.ctx, current_k)
-        partition = refiner.enforce_balance_host(
-            dgraph, partition,
-            np.asarray(self.ctx.partition.max_block_weights), where="deep",
-        )
+        # readback scopes: the overload check is the first host read
+        # after the last refiner, the download the last of the request
+        with timer.scoped_timer("balance-check", sync=True):
+            partition = refiner.enforce_balance_host(
+                dgraph, partition,
+                np.asarray(self.ctx.partition.max_block_weights),
+                where="deep",
+            )
         # quality: push the FINAL partition back up through the recorded
         # cluster maps — the coarsening floors + per-level attribution
         quality_mod.finalize_device(qh, dgraph, partition, graph.n)
-        return np.asarray(partition)[: graph.n]
+        with timer.scoped_timer("partition-download", sync=True):
+            return np.asarray(partition)[: graph.n]
 
     # -- checkpoint payloads / restore (resilience/checkpoint.py) -------
 
@@ -642,9 +647,10 @@ class DeepMultilevelPartitioner:
         ctx = self.ctx
         # the host extraction IS the staged boundary: pull graph and
         # partition before opening the span so the timed extension work
-        # starts from host arrays
-        host = host_graph_from_device(dgraph)
-        part = np.asarray(partition)[: host.n].astype(np.int64)
+        # starts from host arrays; the pull is a readback scope beside it
+        with timer.scoped_timer("extend-pull", sync=True):
+            host = host_graph_from_device(dgraph)
+            part = np.asarray(partition)[: host.n].astype(np.int64)
         with timer.scoped_timer("extend-partition"):
             current_k = len(spans)
             ext = extract_block_subgraphs(host, part, current_k)

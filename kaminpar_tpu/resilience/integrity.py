@@ -353,14 +353,19 @@ def refine_probe(graph, partition, max_block_weights, min_block_weights):
     integrity is disabled."""
     if not enabled():
         return None
-    if min_block_weights is None:
-        vals = _refine_jit(False)(graph, partition, max_block_weights)
-    else:
-        vals = _refine_jit(True)(
-            graph, partition, max_block_weights, min_block_weights
-        )
-    cut, feas, pmin, pmax = vals
-    return int(cut), bool(feas), int(pmin), int(pmax)
+    from ..utils.timer import scoped_timer
+
+    # a readback scope: the probe after a pass is the host's first read
+    # since the refiners launched, so the wait for them lands here
+    with scoped_timer("refine-probe", sync=True):
+        if min_block_weights is None:
+            vals = _refine_jit(False)(graph, partition, max_block_weights)
+        else:
+            vals = _refine_jit(True)(
+                graph, partition, max_block_weights, min_block_weights
+            )
+        cut, feas, pmin, pmax = vals
+        return int(cut), bool(feas), int(pmin), int(pmax)
 
 
 def check_refinement(

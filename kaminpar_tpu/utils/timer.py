@@ -2,8 +2,14 @@
 
 The reference keeps a global hierarchical timer singleton with SCOPED_TIMER
 macros (kaminpar-common/timer.h:20-62).  Here we keep a lightweight tree of
-named scopes; `scoped_timer` is a context manager.  Device work is made
-observable by calling `jax.block_until_ready` at scope exit when requested.
+named scopes; `scoped_timer` is a context manager.  A scope in which the
+host reads back from the device declares it with `sync=True`.
+
+Every scope is also a span on the profiler's clock: it enters a
+`jax.profiler.TraceAnnotation` named `SPAN_PREFIX` + the scope's dotted
+path, so a profiler trace holds the program's phases beside the device's
+lines (perfbench/harness/phase_reduce.py joins the two).  Outside a
+profiler session an annotation costs one atomic load.
 """
 
 from __future__ import annotations
@@ -13,7 +19,16 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Dict, Optional
 
+from jax.profiler import TraceAnnotation
+
 from .. import telemetry
+
+#: every program span in a profiler trace starts with this (the benchmark
+#: does not import the program: perfbench/harness/phase_reduce.py and
+#: PERF.md spell it too)
+SPAN_PREFIX = "kaminpar/"
+#: the one root span of a request (KaMinPar.compute_partition)
+REQUEST_SPAN = SPAN_PREFIX + "request"
 
 
 @dataclass
@@ -43,6 +58,7 @@ class Timer:
         self.root = TimerNode(name)
         self._stack = [self.root]
         self._open_starts: list = []  # perf_counter stamps of open scopes
+        self._open_spans: list = []  # profiler annotations of open scopes
         self.enabled = enabled
 
     def reset(self) -> None:
@@ -56,6 +72,7 @@ class Timer:
         self.root = TimerNode(self.root.name)
         self._stack = [self.root]
         self._open_starts = []
+        self._open_spans = []
 
     def idle(self) -> bool:
         """True when no scope is open — i.e. not nested inside another
@@ -64,13 +81,20 @@ class Timer:
         return len(self._stack) == 1
 
     @contextmanager
-    def scope(self, name: str, sync=None):
-        """Time a named scope. `sync` may be a value to block_until_ready on exit."""
+    def scope(self, name: str, sync: bool = False):
+        """Time a named scope.  `sync=True` declares a readback scope:
+        its body is where the host waits for the device, so the whole
+        scope is sync time (the span's `sync_s`) and tpulint R1 does not
+        apply inside."""
         if not self.enabled:
             yield
             return
         node = self._stack[-1].child(name)
         self._stack.append(node)
+        path = ".".join(n.name for n in self._stack[1:])
+        span = TraceAnnotation(SPAN_PREFIX + path)
+        span.__enter__()
+        self._open_spans.append(span)
         tel = telemetry.enabled()
         entry_state = _span_entry_state() if tel else None
         start = time.perf_counter()
@@ -81,28 +105,20 @@ class Timer:
             # an emergency unwind() may have force-closed this scope
             # while the generator was suspended — don't double-account
             if self._stack and self._stack[-1] is node:
-                sync_s = None
-                if sync is not None:
-                    t_sync = time.perf_counter()
-                    try:
-                        import jax
-
-                        jax.block_until_ready(sync)
-                    except Exception:
-                        pass
-                    sync_s = time.perf_counter() - t_sync
                 end = time.perf_counter()
                 node.elapsed += end - start
                 node.count += 1
                 if tel:
-                    path = ".".join(n.name for n in self._stack[1:])
                     telemetry.record_span(
                         name, path, start, end - start,
-                        **_span_exit_attrs(entry_state, sync_s),
+                        **_span_exit_attrs(
+                            entry_state, end - start if sync else None
+                        ),
                     )
                 self._stack.pop()
                 if self._open_starts:
                     self._open_starts.pop()
+                self._open_spans.pop().__exit__(None, None, None)
 
     def unwind(self) -> int:
         """Force-close every open scope, recording its elapsed time and
@@ -132,6 +148,7 @@ class Timer:
                     node.name, path, start, end - start, interrupted=True
                 )
             self._stack.pop()
+            self._open_spans.pop().__exit__(None, None, None)
             closed += 1
         return closed
 
@@ -220,8 +237,17 @@ def _span_exit_attrs(state: Optional[dict], sync_s: Optional[float]) -> dict:
 GLOBAL_TIMER = Timer()
 
 
+def request_span(**args):
+    """The root profiler span of one request.  An annotation only, not a
+    timer node: every scope of the request lies inside it on one thread,
+    and that containment is what ties a trace's spans to the request."""
+    return TraceAnnotation(REQUEST_SPAN, **args)
+
+
 @contextmanager
-def scoped_timer(name: str, timer: Optional[Timer] = None, sync=None):
+def scoped_timer(
+    name: str, timer: Optional[Timer] = None, sync: bool = False
+):
     t = timer if timer is not None else GLOBAL_TIMER
     with t.scope(name, sync=sync):
         yield
