@@ -39,7 +39,8 @@ from .segments import (
     argmax_per_segment,
     best_from_dense,
     connection_to_label,
-    dense_block_ratings,
+    count_conn_engine,
+    csr_block_ratings,
 )
 
 # Above this k a dense (n, k) rating table is shape-infeasible (the
@@ -98,9 +99,7 @@ def overload_balance_round(
     # compact-gain-cache regime).
     if k <= BALANCER_DENSE_MAX_K:
         if conn is None:
-            conn = dense_block_ratings(
-                graph.src, graph.dst, graph.edge_w, part, n_pad, k
-            )
+            conn = csr_block_ratings(graph, part, k)
         best, best_w, w_own = best_from_dense(
             conn, part, bw, graph.node_w, cap, salt
         )
@@ -233,6 +232,8 @@ def overload_balance(
 ) -> jax.Array:
     """Public entry: runs the fused loop, emitting a per-round progress
     series (moved nodes, residual violation mass) when telemetry is on."""
+    if k <= BALANCER_DENSE_MAX_K:
+        count_conn_engine(graph, k)
     return progress_mod.instrumented(
         lambda stats: _overload_balance_impl(
             graph, partition, k, max_block_weights, seed, max_rounds, stats
@@ -277,9 +278,7 @@ def _underload_balance_impl(
         # (dense rating restricted to deficit columns; large k rates by
         # edge aggregation — see BALANCER_DENSE_MAX_K)
         if k <= BALANCER_DENSE_MAX_K:
-            conn = dense_block_ratings(
-                graph.src, graph.dst, graph.edge_w, part, n_pad, k
-            )
+            conn = csr_block_ratings(graph, part, k)
             best, best_w, _ = best_from_dense(
                 conn, part, bw, graph.node_w, bw, salt,
                 require_fit=False, allowed=deficit > 0,
@@ -374,6 +373,8 @@ def underload_balance(
     """Public entry (see overload_balance): per-round moved nodes and
     residual deficit mass land on the progress stream when telemetry is
     enabled."""
+    if k <= BALANCER_DENSE_MAX_K:
+        count_conn_engine(graph, k)
     return progress_mod.instrumented(
         lambda stats: _underload_balance_impl(
             graph, partition, k, max_block_weights, min_block_weights,

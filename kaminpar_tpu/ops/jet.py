@@ -39,11 +39,13 @@ from .balancer import overload_balance_round
 from .metrics import edge_cut
 # the dense rate+argmax core is shared with LP through ops/rating.py —
 # one public home for every rating engine (see its module docstring)
-from .rating import best_from_dense, dense_block_ratings
+from .rating import best_from_dense
 from .segments import (
     ACC_DTYPE,
     INT32_MIN,
     MAX_FUSED_EDGE_SLOTS,
+    count_conn_engine,
+    csr_block_ratings,
     expand_active_rows,
     packed_afterburner_gain,
     packed_afterburner_gain_rows,
@@ -76,9 +78,7 @@ def _full_ratings(graph: DeviceGraph, part: jax.Array, k: int,
         from .lane_gather import routed_block_ratings
 
         return routed_block_ratings(plans, part, k, graph.n_pad)
-    return dense_block_ratings(
-        graph.src, graph.dst, graph.edge_w, part, graph.n_pad, k
-    )
+    return csr_block_ratings(graph, part, k)
 
 
 def _conn_cut(
@@ -667,6 +667,9 @@ def jet_refine(
     )
     from .lane_gather import maybe_edge_plans
 
+    plans = maybe_edge_plans(graph)  # eager: host readbacks
+    if plans is None:
+        count_conn_engine(graph, k)
     return _jet_refine_impl(
         graph,
         partition,
@@ -680,5 +683,5 @@ def jet_refine(
         int(max_iterations),
         int(max_fruitless),
         int(balancer_rounds),
-        plans=maybe_edge_plans(graph),  # eager: host readbacks
+        plans=plans,
     )

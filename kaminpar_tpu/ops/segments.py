@@ -478,6 +478,144 @@ def dense_block_ratings(
     return conn.reshape(n_pad, num_blocks)
 
 
+# ---------------------------------------------------------------------------
+# CSR-order streaming — work keyed by the OWNER of a CSR slot
+# ---------------------------------------------------------------------------
+# XLA's gathers and scatters are charged per index on TPU whatever the
+# table size (v5e, measured: 9 ns an index gathered, 7 ns scattered),
+# a streaming pass well under 1 ns an element.  On a DeviceGraph the
+# owner of a slot is sorted and constant along each row, so owner-side
+# work is a cumsum plus an n-wide access at the row boundaries (the
+# _row_sums / neighbor_any_true idiom), never an m-wide irregular pass.
+
+# Words of one streaming step's (columns, m_pad) cumsum in
+# csr_block_ratings: at 32 MiB the compiler keeps it out of HBM
+# (temporaries 0.3-5 MB, 134 MB one doubling up); 8 columns fill the
+# int32 sublanes.
+CONN_STREAM_STEP_WORDS = 1 << 23
+# csr_block_ratings streams where its boundary gathers are at least
+# this many times fewer indices than the segment_sum's m_pad ...
+CONN_STREAM_MIN_INDEX_RATIO = 4
+# ... and the table takes at most this many steps: a step whose cumsum
+# does live in HBM (m_pad >= 2^21) costs a ninth of the scatter.
+# Both measured on v5e (PERF.md, PR 25).
+CONN_STREAM_MAX_STEPS = 8
+
+
+# _cumsum_minor splits a long scan into this many rows (PERF.md, PR 25)
+CUMSUM_ROWS = 1024
+
+
+def _cumsum_minor(x: jax.Array) -> jax.Array:
+    """jnp.cumsum along the last axis, bitwise in integers, as a
+    two-level scan: CUMSUM_ROWS rows scanned side by side, then each row
+    lifted by the total of the rows before it.  The flat scan of 2^20
+    or 2^21 words is the most expensive thing in this file to COMPILE
+    for the TPU (12-34 s and 1.3-2.4 MB of code an instance on v5e;
+    this form under a second and 0.5-0.9 MB, no slower to run), and
+    every loaded executable's code sits in HBM."""
+    m = x.shape[-1]
+    if m % CUMSUM_ROWS or m < 128 * CUMSUM_ROWS:
+        return jnp.cumsum(x, axis=-1, dtype=x.dtype)
+    rows = x.reshape(x.shape[:-1] + (CUMSUM_ROWS, m // CUMSUM_ROWS))
+    within = jnp.cumsum(rows, axis=-1, dtype=x.dtype)
+    totals = within[..., -1]
+    before = jnp.cumsum(totals, axis=-1, dtype=x.dtype) - totals
+    return (within + before[..., None]).reshape(x.shape)
+
+
+def expand_rows(values: jax.Array, row_ptr: jax.Array, m_pad: int) -> jax.Array:
+    """Per-slot value of the slot's owner: bitwise `values[graph.src]`
+    on a DeviceGraph (pad slots carry the pad node n_pad - 1, pad rows
+    are empty at m), at n_pad scatter indices + one streaming pass.
+
+    The first differences values[i] - values[i-1] are scatter-added at
+    the row starts and one cumsum telescopes them back: slot e reads
+    the sum over rows starting at or before e, which is the value of
+    the last such row.  Empty rows collide on one slot and telescope
+    there; a row start at m_pad owns no slot and is dropped; integer
+    wrap-around keeps it exact for any int32 word (the afterburner's
+    packed meta included)."""
+    prev = jnp.concatenate([jnp.zeros(1, values.dtype), values[:-1]])
+    starts = jnp.zeros(m_pad, values.dtype).at[row_ptr[:-1]].add(
+        values - prev, mode="drop", indices_are_sorted=True
+    )
+    return _cumsum_minor(starts)
+
+
+def conn_stream_columns(m_pad: int) -> int:
+    """Block columns one streaming step of csr_block_ratings rates."""
+    return max(1, min(8, CONN_STREAM_STEP_WORDS // m_pad))
+
+
+def conn_table_streams(k: int, n_pad: int, m_pad: int) -> bool:
+    """The engine csr_block_ratings takes, from shapes alone.  A
+    streaming step rates conn_stream_columns block columns with one
+    masked cumsum and one boundary gather of n_pad + 1 indices; the
+    flat segment_sum scatters m_pad indices."""
+    steps = -(-k // conn_stream_columns(m_pad))
+    return (
+        steps <= CONN_STREAM_MAX_STEPS
+        and CONN_STREAM_MIN_INDEX_RATIO * steps * n_pad <= m_pad
+    )
+
+
+def count_conn_engine(graph, k: int) -> None:
+    """Host-side record of the engine csr_block_ratings takes for one
+    refiner call on `graph` (utils/statistics; free when disabled)."""
+    from ..utils import statistics
+
+    streams = conn_table_streams(k, graph.n_pad, graph.m_pad)
+    statistics.count("conn_streamed" if streams else "conn_scattered")
+
+
+def csr_block_ratings(graph, labels: jax.Array, num_blocks: int) -> jax.Array:
+    """dense_block_ratings over a DeviceGraph's own CSR rows, bitwise:
+    the labels[dst] gather stays (irregular by nature); the src-keyed
+    segment_sum becomes, where conn_table_streams says it pays, masked
+    cumsums per block column read at the row boundaries.  Columns run
+    conn_stream_columns at a time as a (columns, m_pad) cumsum, m minor
+    (one boundary gather serves the whole step), steps in a rolled
+    loop: HLO size is constant in k, temporaries are bounded by
+    CONN_STREAM_STEP_WORDS, and no (m, k) table ever exists (lane
+    padding)."""
+    n_pad, m_pad = graph.n_pad, graph.m_pad
+    if not conn_table_streams(num_blocks, n_pad, m_pad):
+        return dense_block_ratings(
+            graph.src, graph.dst, graph.edge_w, labels, n_pad, num_blocks
+        )
+    return _stream_block_ratings(
+        graph, labels, num_blocks,
+        min(num_blocks, conn_stream_columns(m_pad)),
+    )
+
+
+def _stream_block_ratings(graph, labels, num_blocks: int, columns: int):
+    """csr_block_ratings' streaming engine, `columns` block columns a
+    step."""
+    n_pad, m_pad = graph.n_pad, graph.m_pad
+    block_v = jnp.clip(labels, 0, num_blocks - 1)[graph.dst]
+    w = graph.edge_w.astype(ACC_DTYPE)
+    # the inclusive cumsum read one slot before each row boundary (0
+    # before the first slot) is the exclusive one at the boundary
+    rp = jnp.clip(graph.row_ptr, 0, m_pad)
+    before = jnp.maximum(rp - 1, 0)
+
+    def step(first):
+        blocks = first + jnp.arange(columns, dtype=block_v.dtype)
+        csum = _cumsum_minor(
+            jnp.where(block_v[None, :] == blocks[:, None], w[None, :], 0)
+        )
+        at_rp = jnp.where(rp[None, :] > 0, csum[:, before], 0)
+        return at_rp[:, 1:] - at_rp[:, :-1]
+
+    # a last partial step rates blocks >= num_blocks, which no label
+    # carries after the clip: zero columns, cut off below
+    firsts = jnp.arange(0, num_blocks, columns, dtype=block_v.dtype)
+    conn_t = lax.map(step, firsts).reshape(-1, n_pad)
+    return conn_t[:num_blocks].T
+
+
 def best_from_dense(
     conn: jax.Array,
     labels: jax.Array,
@@ -820,11 +958,15 @@ def packed_afterburner_gain(
     Returns adj_gain[n_pad]; entries for non-candidates are the plain
     neighborhood sum with no candidate mask applied to themselves (mask
     with `candidate` when accepting).  Shared by the Jet refiner and the
-    bulk-synchronous LP refinement round.  A thin wrapper over the spans
-    variant: a CSR edge list is a row buffer with owner=src and spans
-    [row_ptr[i], row_ptr[i+1]).
+    bulk-synchronous LP refinement round.  A CSR edge list is a row
+    buffer with owner=src and spans [row_ptr[i], row_ptr[i+1]), whose
+    owner columns need no gather at all: src is sorted and constant
+    along a row, so they stream (expand_rows) and only the dst side
+    stays irregular.
     """
-    adj, _, _ = packed_afterburner_gain_rows(
+    m_pad = src.shape[0]
+    adj, _, _ = _afterburner_gain(
+        lambda values: expand_rows(values, row_ptr, m_pad),
         src, dst, edge_w, row_ptr[:-1], row_ptr[1:],
         part, next_part, gain, candidate, k,
     )
@@ -850,6 +992,29 @@ def packed_afterburner_gain_rows(
     current and tentative blocks PER SLOT fall out of the endpoint
     gathers either branch takes, so the Jet conn-table delta reuses them
     without further irregular ops."""
+    return _afterburner_gain(
+        lambda values: values[owner],
+        owner, dst, edge_w, start, end,
+        part, next_part, gain, candidate, k,
+    )
+
+
+def _afterburner_gain(
+    of_owner,
+    owner: jax.Array,
+    dst: jax.Array,
+    edge_w: jax.Array,
+    start: jax.Array,
+    end: jax.Array,
+    part: jax.Array,
+    next_part: jax.Array,
+    gain: jax.Array,
+    candidate: jax.Array,
+    k: int,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The afterburner both entry points share.  `of_owner(values)` is
+    the caller's way to a per-node array's per-slot owner column: a
+    gather over a row buffer, a streaming pass over CSR rows."""
     label_bits = max((k - 1).bit_length(), 1)
     gain_bits = 31 - 2 * label_bits
 
@@ -878,7 +1043,7 @@ def packed_afterburner_gain_rows(
             | (next_part << label_bits)
             | part
         )
-        mu = meta[owner]
+        mu = of_owner(meta)
         mv = meta[dst]
         lab_mask = jnp.int32((1 << label_bits) - 1)
         gain_u = mu >> (2 * label_bits)
@@ -896,15 +1061,15 @@ def packed_afterburner_gain_rows(
 
     def _exact(_):
         gain_full = jnp.where(candidate, gain, INT32_MIN)
-        gain_u = gain_full[owner]
+        gain_u = of_owner(gain_full)
         gain_v = gain_full[dst]
         v_is_cand = gain_v > INT32_MIN
         v_before_u = v_is_cand & (
             (gain_v > gain_u) | ((gain_v == gain_u) & (dst < owner))
         )
         block_v = jnp.where(v_before_u, next_part[dst], part[dst])
-        from_u = part[owner]
-        to_u = next_part[owner]
+        from_u = of_owner(part)
+        to_u = of_owner(next_part)
         return (
             _row_sums(to_u, from_u, block_v, gain_u > INT32_MIN),
             from_u,

@@ -1,0 +1,134 @@
+#!/usr/bin/env python
+"""Break-even of the CSR-order streaming helpers against XLA's gather and
+scatter (ops/segments.expand_rows, csr_block_ratings), on the chip.
+
+For each level shape (n, m, n_pad, m_pad): `values[src]` against
+expand_rows, and for each k the flat segment_sum conn table against the
+streaming engine at each of --columns block columns a step (default: the
+library's conn_stream_columns).  Every timing is the minimum of REPS
+launches ending in block_until_ready; the labels[dst] gather both engines
+share is timed alone so it can be subtracted.  A small program compiles in
+~25 s on the chip: name only what you need.
+
+Usage: python scripts/microbench_csr_stream.py [--shapes coarse,fine,mesh]
+    [--ks 2,4,8,16,32] [--columns 1,4,8] [--no-scatter]
+(TPU; a CPU run only proves the script runs.)  Writes
+chiprun_out/microbench_csr_stream.json.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+
+from kaminpar_tpu.utils.platform import configure_compile_cache
+
+configure_compile_cache()
+
+import jax.numpy as jnp
+import numpy as np
+
+from kaminpar_tpu.graphs.csr import device_graph_from_host
+from kaminpar_tpu.ops import segments as seg
+
+REPS = 5
+# name -> (n, m, n_pad, m_pad): levels 1 and 0 of rmat-s16 at --seed 1,
+# and the fine level of delaunay-n17 (degree 6)
+SHAPES = {
+    "coarse": (7_759, 903_382, 1 << 13, 1 << 20),
+    "fine": (41_761, 1_083_716, 1 << 16, 1 << 21),
+    "mesh": (131_071, 786_000, 1 << 17, 1 << 20),
+}
+
+
+def skewed_graph(rng, n, m):
+    """A directed stand-in with RMAT-like skew on both sides (the
+    timings depend on index counts and locality, not on symmetry)."""
+    from kaminpar_tpu.graphs.host import HostGraph
+
+    rank = rng.permutation(n) + 1.0
+    p = rank ** -0.8
+    p /= p.sum()
+    deg = rng.multinomial(m, p)
+    xadj = np.concatenate([[0], np.cumsum(deg)]).astype(np.int64)
+    adjncy = rng.choice(n, size=m, p=p).astype(np.int32)
+    return HostGraph(xadj=xadj, adjncy=adjncy, node_weights=None,
+                     edge_weights=rng.integers(1, 50, m).astype(np.int64))
+
+
+def timeit(fn, *args):
+    fn_j = jax.jit(fn)
+    jax.block_until_ready(fn_j(*args))  # compile
+    best = float("inf")
+    for _ in range(REPS):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn_j(*args))
+        best = min(best, time.perf_counter() - t0)
+    return best * 1e3
+
+
+def ints(text):
+    return [int(x) for x in text.split(",") if x]
+
+
+def main():
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--shapes", default="coarse,fine")
+    parser.add_argument("--ks", type=ints, default=[2, 4, 8, 16, 32])
+    parser.add_argument("--columns", type=ints, default=[])
+    parser.add_argument("--no-scatter", action="store_true")
+    args = parser.parse_args()
+    dev = jax.devices()[0]
+    out = {"device": {"platform": dev.platform, "kind": dev.device_kind},
+           "reps": REPS, "rows": []}
+    print(json.dumps(out["device"]), flush=True)
+    rng = np.random.default_rng(0)
+    for name in args.shapes.split(","):
+        n, m, n_pad, m_pad = SHAPES[name]
+        graph = device_graph_from_host(skewed_graph(rng, n, m), n_pad=n_pad,
+                                       m_pad=m_pad)
+        values = jnp.asarray(
+            rng.integers(0, 2**31 - 1, n_pad).astype(np.int32))
+        shape = {"shape": name, "n_pad": n_pad, "m_pad": m_pad, "m": m}
+        if not args.no_scatter:
+            row = dict(
+                shape, op="owner_column",
+                gather_ms=timeit(lambda g, v: v[g.src], graph, values),
+                expand_rows_ms=timeit(
+                    lambda g, v: seg.expand_rows(v, g.row_ptr, m_pad),
+                    graph, values),
+                cumsum_ms=timeit(lambda g: jnp.cumsum(g.edge_w), graph),
+                dst_gather_ms=timeit(lambda g, v: v[g.dst], graph, values))
+            out["rows"].append(row)
+            print(json.dumps(row), flush=True)
+        for k in args.ks:
+            labels = jnp.asarray(rng.integers(0, k, n_pad).astype(np.int32))
+            dense = lambda g, lab: seg.dense_block_ratings(
+                g.src, g.dst, g.edge_w, lab, n_pad, k)
+            ref = dense(graph, labels)
+            row = dict(shape, op="conn_table", k=k,
+                       rule_streams=seg.conn_table_streams(k, n_pad, m_pad))
+            if not args.no_scatter:
+                row["scatter_ms"] = timeit(dense, graph, labels)
+            for columns in args.columns or [seg.conn_stream_columns(m_pad)]:
+                columns = min(columns, k)
+                fn = lambda g, lab: seg._stream_block_ratings(
+                    g, lab, k, columns)
+                assert bool(jnp.all(fn(graph, labels) == ref))
+                row[f"stream_c{columns}_ms"] = timeit(fn, graph, labels)
+            out["rows"].append(row)
+            print(json.dumps(row), flush=True)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/microbench_csr_stream.json", "w") as f:
+        json.dump(out, f, indent=1)
+
+
+if __name__ == "__main__":
+    main()
