@@ -39,6 +39,36 @@ def _host_block_weights(graph: HostGraph, partition: np.ndarray) -> np.ndarray:
     return bw
 
 
+# Independent native multilevel bipartitions per call, of which the one
+# with the least overload, then the lowest cut, then the lowest index is
+# kept.  The pool's repetitions all share ONE coarsening hierarchy, and
+# on a mesh that hierarchy decides where the cut runs: one attempt put
+# the first bisection of a 131k-node Delaunay mesh between 1.0x and 1.5x
+# of a straight line's cut and left blocks at their caps, which Jet then
+# pays for with a balancer round and a table rebuild per iteration.  At
+# k = 16 over 40 seeds one attempt cut 4,364-4,968 (sd 2.8 %), eight cut
+# 4,157-4,462 (sd 1.5 %) with a fifth of the rebuilds (CPU, the
+# arithmetic is integer; PERF.md, PR 26).  R-MAT cuts move by under
+# 0.5 % either way.  Never more attempts than the pool may repeat: a
+# preset that asks for one repetition gets one attempt.
+NATIVE_ATTEMPTS = 8
+_ATTEMPT_SEED_STRIDE = 0x9E3779B97F4A7C15
+
+
+def _best_attempt(
+    graph: HostGraph, attempts, max_block_weights: np.ndarray
+) -> np.ndarray:
+    """``attempts``: ``[(int8 partition, cut), ...]``."""
+    node_w = graph.node_weight_array()
+
+    def key(i: int) -> Tuple[int, int, int]:
+        part, cut = attempts[i]
+        bw = np.bincount(part, weights=node_w, minlength=2).astype(np.int64)
+        return int(np.maximum(bw - max_block_weights, 0).sum()), cut, i
+
+    return attempts[min(range(len(attempts)), key=key)][0]
+
+
 @dataclass
 class _PoolEntry:
     name: str
@@ -154,17 +184,26 @@ class InitialMultilevelBipartitioner:
             # skipped by env flag or by a missing toolchain
             if native.available():
                 seed = int(rng.integers(0, 2**62))
+                # ONE draw from the shared stream whatever the number
+                # of attempts: their seeds are strided from it
+                seeds = [
+                    (seed + i * _ATTEMPT_SEED_STRIDE) & 0xFFFFFFFFFFFFFFFF
+                    for i in range(
+                        min(NATIVE_ATTEMPTS,
+                            self.ctx.pool.max_num_repetitions)
+                    )
+                ]
 
                 def _native_ip():
                     with timer.scoped_timer("ip-native"):
-                        part = native.ml_bipartition(
-                            graph, max_block_weights, self.ctx, seed=seed
+                        attempts = native.ml_bipartition_attempts(
+                            graph, max_block_weights, self.ctx, seeds
                         )
-                    if part is None:
+                    if attempts is None:
                         raise NativeUnavailable(
                             "native bipartitioner unavailable"
                         )
-                    return part
+                    return _best_attempt(graph, attempts, max_block_weights)
 
                 # fallback: fall through to the numpy multilevel path
                 # below (the behavioral spec of the native engine)

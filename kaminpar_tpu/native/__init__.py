@@ -376,6 +376,19 @@ def ml_bipartition(graph, max_block_weights, ip_ctx, seed: int):
     ip.cpp header); returns an int8 partition, or None when the native
     library is unavailable (caller falls back to the numpy path).
     """
+    attempts = ml_bipartition_attempts(
+        graph, max_block_weights, ip_ctx, [seed]
+    )
+    return None if attempts is None else attempts[0][0]
+
+
+def ml_bipartition_attempts(graph, max_block_weights, ip_ctx, seeds):
+    """One independent native multilevel bipartition per seed, as
+    ``[(int8 partition, cut), ...]`` in the order of ``seeds``, or None
+    when the native library is unavailable.  More than one seed runs on
+    a thread each: the call holds no state outside its arguments and
+    ctypes releases the GIL for its duration, so the attempts cost the
+    wall time of the slowest one where the host has the cores."""
     lib = get_lib()
     if lib is None or graph.n == 0:
         return None
@@ -393,30 +406,39 @@ def ml_bipartition(graph, max_block_weights, ip_ctx, seed: int):
     max_cluster_weight = max(
         1, int(ic.cluster_weight_multiplier * int(max_bw.max()))
     )
-    out = np.empty(graph.n, dtype=np.int8)
-    lib.kmp_ml_bipartition(
-        graph.n, xadj, adjncy, node_w, edge_w,
-        int(max_bw[0]), int(max_bw[1]),
-        int(ic.contraction_limit), float(ic.convergence_threshold),
-        max_cluster_weight,
-        int(pool.min_num_repetitions),
-        int(pool.min_num_non_adaptive_repetitions),
-        int(pool.max_num_repetitions), float(pool.repetition_multiplier),
-        int(bool(pool.use_adaptive_bipartitioner_selection)),
-        int(bool(pool.enable_bfs_bipartitioner)),
-        int(bool(pool.enable_ggg_bipartitioner)),
-        int(bool(pool.enable_random_bipartitioner)),
-        int(bool(pfm.disabled)),
-        int(pfm.stopping_rule == FMStoppingRule.ADAPTIVE),
-        int(pfm.num_fruitless_moves), float(pfm.alpha),
-        int(pfm.num_iterations),
-        int(bool(fm.disabled)),
-        int(fm.stopping_rule == FMStoppingRule.ADAPTIVE),
-        int(fm.num_fruitless_moves), float(fm.alpha),
-        int(fm.num_iterations),
-        int(seed) & 0xFFFFFFFFFFFFFFFF, out,
-    )
-    return out
+
+    def attempt(seed):
+        out = np.empty(graph.n, dtype=np.int8)
+        cut = lib.kmp_ml_bipartition(
+            graph.n, xadj, adjncy, node_w, edge_w,
+            int(max_bw[0]), int(max_bw[1]),
+            int(ic.contraction_limit), float(ic.convergence_threshold),
+            max_cluster_weight,
+            int(pool.min_num_repetitions),
+            int(pool.min_num_non_adaptive_repetitions),
+            int(pool.max_num_repetitions), float(pool.repetition_multiplier),
+            int(bool(pool.use_adaptive_bipartitioner_selection)),
+            int(bool(pool.enable_bfs_bipartitioner)),
+            int(bool(pool.enable_ggg_bipartitioner)),
+            int(bool(pool.enable_random_bipartitioner)),
+            int(bool(pfm.disabled)),
+            int(pfm.stopping_rule == FMStoppingRule.ADAPTIVE),
+            int(pfm.num_fruitless_moves), float(pfm.alpha),
+            int(pfm.num_iterations),
+            int(bool(fm.disabled)),
+            int(fm.stopping_rule == FMStoppingRule.ADAPTIVE),
+            int(fm.num_fruitless_moves), float(fm.alpha),
+            int(fm.num_iterations),
+            int(seed) & 0xFFFFFFFFFFFFFFFF, out,
+        )
+        return out, int(cut)
+
+    if len(seeds) == 1:
+        return [attempt(seeds[0])]
+    from concurrent.futures import ThreadPoolExecutor
+
+    with ThreadPoolExecutor(max_workers=len(seeds)) as threads:
+        return list(threads.map(attempt, seeds))
 
 
 # ---------------------------------------------------------------------------

@@ -646,17 +646,24 @@ def jet_refine(
     # level at 4M slots).  Most of the cut gain arrives early: on the
     # medium RMAT bench 8 fine iters matches 16 within ±0.1% cut at half
     # the cost (and 32 was measurably worse than 16); coarse levels get
-    # 16 — double the fine budget (they set up the solution structure).
+    # 12 — half as much again (they set up the solution structure).
+    # 12 is the fruitless limit: of a budget of 16 the last four ran
+    # only where one of the first four had improved the cut by 0.1 %,
+    # so a coarse call did 12 to 16 iterations by the luck of the seed
+    # (88-96 a request on a 131k-node mesh, 9 % of Jet's seconds) and
+    # read the counter back after every chunk to find out.  At 12 every
+    # coarse call does the same work and no chunk waits for the host;
+    # the mesh's cut over 40 seeds is the same (mean 4,251 against
+    # 4,255) and R-MAT's cut after every refiner call (three seeds at
+    # k = 16; CPU, the arithmetic is integer; PERF.md, PR 26).
     # Above the large-graph boundary (the delta-round threshold) the
-    # coarse budget halves again: measured on the 10M bench, coarse 8
-    # costs +0.2% cut for -18% total wall (140 s -> 115 s warm), while
-    # small graphs keep 16 (their iterations are cheap and the extra
-    # polish is free).
+    # coarse budget is 8: measured on the 10M bench, coarse 8 costs
+    # +0.2% cut for -18% total wall (140 s -> 115 s warm).
     if ctx.num_iterations > 0:
         max_iterations = ctx.num_iterations
     elif is_coarse:
         max_iterations = (
-            8 if graph.src.shape[0] >= DELTA_MIN_EDGE_SLOTS else 16
+            8 if graph.src.shape[0] >= DELTA_MIN_EDGE_SLOTS else 12
         )
     else:
         max_iterations = 8

@@ -9,6 +9,7 @@ formula (max_cluster_weights.h) and the shrink/convergence checks
 
 from __future__ import annotations
 
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import List, Optional, Tuple
 
@@ -35,6 +36,18 @@ from ..utils import timer
 @partial(jax.jit, donate_argnums=(0,))
 def _project_partition_donated(partition, cmap):
     return partition[cmap]
+
+
+@contextmanager
+def _lp_clustering_scope(engine: str):
+    """The `lp-clustering` timer scope with the level's resolved rating
+    engine as a scope of its own directly under it (`rating-sort2`,
+    `rating-scatter`, ...): telemetry is off in a measured run, and the
+    scope, a span in a profiler trace, still says which engine a level
+    ran and for how long."""
+    with timer.scoped_timer("lp-clustering"):
+        with timer.scoped_timer(f"rating-{engine}"):
+            yield
 
 
 @dataclass
@@ -232,7 +245,7 @@ class Coarsener:
             if timer.GLOBAL_TIMER.enabled:
                 int(jnp.sum(x[:1]))
 
-        with timer.scoped_timer("lp-clustering"):
+        with _lp_clustering_scope(lp_cfg.rating):
             labels = cluster_once(mcw, 0)
             drain(labels)
         with timer.scoped_timer("contraction"):
@@ -255,7 +268,7 @@ class Coarsener:
                 min(int(mcw) * 2, int(jnp.iinfo(WEIGHT_DTYPE).max)),
                 dtype=WEIGHT_DTYPE,
             )
-            with timer.scoped_timer("lp-clustering"):
+            with _lp_clustering_scope(lp_cfg.rating):
                 labels = cluster_once(mcw, retries * 977)
                 drain(labels)
             with timer.scoped_timer("contraction"):
@@ -274,7 +287,7 @@ class Coarsener:
             import dataclasses
 
             hash_cfg = dataclasses.replace(self._lp_cfg, rating="hash")
-            with timer.scoped_timer("lp-clustering"):
+            with _lp_clustering_scope(hash_cfg.rating):
                 labels = lp_cluster(
                     cluster_input, mcw, seed + jnp.int32(3989), hash_cfg
                 )

@@ -40,11 +40,13 @@ from kaminpar_tpu.ops import segments as seg
 
 REPS = 5
 # name -> (n, m, n_pad, m_pad): levels 1 and 0 of rmat-s16 at --seed 1,
-# and the fine level of delaunay-n17 (degree 6)
+# and levels 0 and 1 of delaunay-n17 (degree 6; n = 2^17 exactly, and
+# the n + 1 row pointers pad to 2^18)
 SHAPES = {
     "coarse": (7_759, 903_382, 1 << 13, 1 << 20),
     "fine": (41_761, 1_083_716, 1 << 16, 1 << 21),
-    "mesh": (131_071, 786_000, 1 << 17, 1 << 20),
+    "mesh": (131_072, 786_374, 1 << 18, 1 << 20),
+    "mesh1": (26_901, 160_468, 1 << 15, 1 << 20),
 }
 
 

@@ -423,13 +423,16 @@ def test_bitflip_chaos_detect_retry_recover_cut_identical(monkeypatch):
     a named invariant, recovered in one retry, and the final partition
     is IDENTICAL to the uninjected run (recovery is lossless).  With
     detection kill-switched the same injection yields a measurably
-    different (silently corrupt) result."""
-    baseline = _partition()
+    different (silently corrupt) result.  Seed 3: with eight native
+    bipartition attempts a call (PR 26) the flipped weight no longer
+    reaches the final partition at seeds 1 and 2 (k=4), where one
+    attempt let it through; at seed 3 it still does."""
+    baseline = _partition(seed=3)
 
     resilience.reset()
     telemetry.reset()
     monkeypatch.setenv(faults.ENV_VAR, "bit-flip:contraction:nth=1")
-    injected = _partition()
+    injected = _partition(seed=3)
     s = integrity.summary()
     assert s["verdict"] == "recovered", s
     assert s["retries"] == 1 and s["recovered"] == 1
@@ -446,7 +449,7 @@ def test_bitflip_chaos_detect_retry_recover_cut_identical(monkeypatch):
     resilience.reset()
     telemetry.reset()
     monkeypatch.setenv(integrity.ENV_INTEGRITY, "0")
-    corrupt = _partition()
+    corrupt = _partition(seed=3)
     assert integrity.summary() == {"enabled": False}
     assert not np.array_equal(corrupt, baseline)
 

@@ -231,3 +231,29 @@ def test_new_scopes_are_leaves_beside_the_phase_scopes(session):
     assert tree["partitioning.isolated-nodes"] == 1
     assert tree["partitioning.balance-check"] == 1
     assert tree["partitioning.partition-download"] == 1
+
+
+def test_the_rating_engine_is_a_scope_under_lp_clustering(session):
+    """Every LP clustering runs inside one `rating-<engine>` scope named
+    by the engine the level resolved to, so a profiler trace of a run
+    with telemetry off still says which engine ran; the benchmark's
+    roll-up finds `coarsening` above it, so its layers do not move."""
+    from perfbench.harness import phase_reduce
+
+    tree = session.tree_inside
+    clusterings = [p for p in tree if p.endswith(".lp-clustering")]
+    assert clusterings
+    for path in clusterings:
+        below = {p[len(path) + 1:]: count for p, count in tree.items()
+                 if p.startswith(path + ".")}
+        assert all(name.startswith("rating-") and "." not in name
+                   for name in below)
+        assert sum(below.values()) == tree[path]
+        for name in below:
+            assert phase_reduce.layer_of(f"{path}.{name}") == (
+                "coarsening", "coarsening")
+    # this small skewed graph (avg degree 15, skew over 8) takes scatter;
+    # a mesh takes sort2 (tests/test_mesh_deployment.py)
+    scatter = "partitioning.coarsening.lp-clustering.rating-scatter"
+    assert scatter in tree
+    assert SPAN_PREFIX + scatter in {s[3] for s in session.spans}
