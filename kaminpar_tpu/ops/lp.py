@@ -66,6 +66,7 @@ from .segments import (
     connection_to_own_rows,
     dense_block_ratings,
     expand_active_rows,
+    expand_rows,
     hash_u32,
     hashed_rating_table,
     neighbor_any_true,
@@ -290,13 +291,13 @@ def lp_round(
             best = jnp.where(ok, lab_j, best)
             best_w = jnp.where(ok, val_j, best_w)
     elif engine == "scatter":
-        # the one-launch scatter-add engine (ops/rating.py): ONE edge
-        # gather (labels[dst]), then segment-sum slot tables — no edge
-        # sort anywhere.  Rows the two elimination passes could not
-        # rate exhaustively are barred from moving; when too many rows
-        # are barred the whole round's rating falls back to the exact
-        # sort engine via lax.cond (collision-safe fallback — only the
-        # taken branch executes).
+        # the one-launch scatter-add engine (ops/rating.py): TWO edge
+        # gathers (labels[dst] and the room left in that cluster), then
+        # segment-sum slot tables — no edge sort anywhere.  Rows the
+        # two elimination passes could not rate exhaustively are barred
+        # from moving; when too many rows are barred the whole round's
+        # rating falls back to the exact sort engine via lax.cond
+        # (collision-safe fallback — only the taken branch executes).
         from .rating import best_from_slots, scatter_slot_ratings
 
         nb = (
@@ -311,19 +312,47 @@ def lp_round(
         node_ids0 = jnp.arange(n_pad, dtype=jnp.int32)
         is_real0 = node_ids0 < graph.n
 
+        # may the edge's owner join its neighbour's cluster?  Decided
+        # HERE, per edge: the cluster side rides one more dst gather of
+        # an n-wide column, the owner side streams in CSR order (a
+        # delta buffer indexes by its owner column).  The finished
+        # table is no place to ask: it has n_pad * 2 * num_slots
+        # entries, 4x the edge list at the coarsener's doubled slots,
+        # and a gather is charged per index (49-72 ms a round there at
+        # (2^16, 2^21) on v5e against 18.7 here; PERF.md, PR 27).  The
+        # own label is exempt: its slot holds the row's w_cur.
+        if rows is not None:
+            def of_owner(values):
+                return values[owner_c]
+        else:
+            def of_owner(values):
+                return expand_rows(values, graph.row_ptr, m_slots)
+
+        # n-wide: the room left under the cap in each node's cluster
+        room = (cap - cluster_weights.astype(ACC_DTYPE))[
+            jnp.clip(labels, 0, C - 1)
+        ]
+        joinable = of_owner(graph.node_w).astype(ACC_DTYPE) <= room[dst_b]
+        if communities is not None:
+            # clustering labels are node ids: a cluster's community is
+            # its label node's community (same rule as every engine)
+            joinable = joinable & (
+                communities[jnp.clip(labels, 0, n_pad - 1)][dst_b]
+                == of_owner(communities)
+            )
+        joinable = joinable | (nb == of_owner(labels))
+
         # the slot tables are built ONCE, outside the cond: the fallback
-        # predicate needs fully_rated either way, and the (n, 2S) table
-        # is the cheap part to carry into the taken branch
+        # predicate needs fully_rated either way, and the taken branch
+        # only reads the (n, 2S) table element-wise (the widest array
+        # of the round: nothing irregular is asked of it)
         slot_label, slot_w, fully_rated = scatter_slot_ratings(
             owner_c, nb, w_b, n_pad, cfg.num_slots, salt,
-            valid=valid_slots, spans=(start, end),
+            valid=valid_slots, spans=(start, end), joinable=joinable,
         )
 
         def scatter_rate(_):
-            b, bw, w_own = best_from_slots(
-                slot_label, slot_w, labels, cluster_weights,
-                graph.node_w, cap, salt, communities=communities,
-            )
+            b, bw, w_own = best_from_slots(slot_label, slot_w, labels, salt)
             return b, bw, w_own, ~fully_rated
 
         def sort_rate(_):
@@ -1038,13 +1067,9 @@ def two_hop_cluster(
         )
 
         def scatter_fav(_):
+            # a table built without `joinable`: rated ignoring the cap
             fav, fav_w, _ = best_from_slots(
-                slot_label, slot_w, labels, cluster_weights,
-                graph.node_w,
-                jnp.broadcast_to(
-                    max_cluster_weight, (cluster_weights.shape[0],)
-                ),
-                seed, require_fit=False,
+                slot_label, slot_w, labels, seed
             )
             # zero-weight ratings (sparsified-away edges) are not real
             # favorites — same mask as the sort2/hash branches

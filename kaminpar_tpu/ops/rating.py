@@ -21,9 +21,10 @@ Engines (see docs/performance.md "Rating engines"):
     salt re-rolls the slots) and a round-level guard falls back to the
     exact sort engine when too many rows are barred — collision-safe
     by construction.  No edge-list sort anywhere: the round touches
-    the edge list with ONE gather plus segment ops, which is why this
-    is the coarsening hot-path engine (XLA sorts are many HBM passes;
-    scatter-adds are one — BENCH_r04 utilization data).
+    the edge list with two gathers plus segment ops, and the table
+    (wider than the edge list) with nothing irregular, which is why
+    this is the coarsening hot-path engine (XLA sorts are many HBM
+    passes; scatter-adds are one — BENCH_r04 utilization data).
   * ``sort2``    — top-K rated clusters per row via two buffer-wide
     sorts (ops/segments.rating_topk_rows); exact own-connection.
   * ``sort``     — exact enumeration of every adjacent cluster via the
@@ -56,6 +57,7 @@ import jax.numpy as jnp
 from .segments import (
     ACC_DTYPE,
     INT32_MIN,
+    _cumsum_minor,
     best_from_dense,
     dense_block_ratings,
     hash_u32,
@@ -63,10 +65,15 @@ from .segments import (
 
 ENGINES = ("auto", "scatter", "sort2", "sort", "hash", "dense")
 
-#: Hashed slots per node row (per elimination pass).  32 keeps the slot
-#: table at n_pad * 64 entries across both passes — small next to the
-#: edge list — while two passes push the fully-rated fraction past ~95%
-#: at average degrees up to ~20 (measured on the RMAT bench graphs).
+#: Hashed slots per node row (per elimination pass).  32 puts the slot
+#: table at n_pad * 64 entries across both passes, and the coarsener
+#: doubles it on dense levels: at (n_pad, m_pad) = (2^16, 2^21), S = 64,
+#: the table is 2^23 entries, FOUR TIMES the edge list (select_engine
+#: admits up to six).  Nothing irregular may run at table width: a
+#: gather is charged per index, 49-72 ms at 2^23 on v5e against 18.7
+#: at 2^21 (PERF.md, PR 27).  Two passes push the fully-rated
+#: fraction past ~95% at average degrees up to ~20 (measured on the
+#: RMAT bench graphs).
 DEFAULT_NUM_SLOTS = 32
 
 #: Fall back to the exact sort engine when more than this fraction of
@@ -177,6 +184,7 @@ def scatter_slot_ratings(
     valid: jax.Array | None = None,
     spans: Tuple[jax.Array, jax.Array] | None = None,
     label_space: int | None = None,
+    joinable: jax.Array | None = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Exact-where-rated hashed rating rows via scatter-adds only.
 
@@ -191,7 +199,22 @@ def scatter_slot_ratings(
     Returns (slot_label, slot_w, fully_rated):
       slot_label i32[n_pad, 2*num_slots]  rated label per slot (-1 empty)
       slot_w     ACC[n_pad, 2*num_slots]  exact connection weight
+                                          (< 0: not joinable, below)
       fully_rated bool[n_pad]             every adjacent label was rated
+
+    ``joinable`` (bool per edge) is where the caller says which rated
+    clusters a row may join: False on the edges of an (owner, label)
+    pair that is over its weight cap or outside the owner's community.
+    All edges of one pair carry one value, and the owner's OWN label is
+    always joinable (its weight is the row's w_own).  Such a pair
+    competes for its slot, wins it and sends its losers on exactly as
+    any other; only the weight of a slot it won reads minus the number
+    of its edges instead of their sum.  Edge weights are >= 0 (0 on pad
+    and sparsified-away edges), so slot_w < 0 says "rated, not
+    joinable" and a joinable slot of total weight 0 stays apart from
+    it.  The feasibility test thus rides the segment_sum that builds
+    the table, decided per edge from what the caller fetches at edge
+    width; best_from_slots never gathers at table width.
 
     ``valid`` masks buffer slots (delta rounds); pad/invalid slots are
     routed to an overflow segment so they can never pollute a row.
@@ -226,6 +249,7 @@ def scatter_slot_ratings(
     ok = neighbor_label >= 0
     if valid is not None:
         ok = ok & valid
+    rated_w = edge_w if joinable is None else jnp.where(joinable, edge_w, -1)
 
     def one_pass(pass_salt, active_edge):
         """One elimination pass over the masked edges.  Returns
@@ -249,7 +273,7 @@ def scatter_slot_ratings(
         flat_c = jnp.clip(flat, 0, total - 1)
         is_win = active_edge & (win_label[flat_c] == nb_c)
         w = jax.ops.segment_sum(
-            jnp.where(is_win, edge_w, 0).astype(ACC_DTYPE),
+            jnp.where(is_win, rated_w, 0).astype(ACC_DTYPE),
             flat,
             num_segments=total + 1,
         )[:total]
@@ -271,7 +295,7 @@ def scatter_slot_ratings(
         # streaming: cumsum of the loser mask + row-span diff (no
         # scatter; the same trick as segments.neighbor_any_true)
         start, end = spans
-        csum = jnp.cumsum(lost2.astype(ACC_DTYPE))
+        csum = _cumsum_minor(lost2.astype(ACC_DTYPE))
         csum0 = jnp.concatenate([jnp.zeros(1, dtype=csum.dtype), csum])
         D = lost2.shape[0]
         fully_rated = (
@@ -299,15 +323,16 @@ def best_from_slots(
     slot_label: jax.Array,
     slot_w: jax.Array,
     labels: jax.Array,
-    cluster_weights: jax.Array,
-    node_w: jax.Array,
-    cap: jax.Array,
     tie_salt,
-    communities: jax.Array | None = None,
-    require_fit: bool = True,
     label_range: Tuple[jax.Array, jax.Array] | None = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Per-node (best_label, best_w, w_own) from scatter slot tables.
+
+    Element-wise over the table plus row reductions, nothing irregular:
+    whether a rated cluster may be joined (weight cap, community) was
+    decided per edge and is read off the sign of ``slot_w``
+    (scatter_slot_ratings' ``joinable``; a table built without it has
+    no negative weight and rates unconstrained, as two-hop wants).
 
     The feasibility chain and the tie-break are IDENTICAL to the sort
     engine's argmax_per_segment (max weight, then max hash_u32(label,
@@ -317,29 +342,14 @@ def best_from_slots(
     the own label is absent; rows whose own label stayed contested are
     never fully rated, so callers bar them anyway).
     """
-    n_pad = slot_label.shape[0]
-    C = cluster_weights.shape[0]
-    lab_c = jnp.clip(slot_label, 0, C - 1)
     own = labels[:, None]
     w_own = jnp.max(
         jnp.where(slot_label == own, slot_w, 0), axis=1
     )
-    feas = (slot_label >= 0) & (slot_label != own)
+    feas = (slot_label >= 0) & (slot_label != own) & (slot_w >= 0)
     if label_range is not None:
         lo, hi = label_range
         feas = feas & (slot_label >= lo) & (slot_label < hi)
-    if require_fit:
-        cap_b = jnp.broadcast_to(cap, (C,))
-        feas = feas & (
-            cluster_weights[lab_c].astype(ACC_DTYPE)
-            + node_w[:, None].astype(ACC_DTYPE)
-            <= cap_b[lab_c]
-        )
-    if communities is not None:
-        # clustering labels are node ids: a cluster's community is its
-        # label node's community (same rule as every other engine)
-        lab_n = jnp.clip(slot_label, 0, n_pad - 1)
-        feas = feas & (communities[lab_n] == communities[:, None])
     score = jnp.where(feas, slot_w, INT32_MIN)
     best_w = jnp.max(score, axis=1)
     has = best_w > INT32_MIN

@@ -141,10 +141,21 @@ def _dist_lp_round(
         in_range = (seg >= 0) & (seg < n_loc)
         # rows are the n_loc OWNED nodes, labels are GLOBAL cluster ids
         # (C-wide) — label_space keeps the winner packing and clipping
-        # in the global domain
+        # in the global domain.  The weight cap is decided per edge,
+        # as in ops/lp.lp_round: weights and cap are replicated C-wide,
+        # the neighbour's cluster is in hand, and the sharded COO has
+        # no row spans, so the owner's weight and label are gathers at
+        # edge width too
+        seg_c = jnp.clip(seg, 0, n_loc - 1)
+        room = (cap - weights.astype(ACC_DTYPE))[
+            jnp.clip(neighbor_cluster, 0, C - 1)
+        ]
+        joinable = (nw_l[seg_c].astype(ACC_DTYPE) <= room) | (
+            neighbor_cluster == labels_l[seg_c]
+        )
         slot_label, slot_w, fully_rated = scatter_slot_ratings(
-            jnp.clip(seg, 0, n_loc - 1), neighbor_cluster, ew_l,
-            n_loc, cfg.num_slots, salt, valid=in_range, label_space=C,
+            seg_c, neighbor_cluster, ew_l, n_loc, cfg.num_slots, salt,
+            valid=in_range, label_space=C, joinable=joinable,
         )
         label_range = None
         if cfg.dist_local_only:
@@ -152,8 +163,8 @@ def _dist_lp_round(
 
         def scatter_rate(_):
             b, bw, w_own = best_from_slots(
-                slot_label, slot_w, labels_l, weights, nw_l, cap,
-                salt, label_range=label_range,
+                slot_label, slot_w, labels_l, salt,
+                label_range=label_range,
             )
             return b, bw, w_own, ~fully_rated
 

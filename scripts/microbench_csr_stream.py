@@ -5,13 +5,19 @@ scatter (ops/segments.expand_rows, csr_block_ratings), on the chip.
 For each level shape (n, m, n_pad, m_pad): `values[src]` against
 expand_rows, and for each k the flat segment_sum conn table against the
 streaming engine at each of --columns block columns a step (default: the
-library's conn_stream_columns).  Every timing is the minimum of REPS
-launches ending in block_until_ready; the labels[dst] gather both engines
-share is timed alone so it can be subtracted.  A small program compiles in
-~25 s on the chip: name only what you need.
+library's conn_stream_columns).  With --slots, for the scatter rating's
+slot table at each shape (SLOTS a pass): whether a rated cluster has room,
+asked of the finished table (two gathers at table width, the plain
+reference of tests/test_rating.py) against decided per edge (one more dst
+gather and two owner streams, as ops/lp.lp_round does), and against the
+same with the room bit-packed beside the label into the one word
+labels[dst] moves.  Every timing is the minimum
+of REPS launches ending in block_until_ready; the labels[dst] gather both
+engines share is timed alone so it can be subtracted.  A small program
+compiles in ~25 s on the chip: name only what you need.
 
 Usage: python scripts/microbench_csr_stream.py [--shapes coarse,fine,mesh]
-    [--ks 2,4,8,16,32] [--columns 1,4,8] [--no-scatter]
+    [--ks 2,4,8,16,32] [--columns 1,4,8] [--no-scatter] [--slots]
 (TPU; a CPU run only proves the script runs.)  Writes
 chiprun_out/microbench_csr_stream.json.
 """
@@ -47,7 +53,12 @@ SHAPES = {
     "fine": (41_761, 1_083_716, 1 << 16, 1 << 21),
     "mesh": (131_072, 786_374, 1 << 18, 1 << 20),
     "mesh1": (26_901, 160_468, 1 << 15, 1 << 20),
+    # a rehearsal on the CPU, no level of any cell
+    "tiny": (500, 6_000, 1 << 9, 1 << 13),
 }
+# slots a pass of the scatter rating at a shape: the preset's 32, doubled
+# by the coarsener on rmat-s16's level 0 (average degree 26 > 16)
+SLOTS = {"coarse": 32, "fine": 64, "tiny": 32}
 
 
 def skewed_graph(rng, n, m):
@@ -76,6 +87,60 @@ def timeit(fn, *args):
     return best * 1e3
 
 
+def slots_row(rng, graph, num_slots):
+    """ms a clustering round for the feasibility of the rated clusters,
+    three ways (module docstring), each checked against the first."""
+    n_pad, m_pad = graph.n_pad, graph.m_pad
+    label_bits = (n_pad - 1).bit_length()
+    room_max = (1 << (31 - label_bits)) - 1
+    labels = jnp.asarray(rng.integers(0, n_pad, n_pad).astype(np.int32))
+    weights = jnp.asarray(rng.integers(0, 64, n_pad).astype(np.int32))
+    cap = jnp.int32(48)
+    # what the finished table holds: a label or -1 in every slot
+    table = jnp.asarray(
+        rng.integers(-1, n_pad, (n_pad, 2 * num_slots)).astype(np.int32))
+
+    def table_side(g, slot_label, weights, cap):
+        lab_c = jnp.clip(slot_label, 0, n_pad - 1)
+        cap_b = jnp.broadcast_to(cap, (n_pad,))
+        return weights[lab_c] + g.node_w[:, None] <= cap_b[lab_c]
+
+    def of_owner(g, values):
+        return seg.expand_rows(values, g.row_ptr, m_pad)
+
+    def edge_side(g, labels, weights, cap):
+        nb = labels[g.dst]
+        room = (cap - weights)[labels]
+        fits = of_owner(g, g.node_w) <= room[g.dst]
+        return nb, fits | (nb == of_owner(g, labels))
+
+    def packed(g, labels, weights, cap):
+        # exact while no node weighs more than room_max (a guard and
+        # the form above as its fallback would have to go with it)
+        room = jnp.clip((cap - weights)[labels], 0, room_max)
+        word = ((room << label_bits) | labels)[g.dst]
+        nb = word & ((1 << label_bits) - 1)
+        fits = of_owner(g, g.node_w) <= (word >> label_bits)
+        return nb, fits | (nb == of_owner(g, labels))
+
+    want = edge_side(graph, labels, weights, cap)
+    got = packed(graph, labels, weights, cap)
+    assert all(bool(jnp.all(a == b)) for a, b in zip(want, got))
+    # the per-edge bit is the table's: slot (u, label) against any edge
+    # of u into that label
+    src, dst = np.asarray(graph.src), np.asarray(graph.dst)
+    ref = np.asarray(
+        weights[labels][dst] + graph.node_w[src] <= cap)
+    own = np.asarray(labels)[dst] == np.asarray(labels)[src]
+    assert (np.asarray(want[1]) == (ref | own)).all()
+    return dict(
+        op="slots", num_slots=num_slots, table_entries=n_pad * 2 * num_slots,
+        table_side_ms=timeit(table_side, graph, table, weights, cap),
+        labels_dst_ms=timeit(lambda g, lab: lab[g.dst], graph, labels),
+        edge_side_ms=timeit(edge_side, graph, labels, weights, cap),
+        packed_ms=timeit(packed, graph, labels, weights, cap))
+
+
 def ints(text):
     return [int(x) for x in text.split(",") if x]
 
@@ -86,6 +151,7 @@ def main():
     parser.add_argument("--ks", type=ints, default=[2, 4, 8, 16, 32])
     parser.add_argument("--columns", type=ints, default=[])
     parser.add_argument("--no-scatter", action="store_true")
+    parser.add_argument("--slots", action="store_true")
     args = parser.parse_args()
     dev = jax.devices()[0]
     out = {"device": {"platform": dev.platform, "kind": dev.device_kind},
@@ -108,6 +174,10 @@ def main():
                     graph, values),
                 cumsum_ms=timeit(lambda g: jnp.cumsum(g.edge_w), graph),
                 dst_gather_ms=timeit(lambda g, v: v[g.dst], graph, values))
+            out["rows"].append(row)
+            print(json.dumps(row), flush=True)
+        if args.slots and name in SLOTS:
+            row = dict(shape, **slots_row(rng, graph, SLOTS[name]))
             out["rows"].append(row)
             print(json.dumps(row), flush=True)
         for k in args.ks:

@@ -16,13 +16,23 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import dataclasses
+import hashlib
+
 from kaminpar_tpu.graphs import device_graph_from_host, factories
-from kaminpar_tpu.ops.lp import LPConfig, lp_cluster
+from kaminpar_tpu.ops import rating
+from kaminpar_tpu.ops.lp import LPConfig, lp_cluster, lp_round
 from kaminpar_tpu.ops.rating import (
     best_from_slots,
     best_from_slots_pallas,
     scatter_slot_ratings,
     select_engine,
+)
+from kaminpar_tpu.ops.segments import (
+    ACC_DTYPE,
+    INT32_MIN,
+    expand_active_rows,
+    hash_u32,
 )
 
 
@@ -294,11 +304,7 @@ def test_best_from_slots_pallas_interpret_matches_lax():
         dg.src, nb, dg.edge_w, dg.n_pad, 32, 13
     )
     # unconstrained reference via the lax path
-    b_ref, w_ref, own_ref = best_from_slots(
-        slot_label, slot_w, lab_j,
-        jnp.zeros((dg.n_pad,), slot_w.dtype), dg.node_w,
-        jnp.zeros((dg.n_pad,), slot_w.dtype), 13, require_fit=False,
-    )
+    b_ref, w_ref, own_ref = best_from_slots(slot_label, slot_w, lab_j, 13)
     b_pl, w_pl, own_pl = best_from_slots_pallas(
         slot_label, slot_w, lab_j, 13, interpret=True
     )
@@ -330,3 +336,321 @@ def test_dist_scatter_engine_valid_and_capped():
         assert w.max() <= 40
         assert len(np.unique(lab)) < graph.n
         outs.append(labels)
+
+
+# ---------------------------------------------------------------------------
+# feasibility at the edges (PR 27) against the table-side plain reference
+# ---------------------------------------------------------------------------
+
+
+def _table_side_best(slot_label, slot_w, labels, cluster_weights, node_w,
+                     cap, tie_salt, communities=None, label_range=None):
+    """The plain reference: best_from_slots as it stood before PR 27,
+    which asked the FINISHED table whether each slot's cluster has room
+    (two gathers of n_pad * 2 * num_slots indices, three with
+    communities) where scatter_slot_ratings' `joinable` now decides it
+    per edge.  Kept verbatim; takes a table built WITHOUT `joinable`."""
+    n_pad = slot_label.shape[0]
+    C = cluster_weights.shape[0]
+    lab_c = jnp.clip(slot_label, 0, C - 1)
+    own = labels[:, None]
+    w_own = jnp.max(jnp.where(slot_label == own, slot_w, 0), axis=1)
+    feas = (slot_label >= 0) & (slot_label != own)
+    if label_range is not None:
+        lo, hi = label_range
+        feas = feas & (slot_label >= lo) & (slot_label < hi)
+    cap_b = jnp.broadcast_to(cap, (C,))
+    feas = feas & (
+        cluster_weights[lab_c].astype(ACC_DTYPE)
+        + node_w[:, None].astype(ACC_DTYPE)
+        <= cap_b[lab_c]
+    )
+    if communities is not None:
+        lab_n = jnp.clip(slot_label, 0, n_pad - 1)
+        feas = feas & (communities[lab_n] == communities[:, None])
+    score = jnp.where(feas, slot_w, INT32_MIN)
+    best_w = jnp.max(score, axis=1)
+    has = best_w > INT32_MIN
+    is_best = feas & (score == best_w[:, None])
+    tb = hash_u32(slot_label, tie_salt)
+    best_tb = jnp.max(jnp.where(is_best, tb, -1), axis=1)
+    winner = is_best & (tb == best_tb[:, None])
+    best = jnp.max(jnp.where(winner, slot_label, -1), axis=1)
+    return (
+        jnp.where(has, best, -1),
+        jnp.where(has, best_w, INT32_MIN),
+        w_own,
+    )
+
+
+#: name -> what the mid-clustering state is made of
+FEASIBILITY_CASES = {
+    "unit-S32": dict(slots=32),
+    "unit-S64": dict(slots=64),
+    "heavy-node-weights": dict(slots=32, heavy=True),
+    "clusters-at-cap": dict(slots=32, cap_quantile=70),
+    "zero-weight-edges": dict(slots=32, zero_edges=True, heavy=True),
+    "contested-both-passes": dict(slots=32, hubs=True),
+    "communities": dict(slots=64, communities=True),
+    "delta-rows": dict(slots=32, delta=True, heavy=True),
+}
+
+
+def _mid_clustering_state(slots=32, heavy=False, cap_quantile=80,
+                          zero_edges=False, hubs=False, communities=False,
+                          delta=False):
+    """A state as a clustering round meets it: clusters of several
+    nodes with their true weights, some with room, some at the cap,
+    some over it (`cap_quantile` of the cluster weights is the cap)."""
+    rng = np.random.default_rng(len(repr((slots, heavy, cap_quantile,
+                                          zero_edges, hubs, communities,
+                                          delta))))
+    if hubs:
+        # 1,024 nodes, 24k edges: the hub rows see far more than 2 x 32
+        # distinct clusters, so labels lose both passes
+        g = factories.make_rmat(1024, 24_000, seed=5)
+    else:
+        g = factories.make_rmat(512, 4096, seed=11)
+    dg = device_graph_from_host(g)
+    n_pad = dg.n_pad
+    node_w = np.zeros(n_pad, np.int32)
+    node_w[: g.n] = rng.integers(1, 40, g.n) if heavy else 1
+    edge_w = np.asarray(dg.edge_w).copy()
+    if zero_edges:
+        edge_w[rng.random(edge_w.shape[0]) < 0.3] = 0
+    dg = dataclasses.replace(dg, node_w=jnp.asarray(node_w),
+                             edge_w=jnp.asarray(edge_w))
+    # every node joins a random node of a small pool or stays alone
+    labels = np.arange(n_pad, dtype=np.int32)
+    pool = rng.choice(g.n, size=g.n // (2 if hubs else 6), replace=False)
+    joins = rng.random(g.n) < (0.5 if hubs else 0.8)
+    labels[: g.n] = np.where(joins, rng.choice(pool, g.n), labels[: g.n])
+    labels[pool] = pool
+    cluster_w = np.zeros(n_pad, np.int64)
+    np.add.at(cluster_w, labels, node_w)
+    cap = int(np.percentile(cluster_w[cluster_w > 0], cap_quantile))
+    active = np.zeros(n_pad, bool)
+    active[: g.n] = rng.random(g.n) < (0.25 if delta else 0.9)
+    comm = None
+    if communities:
+        comm = jnp.asarray(rng.integers(0, 3, n_pad).astype(np.int32))
+    rows = None
+    if delta:
+        # a buffer the active rows do not fill: the tail is invalid
+        rows = expand_active_rows(dg.row_ptr, dg.degrees,
+                                  jnp.asarray(active), dg.m_pad // 2)
+        assert not bool(jnp.all(rows[3])) and bool(jnp.any(rows[3]))
+    cfg = LPConfig(rating="scatter", num_slots=slots, scatter_fallback=1.0)
+    return dict(graph=dg, labels=jnp.asarray(labels),
+                cluster_weights=jnp.asarray(cluster_w.astype(np.int32)),
+                cap=jnp.int32(cap), active=jnp.asarray(active),
+                salt=jnp.int32(0x2F6B1D), cfg=cfg, communities=comm,
+                rows=rows)
+
+
+def _run_round(state):
+    return lp_round(
+        state["graph"], state["labels"], state["cluster_weights"],
+        state["cap"], state["active"], state["salt"], state["cfg"],
+        communities=state["communities"], rows=state["rows"],
+    )
+
+
+@pytest.mark.parametrize("name", list(FEASIBILITY_CASES))
+def test_edge_side_feasibility_table_is_bitwise_table_side(name, monkeypatch):
+    """The table lp_round builds (`joinable` decided per edge) rates
+    every row as the table-side reference rates the plain table: same
+    best, best_w, w_own and barred rows, and the two tables differ only
+    in the weight of the slots the reference finds infeasible."""
+    state = _mid_clustering_state(**FEASIBILITY_CASES[name])
+    calls = []
+    build = rating.scatter_slot_ratings
+    monkeypatch.setattr(
+        rating, "scatter_slot_ratings",
+        lambda *a, **k: calls.append((a, k)) or build(*a, **k),
+    )
+    _run_round(state)
+    ((args, kwargs),) = calls
+    assert kwargs["joinable"] is not None
+    sl, sw, fully = build(*args, **kwargs)
+    plain = {k: v for k, v in kwargs.items() if k != "joinable"}
+    sl_ref, sw_ref, fully_ref = build(*args, **plain)
+    dg = state["graph"]
+    got = best_from_slots(sl, sw, state["labels"], state["salt"])
+    want = _table_side_best(
+        sl_ref, sw_ref, state["labels"], state["cluster_weights"],
+        dg.node_w, state["cap"], state["salt"],
+        communities=state["communities"],
+    )
+    for g_, w_ in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g_), np.asarray(w_))
+    np.testing.assert_array_equal(np.asarray(fully), np.asarray(fully_ref))
+    np.testing.assert_array_equal(np.asarray(sl), np.asarray(sl_ref))
+    sw, sw_ref = np.asarray(sw), np.asarray(sw_ref)
+    assert (sw_ref >= 0).all()
+    np.testing.assert_array_equal(sw[sw >= 0], sw_ref[sw >= 0])
+    # the case does exercise what its name says
+    best = np.asarray(got[0])
+    assert (sw < 0).any() and (best >= 0).any()
+    S = state["cfg"].num_slots
+    assert (np.asarray(sl)[:, S:] >= 0).any()  # pass 2 rated something
+    if name == "contested-both-passes":
+        assert not np.asarray(fully)[: int(dg.n)].all()
+    if name == "zero-weight-edges":
+        # a joinable slot of total weight 0 is not an infeasible one
+        assert ((np.asarray(sl) >= 0) & (sw == 0)).any()
+    if name == "clusters-at-cap":
+        room = int(state["cap"]) - np.asarray(state["cluster_weights"])
+        assert (room[np.asarray(state["labels"])] == 0).any()
+
+
+@pytest.mark.parametrize("name", list(FEASIBILITY_CASES))
+def test_edge_side_feasibility_round_is_bitwise_table_side(name, monkeypatch):
+    """lp_round's four outputs against the same round with the rating
+    swapped for the table-side reference (plain table + gathers)."""
+    state = _mid_clustering_state(**FEASIBILITY_CASES[name])
+    got = [np.asarray(x) for x in _run_round(state)]
+    build = rating.scatter_slot_ratings
+    monkeypatch.setattr(
+        rating, "scatter_slot_ratings",
+        lambda *a, joinable=None, **k: build(*a, **k),
+    )
+    monkeypatch.setattr(
+        rating, "best_from_slots",
+        lambda sl, sw, labels, salt: _table_side_best(
+            sl, sw, labels, state["cluster_weights"],
+            state["graph"].node_w, state["cap"], salt,
+            communities=state["communities"],
+        ),
+    )
+    want = [np.asarray(x) for x in _run_round(state)]
+    for g_, w_ in zip(got, want):
+        np.testing.assert_array_equal(g_, w_)
+    assert (got[0] != np.asarray(state["labels"])).any()  # nodes moved
+
+
+def test_edge_side_feasibility_global_label_space():
+    """The dist caller's form (parallel/dist_lp.py): n_loc rows rate
+    GLOBAL cluster ids, weights and cap are C-wide, the sharded COO has
+    no row spans, some edges belong to no owned row."""
+    rng = np.random.default_rng(8)
+    n_loc, C, m, S = 64, 512, 3000, 32
+    owner = jnp.asarray(rng.integers(0, n_loc, m).astype(np.int32))
+    in_range = jnp.asarray(rng.random(m) < 0.9)
+    nb = jnp.asarray(rng.integers(0, C, m).astype(np.int32))
+    ew = jnp.asarray(rng.integers(0, 9, m).astype(np.int32))
+    nw = jnp.asarray(rng.integers(1, 20, n_loc).astype(np.int32))
+    labels_l = jnp.asarray(rng.integers(0, C, n_loc).astype(np.int32))
+    weights = jnp.asarray(rng.integers(0, 60, C).astype(np.int32))
+    cap = jnp.full((C,), 50, jnp.int32)
+    joinable = (nw[owner] <= (cap - weights)[nb]) | (nb == labels_l[owner])
+    kwargs = dict(valid=in_range, label_space=C)
+    sl, sw, fully = scatter_slot_ratings(
+        owner, nb, ew, n_loc, S, 21, joinable=joinable, **kwargs)
+    sl_ref, sw_ref, fully_ref = scatter_slot_ratings(
+        owner, nb, ew, n_loc, S, 21, **kwargs)
+    label_range = (jnp.int32(128), jnp.int32(384))
+    for lr in (None, label_range):
+        got = best_from_slots(sl, sw, labels_l, 21, label_range=lr)
+        want = _table_side_best(sl_ref, sw_ref, labels_l, weights, nw, cap,
+                                21, label_range=lr)
+        for g_, w_ in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g_), np.asarray(w_))
+    np.testing.assert_array_equal(np.asarray(fully), np.asarray(fully_ref))
+    assert (np.asarray(sw) < 0).any() and (np.asarray(got[0]) >= 0).any()
+
+
+#: sha256 of lp_cluster's int32 labels at commit f52751b (PR 26), the
+#: parent of PR 27, for the level-0 clustering the coarsener asks for:
+#: (rmat n, m, graph seed, k) -> digest
+PARENT_LABELS = {
+    (4096, 60_000, 3, 4):
+        "fa1a69899eb16964a97a0bff681a1a817675f6ec4c8ea2be8b6ff2821f1d455e",
+    (2048, 24_000, 5, 8):
+        "ece5ee7e73961f46e3e106757d9474f9dfc05972c3650e44dfdc6ecd2307ac48",
+}
+
+
+@pytest.mark.parametrize("spec", list(PARENT_LABELS))
+def test_scatter_level_returns_the_parents_labels(spec):
+    """A skewed graph whose level 0 the coarsener rates with `scatter`
+    at doubled slots (the R-MAT cells' level 0 in small) clusters to
+    the labels the parent commit returned, bit for bit."""
+    from kaminpar_tpu import telemetry
+    from kaminpar_tpu.partitioning.coarsener import Coarsener
+    from kaminpar_tpu.presets import create_context_by_preset_name
+
+    n, m, graph_seed, k = spec
+    g = factories.make_rmat(n, m, seed=graph_seed)
+    dg = device_graph_from_host(g)
+    ctx = create_context_by_preset_name("default")
+    ctx.partition.setup(g, k=k, epsilon=0.03)
+    coarsener = Coarsener(ctx, dg, g.n)
+    was_enabled = telemetry.enabled()
+    try:
+        telemetry.enable()
+        cfg = coarsener._level_lp_cfg(dg)
+        event = telemetry.events("rating-engine")[-1].attrs
+    finally:
+        telemetry.enable() if was_enabled else telemetry.disable()
+    assert event["engine"] == "scatter" and cfg.num_slots == 64
+    cap = ctx.coarsening.max_cluster_weight(
+        g.n, int(ctx.partition.total_node_weight), ctx.partition)
+    labels = np.asarray(lp_cluster(dg, jnp.int32(cap), jnp.int32(11), cfg))
+    assert len(np.unique(labels[: g.n])) < g.n // 4
+    digest = hashlib.sha256(labels.astype(np.int32).tobytes()).hexdigest()
+    assert digest == PARENT_LABELS[spec]
+
+
+def _irregular_ops(jaxpr, skip_branch=None):
+    """(primitive, number of indices) of every gather and scatter of a
+    jaxpr and all it calls; `skip_branch` leaves that branch of every
+    `cond` out (0 is lax.cond's false branch)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "gather" or name.startswith("scatter"):
+            found.append((name, int(np.prod(eqn.invars[1].aval.shape[:-1]))))
+        for key, sub in eqn.params.items():
+            subs = sub if isinstance(sub, (tuple, list)) else (sub,)
+            for i, item in enumerate(subs):
+                if name == "cond" and key == "branches" and i == skip_branch:
+                    continue
+                inner = getattr(item, "jaxpr", item)
+                if hasattr(inner, "eqns"):
+                    found += _irregular_ops(inner, skip_branch)
+    return found
+
+
+def test_scatter_round_has_no_table_wide_irregular_pass():
+    """Structure of one `scatter` round: nothing irregular runs at the
+    width of the slot table (n_pad * 2 * num_slots: four times the edge
+    list on the R-MAT cells' level 0), best_from_slots is element-wise,
+    and the edge-wide irregular passes are counted, so that the next
+    writer sees one come back: 2 gathers for the neighbour (labels[dst]
+    and the room of its cluster), 3 a pass of the table build
+    (segment_max, winner gather, segment_sum), 1 in neighbor_any_true."""
+    state = _mid_clustering_state(slots=64)
+    dg = state["graph"]
+    n_pad, m_pad, S = dg.n_pad, dg.m_pad, state["cfg"].num_slots
+    table = n_pad * 2 * S
+    assert n_pad + 1 < m_pad < table
+    jaxpr = jax.make_jaxpr(
+        lambda labels, weights, active: lp_round(
+            dg, labels, weights, state["cap"], active, state["salt"],
+            state["cfg"])
+    )(state["labels"], state["cluster_weights"], state["active"]).jaxpr
+    everything = _irregular_ops(jaxpr)
+    assert everything and max(count for _, count in everything) < table
+    # the round as it runs while the barred rows are few: without the
+    # sort engine's branch of the fallback cond
+    taken = [op for op in _irregular_ops(jaxpr, skip_branch=0)
+             if op[1] == m_pad]
+    gathers = [op for op in taken if op[0] == "gather"]
+    scatters = [op for op in taken if op[0] != "gather"]
+    assert (len(gathers), len(scatters)) == (2 + 2 + 1, 2 * 2), taken
+    slot_label = jnp.zeros((n_pad, 2 * S), jnp.int32)
+    rate = jax.make_jaxpr(
+        lambda sl, sw, lab: best_from_slots(sl, sw, lab, 7)
+    )(slot_label, slot_label, state["labels"]).jaxpr
+    assert _irregular_ops(rate) == []
