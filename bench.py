@@ -409,22 +409,6 @@ def _measure_utilization():
             jnp.cumsum, M * 8, vals
         ),
     }
-    # the round-5 lane-routed gather at the same (M, N) shape — the
-    # Pallas dynamic_gather answer to the XLA gather floor above
-    try:
-        from kaminpar_tpu.ops.lane_gather import (
-            build_gather_plan,
-            lane_gather,
-            lane_gather_supported,
-        )
-
-        if lane_gather_supported():
-            plan = build_gather_plan(dst, N)
-            out["util_lane_gather_pct_hbm"] = probe(
-                lambda t: lane_gather(t, plan), M * 12, tab
-            )
-    except Exception:
-        pass
     return out
 
 

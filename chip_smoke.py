@@ -6,16 +6,12 @@ process, no children, JAX imported once.  In order:
 
   1. print what JAX sees and exit non-zero unless it is a TPU — before
      any graph is built.  No flag or variable makes this pass on a CPU;
-  2. compile both Pallas kernels once, outside the timed runs, and
-     record what the compiler said (the lane-gather support probe,
-     ``ops/lane_gather.py``, and the slot rating core,
-     ``ops/rating.best_from_slots_pallas``);
-  3. the medium bench graph (rmat n=2^16 m=600k, k=16) through the CLI
+  2. the medium bench graph (rmat n=2^16 m=600k, k=16) through the CLI
      in-process with ``--report-json``: the cold run, XLA compilation
      included;
-  4. the same graph through the facade: the warm run, every executable
+  3. the same graph through the facade: the warm run, every executable
      already compiled.  The two partitions must be bitwise equal;
-  5. with >= 4 devices, the same graph through ``dKaMinPar`` on a
+  4. with >= 4 devices, the same graph through ``dKaMinPar`` on a
      four-device mesh.
 
 Size.  The contract is a pass within 1200 s with nothing compiled
@@ -65,8 +61,8 @@ K, EPS, SEED = 16, 0.03, 1
 DIST_DEVICES = 4
 SIZE_NOTE = (
     "medium bench graph (1,083,716 directed edge slots, padded to 2^21): "
-    "below the 1 << 22 size gates (delta rounds, device extend, "
-    "lane-gather probe), because a graph at or above them is not "
+    "below the 1 << 22 size gates (delta rounds, device extend), "
+    "because a graph at or above them is not "
     "expected to compile inside the 1200 s limit from a cold cache "
     "(medium: ~870 s cold, 10M edges: 1743 s, nothing in between "
     "measured); the 10M-edge run that crosses them is recorded in "
@@ -170,42 +166,6 @@ def require_on_tpu(arrays, what: str) -> None:
     for arr in arrays:
         platforms = {d.platform for d in arr.devices()}
         require(platforms == {"tpu"}, f"{what} sits on {arr.devices()}")
-
-
-def pallas_outcomes() -> dict:
-    """Compile both Pallas kernels once and keep what the compiler said.
-    A refusal is a finding, not a failure: both paths are off by default
-    (ROADMAP A1/C2 decide their life)."""
-    import jax
-    import jax.numpy as jnp
-
-    from kaminpar_tpu.ops import lane_gather
-    from kaminpar_tpu.ops.rating import (
-        DEFAULT_NUM_SLOTS,
-        best_from_slots_pallas,
-    )
-
-    t0 = time.perf_counter()
-    routed = lane_gather.lane_gather_supported()
-    probe = dict(lane_gather.probe_status(), routed=bool(routed),
-                 probe_wall_s=round(time.perf_counter() - t0, 3))
-    print(f"chip_smoke: lane-gather probe: {json.dumps(probe)}", flush=True)
-
-    n_pad = 1 << 20
-    slots = jax.ShapeDtypeStruct((n_pad, DEFAULT_NUM_SLOTS), jnp.int32)
-    labels = jax.ShapeDtypeStruct((n_pad,), jnp.int32)
-    t0 = time.perf_counter()
-    try:
-        jax.jit(
-            lambda sl, sw, lab: best_from_slots_pallas(sl, sw, lab, 13)
-        ).lower(slots, slots, labels).compile()
-        rating = {"compiled": True}
-    except Exception as e:  # the compiler's answer IS the result here
-        rating = {"compiled": False, "error_type": type(e).__name__,
-                  "error": " ".join(str(e).split())[:2000]}
-    rating["compile_wall_s"] = round(time.perf_counter() - t0, 3)
-    print(f"chip_smoke: rating pallas core: {json.dumps(rating)}", flush=True)
-    return {"lane_gather": probe, "rating_pallas": rating}
 
 
 def block_until_ready_blocks() -> dict:
@@ -408,7 +368,6 @@ def main() -> int:
             os.environ.get("JAX_COMPILATION_CACHE_DIR")),
         "native_library": lib._name,
         "block_until_ready": block_until_ready_blocks(),
-        "pallas": pallas_outcomes(),
         "size_note": SIZE_NOTE,
         "runs": [],
     }

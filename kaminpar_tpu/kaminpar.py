@@ -158,11 +158,7 @@ class KaMinPar:
         self, k, epsilon, max_block_weights, min_block_weights, seed
     ) -> np.ndarray:
         from .graphs.compressed import CompressedHostGraph
-        from .ops.lane_gather import clear_plan_cache
 
-        # previous runs' routed-gather plans pin O(m) device memory and
-        # belong to freed graphs — drop them before building new levels
-        clear_plan_cache()
         graph = self._graph
         if isinstance(graph, CompressedHostGraph) and self._must_decode(
             graph

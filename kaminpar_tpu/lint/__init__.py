@@ -4,8 +4,7 @@ The round-5 verdict and advisor findings were all *statically visible*
 in the Python source before they cost a round: the C-ABI driver eagerly
 initialized a TPU backend despite ``JAX_PLATFORMS=cpu`` and hung the
 suite 600 s; trace-time comm accounting silently under/over-counted;
-int32 tags and accumulators capped scale; routed-gather plans could
-inflate without bound on skewed graphs.  tpulint encodes each incident
+int32 tags and accumulators capped scale.  tpulint encodes each incident
 class as a rule so future perf PRs cannot silently reintroduce them:
 
   R1  host-sync primitives (``.item()``, ``int()/float()/bool()`` of jax
@@ -22,8 +21,6 @@ class as a rule so future perf PRs cannot silently reintroduce them:
       accumulator widths;
   R4  retrace hygiene — jit wrappers constructed inside loops or around
       fresh lambdas retrace/recompile per evaluation;
-  R5  routed-gather plan builders must check the plan against a slot cap
-      (``plan_within_cap`` / ``num_slots``) before keeping it;
   R6  eager device-memory/cost introspection must stay behind the gated
       perf helpers (``telemetry.perf`` / ``utils.heap_profiler``);
   R7  SPMD collective symmetry — rank-dependent control flow

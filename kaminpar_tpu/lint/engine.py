@@ -1,7 +1,7 @@
 """tpulint core: AST analysis, suppressions, file walking.
 
 One analyzer instance handles one module.  The rule logic lives in
-``rules.py`` (R1-R6) and ``spmd.py`` (R7/R8); ``schema_pins.py`` owns
+``rules.py`` (R1-R4, R6) and ``spmd.py`` (R7/R8); ``schema_pins.py`` owns
 the cross-file R9 check and ``callgraph.py`` the package index.  This
 module owns the shared machinery every rule needs:
 
@@ -36,7 +36,6 @@ RULES: Dict[str, str] = {
     "R2": "eager/ungated device or backend query (use utils.platform)",
     "R3": "32-bit accumulation where the dtypes.py 64-bit policy applies",
     "R4": "jit wrapper constructed per iteration/evaluation (retrace)",
-    "R5": "routed-gather plan built without a slot cap check",
     "R6": "eager device-memory/cost introspection outside the gated "
           "perf helpers (telemetry.perf / utils.heap_profiler)",
     "R7": "rank-dependent control flow guarding an SPMD collective "

@@ -1,4 +1,4 @@
-"""tpulint rule implementations (R1-R5).
+"""tpulint rule implementations (R1-R4, R6).
 
 Each rule documents the incident that motivated it (VERDICT/ADVICE round
 5) next to the pattern it matches; docs/static_analysis.md is the
@@ -395,38 +395,7 @@ class _RuleWalker(ast.NodeVisitor):
                         f"{pline}); defer it behind the perf gate",
                     )
 
-        # R5: gather plans must be checked against the slot cap
-        if _terminal_name(node.func) == "build_gather_plan":
-            encl = self.func_stack[-1] if self.func_stack else ctx.tree
-            encl_name = getattr(encl, "name", "<module>")
-            if encl_name != "build_gather_plan" and not _has_cap_check(encl):
-                self._emit(
-                    "R5", node,
-                    "build_gather_plan() without a slot-cap check in the "
-                    "enclosing scope: skewed graphs inflate num_slots to "
-                    "a multiple of m (ADVICE r5 medium); compare "
-                    "plan.num_slots / use plan_within_cap before keeping "
-                    "the plan",
-                )
-
         self.generic_visit(node)
-
-
-def _has_cap_check(scope: ast.AST) -> bool:
-    """A real cap check: plan_within_cap (or the builder's max_slots=
-    abort) is used, or num_slots appears inside a COMPARISON — a bare
-    num_slots mention (telemetry logging) is not a cap."""
-    for sub in ast.walk(scope):
-        if isinstance(sub, ast.Call):
-            if _terminal_name(sub.func) == "plan_within_cap":
-                return True
-            if any(kw.arg == "max_slots" for kw in sub.keywords):
-                return True
-        if isinstance(sub, ast.Compare):
-            for part in ast.walk(sub):
-                if isinstance(part, ast.Attribute) and part.attr == "num_slots":
-                    return True
-    return False
 
 
 def run_rules(ctx: ModuleContext) -> List[Finding]:

@@ -71,7 +71,7 @@ class CoarseGraph:
 
 
 @jax.jit
-def _contract_part1(graph: DeviceGraph, labels: jax.Array, plans=None):
+def _contract_part1(graph: DeviceGraph, labels: jax.Array):
     n_pad = graph.n_pad
     node_ids = jnp.arange(n_pad, dtype=jnp.int32)
     is_real = node_ids < graph.n
@@ -94,28 +94,14 @@ def _contract_part1(graph: DeviceGraph, labels: jax.Array, plans=None):
     ).astype(WEIGHT_DTYPE)
 
     # coarse edges: route self-loops and pad edges to a trailing
-    # sentinel.  aggregate_by_key SORTS by (cu, cv), so slot order is
-    # free — with level plans, cmap[dst] runs through the lane-routed
-    # gather and cmap itself provides the validity check (-1 marks
-    # non-real endpoints, including every pad slot via owner n_pad-1).
-    if plans is not None:
-        from .lane_gather import INTERPRET, lane_gather
-
-        sentinel = jnp.int32(n_pad)
-        cu0 = cmap[plans.src_idx]
-        cv0 = lane_gather(cmap, plans.plan, interpret=INTERPRET)
-        valid = (cu0 != cv0) & (cu0 >= 0) & (cv0 >= 0)
-        cu = jnp.where(valid, cu0, sentinel)
-        cv = jnp.where(valid, cv0, sentinel)
-        w = jnp.where(valid, plans.edge_w, 0)
-    else:
-        sentinel = jnp.int32(n_pad)
-        cu = jnp.where(graph.src < graph.n, cmap[jnp.clip(graph.src, 0, n_pad - 1)], sentinel)
-        cv = jnp.where(graph.dst < graph.n, cmap[jnp.clip(graph.dst, 0, n_pad - 1)], sentinel)
-        valid = (cu != cv) & (cu < sentinel) & (cv < sentinel)
-        cu = jnp.where(valid, cu, sentinel)
-        cv = jnp.where(valid, cv, sentinel)
-        w = jnp.where(valid, graph.edge_w, 0)
+    # sentinel (aggregate_by_key sorts by (cu, cv), so slot order is free)
+    sentinel = jnp.int32(n_pad)
+    cu = jnp.where(graph.src < graph.n, cmap[jnp.clip(graph.src, 0, n_pad - 1)], sentinel)
+    cv = jnp.where(graph.dst < graph.n, cmap[jnp.clip(graph.dst, 0, n_pad - 1)], sentinel)
+    valid = (cu != cv) & (cu < sentinel) & (cv < sentinel)
+    cu = jnp.where(valid, cu, sentinel)
+    cv = jnp.where(valid, cv, sentinel)
+    w = jnp.where(valid, graph.edge_w, 0)
 
     cu_g, cv_g, w_g = aggregate_by_key(cu, cv, w)
     group_valid = (cu_g >= 0) & (cu_g < sentinel)
@@ -207,10 +193,8 @@ def contract_clustering(
     from ..resilience import maybe_inject
 
     maybe_inject("device-oom")
-    from .lane_gather import maybe_edge_plans
-
     cmap, c_n, c_node_w, cu_g, cv_g, w_g, group_valid, c_m = _contract_part1(
-        graph, labels, maybe_edge_plans(graph)  # eager: host readbacks
+        graph, labels
     )
     from ..graphs.csr import shape_floors
 

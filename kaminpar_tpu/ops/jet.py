@@ -69,15 +69,8 @@ def _delta_slots(graph: DeviceGraph) -> int | None:
     return m_slots // 4
 
 
-def _full_ratings(graph: DeviceGraph, part: jax.Array, k: int,
-                  plans=None) -> jax.Array:
-    """Full dense rating table; routes the block lookup through the lane
-    gather when the caller threaded the level's plans in (built eagerly
-    outside jit — see ops/lane_gather.py and lp.lp_cluster)."""
-    if plans is not None:
-        from .lane_gather import routed_block_ratings
-
-        return routed_block_ratings(plans, part, k, graph.n_pad)
+def _full_ratings(graph: DeviceGraph, part: jax.Array, k: int) -> jax.Array:
+    """Full dense (n_pad, k) rating table of `part`."""
     return csr_block_ratings(graph, part, k)
 
 
@@ -159,7 +152,6 @@ def _jet_iteration(
     balancer_rounds: int,
     wdeg: jax.Array | None = None,
     conn: jax.Array | None = None,
-    plans=None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """One Jet move round.  Returns (new_part, new_lock, ext_sum,
     new_conn) where ext_sum = sum over real nodes of (weighted degree -
@@ -186,7 +178,7 @@ def _jet_iteration(
     # gain-cache strategy Jet's paper assumes; caps checked by the
     # balancer, so require_fit=False like the reference's candidate step)
     if conn is None:
-        conn = _full_ratings(graph, part, k, plans)
+        conn = _full_ratings(graph, part, k)
     best, best_conn, conn_own = best_from_dense(
         conn, part, jnp.zeros((k,), ACC_DTYPE), graph.node_w,
         jnp.zeros((k,), ACC_DTYPE), salt, require_fit=False,
@@ -249,7 +241,7 @@ def _jet_iteration(
     # when few nodes changed, re-scatter only their rows
     def _conn_step(conn_, before, after):
         if dslots is None:
-            return _full_ratings(graph, after, k, plans)
+            return _full_ratings(graph, after, k)
         # degree total <= m_pad < 2^31 (device layout)
         # tpulint: disable=R3
         changed_edges = jnp.sum(
@@ -258,7 +250,7 @@ def _jet_iteration(
         return lax.cond(
             changed_edges <= dslots,
             lambda args: _conn_update_rows(graph, *args, k, dslots),
-            lambda args: _full_ratings(graph, args[2], k, plans),
+            lambda args: _full_ratings(graph, args[2], k),
             (conn_, before, after),
         )
 
@@ -345,7 +337,6 @@ def _jet_chunk(
     wdeg: jax.Array,
     max_fruitless: int,
     balancer_rounds: int,
-    plans=None,
     stats=None,
 ):
     """A bounded chunk of Jet iterations in one device program.
@@ -390,7 +381,6 @@ def _jet_chunk(
             balancer_rounds,
             wdeg=wdeg,
             conn=conn,
-            plans=plans,
         )
         # snapshot the state ENTERING this iteration (its cut falls out
         # of the rating); the state leaving the round's final iteration
@@ -462,17 +452,16 @@ def _jet_round_close(
 
 
 @partial(jax.jit, static_argnames=("k",))
-def _jet_build_conn(graph: DeviceGraph, part: jax.Array, k: int,
-                    plans=None):
+def _jet_build_conn(graph: DeviceGraph, part: jax.Array, k: int):
     """Fresh dense rating table — run once per Jet round (the in-round
     table is maintained incrementally; the round-end rollback to `best`
     invalidates it)."""
-    return _full_ratings(graph, part, k, plans)
+    return _full_ratings(graph, part, k)
 
 
 @partial(jax.jit, static_argnames=("k",))
 def _jet_init(graph: DeviceGraph, partition: jax.Array, k: int,
-              max_block_weights: jax.Array, wdeg: jax.Array, plans=None):
+              max_block_weights: jax.Array, wdeg: jax.Array):
     """Clip the input partition, build the round-0 conn table, and derive
     the starting cut FROM the table (one segment_sum instead of a
     separate edge-wide cut pass — the table is needed anyway)."""
@@ -481,7 +470,7 @@ def _jet_init(graph: DeviceGraph, partition: jax.Array, k: int,
         graph.node_w.astype(ACC_DTYPE), part0, num_segments=k
     )
     feasible = jnp.all(bw <= max_block_weights.astype(ACC_DTYPE))
-    conn = _jet_build_conn(graph, part0, k, plans)  # nested jit inlines
+    conn = _jet_build_conn(graph, part0, k)  # nested jit inlines
     cut = _conn_cut(graph, conn, part0, wdeg, k)
     # snapshots track the best FEASIBLE cut; an infeasible input (e.g.
     # everything in one block, cut 0) must not pin the snapshot
@@ -503,7 +492,6 @@ def _jet_refine_impl(
     max_fruitless: int,
     balancer_rounds: int,
     chunk: int = 4,
-    plans=None,
 ) -> jax.Array:
     # static per-node weighted degree (one streaming pass per refine
     # call, via the CSR row spans): each iteration's rating table then
@@ -514,7 +502,7 @@ def _jet_refine_impl(
     row_ptr = jnp.clip(graph.row_ptr, 0, graph.edge_w.shape[0])
     wdeg = csum0[row_ptr[1:]] - csum0[row_ptr[:-1]]
     part, best_cut, conn = _jet_init(
-        graph, partition, k, max_block_weights, wdeg, plans
+        graph, partition, k, max_block_weights, wdeg
     )
     best = part
     # scale the iteration chunk down with edge count so each launch
@@ -538,7 +526,7 @@ def _jet_refine_impl(
             # only needed on round 0 and after a rollback — the in-round
             # table is maintained incrementally and stays valid across
             # rounds whenever the round ended on its best partition
-            conn = _jet_build_conn(graph, part, k, plans)
+            conn = _jet_build_conn(graph, part, k)
         # per-round progress buffer, row-indexed by the global iteration
         # so it rides across host-driven chunks without a host pull
         stats = progress_mod.new_buffer(max_iterations, 3) if rec else None
@@ -552,7 +540,7 @@ def _jet_refine_impl(
                 jnp.float32(gain_temp), jnp.float32(fruitless_threshold),
                 seed, jnp.int32(rnd),
                 jnp.int32(min(chunk, max_iterations - i)), wdeg,
-                max_fruitless, balancer_rounds, plans, stats,
+                max_fruitless, balancer_rounds, stats,
             )
             i += chunk
             # the readback is a blocking device sync; skip it when the
@@ -672,11 +660,7 @@ def jet_refine(
         if ctx.num_fruitless_iterations > 0
         else 2**30
     )
-    from .lane_gather import maybe_edge_plans
-
-    plans = maybe_edge_plans(graph)  # eager: host readbacks
-    if plans is None:
-        count_conn_engine(graph, k)
+    count_conn_engine(graph, k)
     return _jet_refine_impl(
         graph,
         partition,
@@ -690,5 +674,4 @@ def jet_refine(
         int(max_iterations),
         int(max_fruitless),
         int(balancer_rounds),
-        plans=plans,
     )

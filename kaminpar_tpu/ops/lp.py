@@ -174,7 +174,6 @@ def lp_round(
     cfg: LPConfig,
     communities: jax.Array | None = None,
     rows=None,
-    plans=None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """One bulk-synchronous LP round.
 
@@ -240,35 +239,15 @@ def lp_round(
         # flowing — the reads are n-wide gathers, essentially free.
         avg_degree = graph.m_pad / max(C, 1)
         K = cfg.topk if avg_degree <= 32 else max(cfg.topk, 16)
-        if plans is not None and rows is None:
-            from .lane_gather import INTERPRET, lane_gather
-
-            # lane-routed full round: labels[dst] via the Pallas
-            # dynamic_gather kernel (streaming speed) in the plan's slot
-            # order; the rating sort re-groups by owner anyway, and the
-            # own-connection rides sort1 as a 4th operand, so nothing
-            # ever returns to CSR order (ops/lane_gather.py rationale)
-            nb_r = lane_gather(labels, plans.plan, interpret=INTERPRET)
-            own_rt = labels[plans.src_idx]
-            w_own_r = jnp.where(nb_r == own_rt, plans.edge_w, 0)
-            topk, w_cur = rating_topk_rows(
-                plans.owner_key, nb_r, plans.edge_w,
-                graph.row_ptr[1:], graph.degrees, salt, K,
-                w_own=w_own_r,
-            )
-            labs = topk[0::2]
-            vals = topk[1::2]
-            own = labels
-        else:
-            nb = jnp.where(valid, labels[dst_b], -1) if rows is not None else (
-                labels[dst_b]
-            )
-            own_slot = labels[owner_c]
-            topk = rating_topk_rows(owner_key, nb, w_b, end, deg_eff, salt, K)
-            labs = topk[0::2]
-            vals = topk[1::2]
-            own = labels
-            w_cur = connection_to_own_rows(nb, w_b, own_slot, start, end)
+        nb = jnp.where(valid, labels[dst_b], -1) if rows is not None else (
+            labels[dst_b]
+        )
+        own_slot = labels[owner_c]
+        topk = rating_topk_rows(owner_key, nb, w_b, end, deg_eff, salt, K)
+        labs = topk[0::2]
+        vals = topk[1::2]
+        own = labels
+        w_cur = connection_to_own_rows(nb, w_b, own_slot, start, end)
 
         def fits(lab):
             lab_c = jnp.clip(lab, 0, C - 1)
@@ -392,12 +371,7 @@ def lp_round(
         best = jnp.where(barred, -1, best)
         best_w = jnp.where(barred, INT32_MIN, best_w)
     elif engine == "dense":
-        if plans is not None and rows is None:
-            from .lane_gather import routed_block_ratings
-
-            conn = routed_block_ratings(plans, labels, C, n_pad)
-        else:
-            conn = dense_block_ratings(owner_c, dst_b, w_b, labels, n_pad, C)
+        conn = dense_block_ratings(owner_c, dst_b, w_b, labels, n_pad, C)
         best, best_w, w_cur = best_from_dense(
             conn, labels, cluster_weights, graph.node_w, cap, salt,
             communities=communities,
@@ -563,8 +537,6 @@ def _round_with_delta(
     cfg: LPConfig,
     communities: jax.Array | None,
     i: jax.Array,
-
-    plans=None,
 ):
     """One LP round, delta-dispatched: after the first round, when the
     active nodes' rows fit the m_pad/4 buffer, run the round on the
@@ -581,7 +553,7 @@ def _round_with_delta(
     if dslots is None:
         return lp_round(
             graph, labels, weights, max_cluster_weight, active, salt, cfg,
-            communities=communities, plans=plans,
+            communities=communities,
         )
     deg = graph.degrees
 
@@ -597,7 +569,7 @@ def _round_with_delta(
         labels, weights, active = op
         return lp_round(
             graph, labels, weights, max_cluster_weight, active, salt, cfg,
-            communities=communities, plans=plans,
+            communities=communities,
         )
 
     # active-degree total <= m_pad < 2^31 (device layout)
@@ -616,13 +588,12 @@ def _lp_cluster_impl(
     cfg: LPConfig,
     num_iterations: int | None,
     has_communities: bool,
-    plans=None,
     stats=None,
 ):
     iters = num_iterations if num_iterations is not None else cfg.num_iterations
     comm = communities if has_communities else None
     labels, weights, stats = _lp_cluster_fused_rounds(
-        graph, max_cluster_weight, seed, comm, cfg, iters, plans, stats
+        graph, max_cluster_weight, seed, comm, cfg, iters, stats
     )
     labels = _lp_cluster_postpasses_traced(
         graph, labels, weights, max_cluster_weight, seed, cfg,
@@ -663,7 +634,6 @@ def _lp_cluster_chunked(
     cfg: LPConfig,
     iters: int,
     has_communities: bool,
-    plans=None,
 ) -> jax.Array:
     """One clustering round per launch — the TPU-worker watchdog guard
     above the fused budget (a multi-round fused clustering loop at
@@ -699,7 +669,7 @@ def _lp_cluster_chunked(
                                     kind="lp-round")
         labels, weights, active, moved = _lp_cluster_round_launch(
             graph, labels, weights, max_cluster_weight, active,
-            salt, jnp.int32(i), cfg, comm, plans,
+            salt, jnp.int32(i), cfg, comm,
         )
         ledger.donation_end(tok)
         record_transfer("d2h", getattr(moved, "nbytes", 8),
@@ -728,30 +698,30 @@ def _lp_cluster_chunked(
          donate_argnums=(1, 2, 4))
 def _lp_cluster_round_launch_jit(
     graph, labels, weights, max_cluster_weight, active, salt, i,
-    cfg: LPConfig, communities, has_comm: bool, plans=None,
+    cfg: LPConfig, communities, has_comm: bool,
 ):
     return _round_with_delta(
         graph, labels, weights, max_cluster_weight, active, salt, cfg,
-        communities if has_comm else None, i, plans=plans,
+        communities if has_comm else None, i,
     )
 
 
 def _lp_cluster_round_launch(
     graph, labels, weights, max_cluster_weight, active, salt, i,
-    cfg: LPConfig, comm, plans=None,
+    cfg: LPConfig, comm,
 ):
     has_comm = comm is not None
     # the dummy is a 1-element array (never read when has_comm is False)
     return _lp_cluster_round_launch_jit(
         graph, labels, weights, max_cluster_weight, active, salt, i, cfg,
         comm if has_comm else jnp.zeros(1, dtype=jnp.int32),
-        has_comm, plans,
+        has_comm,
     )
 
 
 def _lp_cluster_fused_rounds(
     graph, max_cluster_weight, seed, comm, cfg: LPConfig, iters: int,
-    plans=None, stats=None,
+    stats=None,
 ):
     """The fused multi-round clustering loop (one launch).
 
@@ -773,7 +743,7 @@ def _lp_cluster_fused_rounds(
         salt = (seed.astype(jnp.int32) * 131071 + i * 1566083941) & 0x7FFFFFFF
         labels, weights, active, moved = _round_with_delta(
             graph, labels, weights, max_cluster_weight, active, salt,
-            cfg, comm, i, plans=plans,
+            cfg, comm, i,
         )
         if stats is not None:  # trace-time guard (None adds no carry)
             stats = progress_mod.record(
@@ -805,24 +775,18 @@ def lp_cluster(
 
     Returns i32[n_pad] cluster labels (values are node ids; pad slots keep
     their own id)."""
-    from .lane_gather import maybe_edge_plans
     from .segments import MAX_FUSED_EDGE_SLOTS
 
     has_comm = communities is not None
     iters = (
         num_iterations if num_iterations is not None else cfg.num_iterations
     )
-    # plan building does host readbacks, so it happens HERE (eagerly,
-    # outside jit) and the plan rides into the traced rounds as an
-    # ordinary pytree argument — NEVER as a captured constant, which the
-    # shape-bucketed jit cache would wrongly share across levels
-    plans = maybe_edge_plans(graph)
     if graph.src.shape[0] > MAX_FUSED_EDGE_SLOTS and iters > 1:
         # watchdog guard: the dispatch must stay OUTSIDE jit — the
         # chunked loop reads the convergence flag back per round
         return _lp_cluster_chunked(
             graph, max_cluster_weight, seed, communities, cfg, iters,
-            has_comm, plans,
+            has_comm,
         )
     if communities is None:
         communities = jnp.zeros(graph.n_pad, dtype=jnp.int32)
@@ -835,7 +799,6 @@ def lp_cluster(
             cfg,
             num_iterations,
             has_comm,
-            plans,
             stats,
         ),
         "lp", ("moved", "active"), rows=iters, phase="cluster",
@@ -845,10 +808,9 @@ def lp_cluster(
 # round carry (part, bw, active) donated — see _lp_cluster_round_launch_jit
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1, 2, 4))
 def _lp_refine_round_launch(graph, part, bw, max_block_weights, active,
-                            salt, i, cfg: LPConfig, plans=None):
+                            salt, i, cfg: LPConfig):
     return _round_with_delta(
         graph, part, bw, max_block_weights, active, salt, cfg, None, i,
-        plans=plans,
     )
 
 
@@ -868,15 +830,12 @@ def lp_refine(
     active set and moved==0 convergence exit across launches."""
     from .segments import MAX_FUSED_EDGE_SLOTS
 
-    from .lane_gather import maybe_edge_plans
-
     iters = num_iterations if num_iterations is not None else cfg.num_iterations
     if not cfg.refinement:
         # normalize once for BOTH launch strategies so the chunked path
         # never runs with clustering semantics (tie moves, no positive-gain
         # restriction); replace() preserves the caller's engine settings
         cfg = replace(cfg, allow_tie_moves=False, refinement=True)
-    plans = maybe_edge_plans(graph)  # eager: host readbacks (see lp_cluster)
     if graph.src.shape[0] > MAX_FUSED_EDGE_SLOTS and iters > 1:
         from ..caching import record_transfer
         from ..telemetry import ledger
@@ -900,7 +859,7 @@ def lp_refine(
                                         kind="lp-round")
             part, bw, active, moved = _lp_refine_round_launch(
                 graph, part, bw, max_block_weights, active, salt,
-                jnp.int32(i), cfg, plans
+                jnp.int32(i), cfg
             )
             ledger.donation_end(tok)
             record_transfer("d2h", getattr(moved, "nbytes", 8),
@@ -919,7 +878,7 @@ def lp_refine(
     return progress_mod.instrumented(
         lambda stats: _lp_refine_fused(
             graph, partition, k, max_block_weights, seed, cfg, iters,
-            plans, stats,
+            stats,
         ),
         "lp", ("moved", "active"), rows=iters, phase="refine",
     )
@@ -934,7 +893,6 @@ def _lp_refine_fused(
     seed: jax.Array,
     cfg: LPConfig = LPConfig(refinement=True),
     num_iterations: int | None = None,
-    plans=None,
     stats=None,
 ):
     """LP refinement (analog of LabelPropagationRefiner,
@@ -961,7 +919,6 @@ def _lp_refine_fused(
         salt = (seed.astype(jnp.int32) * 92821 + i * 1566083941) & 0x7FFFFFFF
         part, bw, active, moved = _round_with_delta(
             graph, part, bw, max_block_weights, active, salt, cfg, None, i,
-            plans=plans,
         )
         if stats is not None:  # trace-time guard (None adds no carry)
             stats = progress_mod.record(

@@ -36,10 +36,6 @@ Engines (see docs/performance.md "Rating engines"):
   * ``dense``    — the exact (n, k) table for refinement-sized label
     spaces (ops/segments.dense_block_ratings).
 
-An optional Pallas kernel for the rate+argmax core over the slot tables
-sits behind the same lazy platform gate as ops/lane_gather (TPU-class
-backends only, env-gated); the fused-lax path is the portable default.
-
 All engines share the SAME tie-break hash (hash_u32 of the candidate
 label under the round salt), so two engines that rate the same
 candidate set pick the SAME cluster — the engine-equivalence contract
@@ -48,7 +44,6 @@ tests/test_rating.py pins.
 
 from __future__ import annotations
 
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -83,8 +78,6 @@ DEFAULT_NUM_SLOTS = 32
 #: rounds, and a lower threshold flips late rounds into paying BOTH
 #: the table build and the sort).
 SCATTER_FALLBACK_FRAC = 0.5
-
-ENV_PALLAS = "KAMINPAR_TPU_RATING_PALLAS"
 
 
 # ---------------------------------------------------------------------------
@@ -365,90 +358,6 @@ def best_from_slots(
     )
 
 
-# ---------------------------------------------------------------------------
-# optional Pallas rate+argmax core (lazy platform gate; lax is default)
-# ---------------------------------------------------------------------------
-
-
-def rating_pallas_requested() -> bool:
-    """The opt-in env gate, mirroring ops/lane_gather's contract: the
-    Pallas core only runs on TPU-class backends AND when explicitly
-    requested — the fused-lax path is the portable default."""
-    if os.environ.get(ENV_PALLAS, "") != "1":
-        return False
-    try:
-        from ..utils import platform
-
-        return platform.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
-def best_from_slots_pallas(
-    slot_label: jax.Array,
-    slot_w: jax.Array,
-    labels: jax.Array,
-    tie_salt,
-    interpret: bool = False,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Pallas row-wise rate+argmax over the slot tables: per row the
-    best non-own (label, weight) pair plus the own connection, with the
-    shared tie-break hash.  Feasibility (weight caps, communities) is
-    applied by the caller at node level — the kernel only needs the
-    row-local reduction, which is the part worth keeping in VMEM.
-
-    Unlike the full best_from_slots this does NOT mask infeasible
-    targets, so it serves the unconstrained rating uses (two-hop
-    favored clusters, candidate pre-ranking); `interpret=True` runs the
-    same kernel through the Pallas interpreter for CPU tests.
-    """
-    from jax.experimental import pallas as pl
-
-    n_pad, S = slot_label.shape
-
-    def kernel(lab_ref, w_ref, own_ref, out_lab, out_w, out_own):
-        lab = lab_ref[...]
-        w = w_ref[...]
-        own = own_ref[...]
-        own_b = own[:, None]
-        w_own = jnp.max(jnp.where(lab == own_b, w, 0), axis=1)
-        feas = (lab >= 0) & (lab != own_b)
-        score = jnp.where(feas, w, INT32_MIN)
-        best_w = jnp.max(score, axis=1)
-        is_best = feas & (score == best_w[:, None])
-        tb = hash_u32(lab, tie_salt)
-        best_tb = jnp.max(jnp.where(is_best, tb, -1), axis=1)
-        winner = is_best & (tb == best_tb[:, None])
-        best = jnp.max(jnp.where(winner, lab, -1), axis=1)
-        has = best_w > INT32_MIN
-        out_lab[...] = jnp.where(has, best, -1)
-        out_w[...] = jnp.where(has, best_w, INT32_MIN)
-        out_own[...] = w_own
-
-    rows = min(512, n_pad)  # n_pad is a power-of-two bucket
-    grid = (max(n_pad // rows, 1),)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((rows, S), lambda i: (i, 0)),
-            pl.BlockSpec((rows, S), lambda i: (i, 0)),
-            pl.BlockSpec((rows,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((rows,), lambda i: (i,)),
-            pl.BlockSpec((rows,), lambda i: (i,)),
-            pl.BlockSpec((rows,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad,), slot_w.dtype),
-            jax.ShapeDtypeStruct((n_pad,), slot_w.dtype),
-        ],
-        interpret=interpret,
-    )(slot_label, slot_w, labels)
-
-
 # Re-exports: the dense refinement core lives in segments.py for
 # historical import-cycle reasons; rating.py is its public home so LP,
 # Jet and the dist kernels share one rating surface.
@@ -459,8 +368,6 @@ __all__ = [
     "select_engine",
     "scatter_slot_ratings",
     "best_from_slots",
-    "best_from_slots_pallas",
-    "rating_pallas_requested",
     "dense_block_ratings",
     "best_from_dense",
 ]

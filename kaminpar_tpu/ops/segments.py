@@ -838,7 +838,6 @@ def rating_topk_rows(
     deg: jax.Array,
     salt,
     k_best: int,
-    w_own: jax.Array | None = None,
 ) -> Tuple[jax.Array, ...]:
     """Top-k_best rated clusters per row, from row-grouped
     (owner, neighbor-label, weight) triples.
@@ -859,23 +858,8 @@ def rating_topk_rows(
     change that gives node n_pad-1 real edges would silently corrupt the
     top-K reads — keep the last pad row empty (see
     DeviceGraph.from_host's padding contract).
-
-    `w_own` (optional, per SLOT: the slot's weight where its neighbor
-    label equals the owner's label, else 0) rides sort1 as an extra
-    operand; the per-node own-connection then falls out of one cumsum at
-    the row boundaries and the return becomes (topk_tuple, w_cur).  This
-    serves the lane-routed rating path (ops/lane_gather.py), whose slot
-    order is NOT row-grouped — the owner-sort both engines already do
-    restores the spans.
     """
-    has_own = w_own is not None
-    if has_own:
-        o_s, nb_s, w_s, wo_s = lax.sort(
-            (owner_key, nb, w.astype(ACC_DTYPE), w_own.astype(ACC_DTYPE)),
-            num_keys=2,
-        )
-    else:
-        o_s, nb_s, w_s = sort_by_two_keys(owner_key, nb, w.astype(ACC_DTYPE))
+    o_s, nb_s, w_s = sort_by_two_keys(owner_key, nb, w.astype(ACC_DTYPE))
     prev_o = jnp.concatenate([jnp.array([-1], o_s.dtype), o_s[:-1]])
     prev_nb = jnp.concatenate([jnp.array([-1], nb_s.dtype), nb_s[:-1]])
     new_grp = (o_s != prev_o) | (nb_s != prev_nb)
@@ -894,13 +878,7 @@ def rating_topk_rows(
         validj = (deg > j) & (prio2[posj] >= 0)
         out.append(jnp.where(validj, lab2[posj], -1))
         out.append(jnp.where(validj, prio2[posj], INT32_MIN))
-    if not has_own:
-        return tuple(out)
-    csum = jnp.cumsum(wo_s)
-    csum0 = jnp.concatenate([jnp.zeros(1, dtype=csum.dtype), csum])
-    start = jnp.clip(end - deg, 0, D)
-    w_cur = csum0[jnp.clip(end, 0, D)] - csum0[start]
-    return tuple(out), w_cur
+    return tuple(out)
 
 
 def connection_to_own_rows(

@@ -209,11 +209,12 @@ class DeepMultilevelPartitioner:
         # --- uncoarsen: refine / extend / repeat (:275-365) ---
         if num_levels is None:
             num_levels = coarsener.level + 1
-        # debug hierarchy dumps are STAGED: device partitions are
-        # collected by reference during the span and pulled to host only
+        # debug hierarchy dumps are STAGED: a device copy of each level's
+        # partition is collected during the span and pulled to host only
         # after it closes, so the uncoarsening span never carries the
-        # readback (tpulint R1).  Debug-only path: the held references
-        # keep each level's partition alive until the dump.
+        # readback (tpulint R1).  A copy, not a reference: the next
+        # level's projection donates the partition's buffer
+        # (coarsener._project_partition_donated).
         pending_dumps: List[Tuple[int, object, int]] = []
         with timer.scoped_timer("uncoarsening"):
             level = coarsener.level
@@ -263,7 +264,7 @@ class DeepMultilevelPartitioner:
                 )
                 if ctx.debug.dump_partition_hierarchy:
                     pending_dumps.append(
-                        (level, partition, coarsener.current_n)
+                        (level, jnp.copy(partition), coarsener.current_n)
                     )
                 part_now = partition
                 spans_now = spans

@@ -37,7 +37,6 @@ from .errors import (  # noqa: F401
     DeviceOOM,
     IntegrityViolation,
     NativeUnavailable,
-    PlanBlowup,
     RankDivergence,
     RefinerRefused,
     StageHang,

@@ -1,9 +1,9 @@
 """Structured degradation exceptions — the failure vocabulary of the
 pipeline's optional fast paths.
 
-Every optional accelerator path (native FM/IP via the C-API, routed
-lane-gather plans, compressed-graph streaming, device balancers,
-distributed collectives) can refuse, crash, or time out.  Instead of a
+Every optional accelerator path (native FM/IP via the C-API,
+compressed-graph streaming, device balancers, distributed collectives)
+can refuse, crash, or time out.  Instead of a
 bare ``except Exception`` at each call site (a tpulint-documented hazard,
 docs/static_analysis.md), failures are raised as one of these types and
 routed through :func:`kaminpar_tpu.resilience.with_fallback`, which pairs
@@ -56,15 +56,6 @@ class NativeUnavailable(DegradationError):
     """The native (C++/ctypes) component could not be built, loaded, or
     run — missing toolchain, build timeout, or a corrupted build cache.
     Fallback: the pure-numpy/ctypes-free twin of the same entry point."""
-
-
-class PlanBlowup(DegradationError):
-    """A routed lane-gather plan would exceed its slot budget (one
-    high-degree hub inflating H*128 past PLAN_MAX_SLOT_RATIO * m).
-    Fallback: the plain XLA gather.  A refusal, not a fault: does not
-    advance the circuit breaker."""
-
-    breaker_relevant = False
 
 
 class RefinerRefused(DegradationError):

@@ -56,7 +56,6 @@ from .errors import (
     DeviceOOM,
     IntegrityViolation,
     NativeUnavailable,
-    PlanBlowup,
     RankDivergence,
     RefinerRefused,
     StageHang,
@@ -110,11 +109,6 @@ _register(SiteSpec(
     "device-balancer", DeviceOOM,
     "exact greedy host balancer",
     "device overload-balancing rounds (ops/balancer.py)",
-))
-_register(SiteSpec(
-    "lane-gather", PlanBlowup,
-    "plain XLA gather (no routed plan for the level)",
-    "routed lane-gather plan build (ops/lane_gather.py)",
 ))
 _register(SiteSpec(
     "compressed-stream", DeviceOOM,

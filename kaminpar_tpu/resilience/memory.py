@@ -30,8 +30,8 @@ Three mechanisms, one module:
     partition call.  On a classified ``DeviceOOM`` anywhere under
     ``compute_partition`` it unwinds cleanly (force-closes timer scopes
     opened by the failed attempt via the PR-5 ``Timer.unwind`` idiom,
-    sheds the registered bounded caches with ``evict_to``, drops routed
-    gather plans, collects garbage) and retries at the next rung:
+    sheds the registered bounded caches with ``evict_to``, collects
+    garbage) and retries at the next rung:
 
       ====  =========================================================
       rung  behavior
@@ -472,21 +472,13 @@ def register_shed_target(cache: Any) -> None:
 
 def shed_caches(target_bytes: int = 0) -> int:
     """Evict every registered cache down to ``target_bytes`` (pressure
-    cause); also drops the routed lane-gather plans, which pin O(m)
-    device memory for graphs that may already be dead.  Returns the
-    cache bytes freed."""
+    cause).  Returns the cache bytes freed."""
     freed = 0
     for cache in list(_shed_targets):
         try:
             freed += int(cache.evict_to(target_bytes, cause="pressure"))
         except Exception:
             continue
-    try:
-        from ..ops.lane_gather import clear_plan_cache
-
-        clear_plan_cache()
-    except Exception:
-        pass
     st = state()
     if st is not None:
         st.shed_bytes += freed
@@ -613,7 +605,7 @@ def _recover(st: GovernorState, depth: int, err: DeviceOOM) -> None:
     """Unwind one failed rung attempt: force-close the timer scopes it
     left open (Timer.unwind_to — the exception already closed scoped
     ones; this catches scopes opened by code that died between
-    __enter__s), shed the bounded caches and gather plans, and collect
+    __enter__s), shed the bounded caches, and collect
     garbage so the dead attempt's device arrays are actually freed
     before the next rung allocates."""
     from ..utils import timer

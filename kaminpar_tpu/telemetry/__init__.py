@@ -6,9 +6,9 @@ timer.{h,cc}, kaminpar-dist/timer.cc).  This package is the shared stream
 those utilities publish into here: every `utils.timer` scope exit emits a
 structured *span* (name, dotted path, wall time, optional sync time,
 host/HBM peaks when heap profiling is on, statistics-counter deltas), and
-discrete runtime decisions that previously vanished — the lane-gather
-support-probe verdict, jit (re)traces of collective phases, native FM
-refusals, host balancer fallbacks — are recorded as one-shot *events*.
+discrete runtime decisions that previously vanished — jit (re)traces of
+collective phases, native FM refusals, host balancer fallbacks — are
+recorded as one-shot *events*.
 
 Two exporters consume the stream:
 

@@ -4,8 +4,8 @@ One machine-readable artifact per partition call, the analog of the
 reference's parseable RESULT + TIME output promoted to a single schema:
 scope tree (from the hierarchical timer), result metrics, per-level
 graph sizes (from the coarsener's telemetry events), the collective
-traffic table (parallel/mesh comm accounting), the lane-gather probe
-verdict, statistics counters, and an environment stamp.  `bench.py`
+traffic table (parallel/mesh comm accounting), statistics counters, and
+an environment stamp.  `bench.py`
 embeds the same dict into its BENCH line so ad-hoc runs and the perf
 trajectory share one schema.
 
@@ -180,7 +180,6 @@ def build_run_report(extra_run: Optional[dict] = None) -> dict:
     Call after `compute_partition` returns (the facade annotates the run
     and result sections during the call); `extra_run` keys (e.g. CLI io /
     wall seconds) are merged into the `run` section."""
-    from ..ops import lane_gather
     from ..utils import statistics, timer
 
     info = _run_info()
@@ -340,7 +339,6 @@ def build_run_report(extra_run: Optional[dict] = None) -> dict:
         "comm": comm,
         "events": [e.to_dict() for e in _events()],
         "counters": statistics.as_dict() if statistics.enabled() else {},
-        "lane_gather": lane_gather.probe_status(),
         # resilience sections: the active fault plan (and every injected
         # fault), each degradation the policy wrapper recorded, and the
         # output gate's verdict — the run report is the audit trail of

@@ -146,7 +146,6 @@ def _minimal_v1_report() -> dict:
         "comm": {"caveat": "none", "records": []},
         "events": [],
         "counters": {},
-        "lane_gather": {"mode": "not-probed"},
         "faults": {"plan": None, "sites": [], "injected": []},
         "degraded": [],
         "output_gate": {"checked": False},

@@ -56,8 +56,29 @@ def _clear_jax_caches_per_module():
     jax.clear_caches()
 
 
+@pytest.fixture(scope="session")
+def rgg2d_files(tmp_path_factory):
+    """The 1,024-node sample graph (the size and average degree of the
+    reference's misc/rgg2d.metis), generated from a seed and written
+    once a session in the three formats the IO tests compare."""
+    from kaminpar_tpu.graphs.factories import make_rgg2d
+    from kaminpar_tpu.io import write_metis, write_parhip
+
+    root = tmp_path_factory.mktemp("sample")
+    g = make_rgg2d(1024, avg_degree=8, seed=1)
+    write_metis(g, str(root / "rgg2d.metis"))
+    write_parhip(g, str(root / "rgg2d-32bit.parhip"), use_32bit=True)
+    write_parhip(g, str(root / "rgg2d-64bit.parhip"), use_32bit=False)
+    return root
+
+
+@pytest.fixture(scope="session")
+def rgg2d_path(rgg2d_files):
+    return str(rgg2d_files / "rgg2d.metis")
+
+
 @pytest.fixture
-def rgg2d():
+def rgg2d(rgg2d_path):
     from kaminpar_tpu.io import load_graph
 
-    return load_graph("/root/reference/misc/rgg2d.metis")
+    return load_graph(rgg2d_path)

@@ -16,8 +16,6 @@ from kaminpar_tpu.cli import (
 )
 from kaminpar_tpu.presets import create_context_by_preset_name
 
-RGG = "/root/reference/misc/rgg2d.metis"
-
 
 def test_dump_config_roundtrips_through_toml(tmp_path):
     import tomllib
@@ -30,12 +28,12 @@ def test_dump_config_roundtrips_through_toml(tmp_path):
     assert context_to_dict(ctx2) == context_to_dict(ctx)
 
 
-def test_cli_partitions_and_writes_output(tmp_path, capfd):
+def test_cli_partitions_and_writes_output(rgg2d_path, tmp_path, capfd):
     out = tmp_path / "part.txt"
     sizes = tmp_path / "sizes.txt"
     rc = main(
         [
-            RGG,
+            rgg2d_path,
             "-k",
             "4",
             "-e",
@@ -60,20 +58,20 @@ def test_cli_partitions_and_writes_output(tmp_path, capfd):
     assert bs.sum() == 1024
 
 
-def test_cli_config_file_override(tmp_path):
+def test_cli_config_file_override(rgg2d_path, tmp_path):
     cfg = tmp_path / "cfg.toml"
     cfg.write_text("[coarsening]\ncontraction_limit = 123\n")
     parser = build_parser()
-    args = parser.parse_args([RGG, "-k", "2", "-C", str(cfg)])
+    args = parser.parse_args([rgg2d_path, "-k", "2", "-C", str(cfg)])
     from kaminpar_tpu.cli import make_context
 
     ctx = make_context(args)
     assert ctx.coarsening.contraction_limit == 123
 
 
-def test_cli_refinement_override():
+def test_cli_refinement_override(rgg2d_path):
     parser = build_parser()
-    args = parser.parse_args([RGG, "-k", "2", "--refinement", "lp;jet"])
+    args = parser.parse_args([rgg2d_path, "-k", "2", "--refinement", "lp;jet"])
     from kaminpar_tpu.cli import make_context
     from kaminpar_tpu.context import RefinementAlgorithm
 
@@ -84,13 +82,13 @@ def test_cli_refinement_override():
     ]
 
 
-def test_cli_errors_without_k(capfd):
-    assert main([RGG]) == 1
+def test_cli_errors_without_k(rgg2d_path, capfd):
+    assert main([rgg2d_path]) == 1
     assert main([]) == 1
 
 
-def test_cli_machine_timers(capfd):
-    rc = main([RGG, "-k", "2", "--machine-timers"])
+def test_cli_machine_timers(rgg2d_path, capfd):
+    rc = main([rgg2d_path, "-k", "2", "--machine-timers"])
     assert rc == 0
     out = capfd.readouterr().out
     line = [l for l in out.splitlines() if l.startswith("TIMERS ")]
@@ -101,20 +99,20 @@ def test_cli_machine_timers(capfd):
     assert any(key.startswith("partitioning.") for key in pairs)
 
 
-def test_cli_degree_bucket_ordering_outputs_file_order(tmp_path):
+def test_cli_degree_bucket_ordering_outputs_file_order(rgg2d_path, tmp_path):
     """--node-ordering reorders internally but the written partition is
     in original file order (permutation-aware output)."""
     out_nat = tmp_path / "nat.txt"
     out_db = tmp_path / "db.txt"
     remap = tmp_path / "remap.txt"
-    assert main([RGG, "-k", "4", "-q", "-o", str(out_nat)]) == 0
-    assert main([RGG, "-k", "4", "-q", "--node-ordering", "degree-buckets",
+    assert main([rgg2d_path, "-k", "4", "-q", "-o", str(out_nat)]) == 0
+    assert main([rgg2d_path, "-k", "4", "-q", "--node-ordering", "degree-buckets",
                  "-o", str(out_db), "--output-remapping", str(remap)]) == 0
     mapping = np.loadtxt(remap, dtype=np.int64)
     assert sorted(mapping.tolist()) == list(range(1024))
     from kaminpar_tpu.io import load_graph
 
-    g = load_graph(RGG)
+    g = load_graph(rgg2d_path)
     src, dst = g.edge_sources(), g.adjncy
     for path in (out_nat, out_db):
         part = np.loadtxt(path, dtype=np.int64)

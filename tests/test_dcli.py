@@ -4,13 +4,11 @@ import numpy as np
 
 from kaminpar_tpu.dcli import main
 
-RGG = "/root/reference/misc/rgg2d.metis"
 
-
-def test_dcli_partitions_file_graph(tmp_path, capfd):
+def test_dcli_partitions_file_graph(rgg2d_path, tmp_path, capfd):
     out = tmp_path / "part.txt"
     rc = main(
-        [RGG, "-k", "4", "-n", "2", "-o", str(out), "-T", "--validate"]
+        [rgg2d_path, "-k", "4", "-n", "2", "-o", str(out), "-T", "--validate"]
     )
     assert rc == 0
     captured = capfd.readouterr()
@@ -41,18 +39,18 @@ def test_dcli_streamed_generator_input(capfd):
     assert rc == 0
 
 
-def test_dcli_errors_without_k(capfd):
-    assert main([RGG]) == 1
+def test_dcli_errors_without_k(rgg2d_path, capfd):
+    assert main([rgg2d_path]) == 1
     assert "need -k" in capfd.readouterr().err
 
 
-def test_dcli_compressed_input(tmp_path, capfd):
+def test_dcli_compressed_input(rgg2d_path, tmp_path, capfd):
     """dKaMinPar decodes compressed graphs eagerly (terapart input)."""
     from kaminpar_tpu.graphs.compressed import compress_host_graph
     from kaminpar_tpu.io import load_graph, write_compressed
 
     path = str(tmp_path / "rgg2d.npz")
-    write_compressed(path, compress_host_graph(load_graph(RGG)))
+    write_compressed(path, compress_host_graph(load_graph(rgg2d_path)))
     rc = main([path, "-k", "2", "-n", "2", "-f", "compressed", "-q"])
     assert rc == 0
 

@@ -8,13 +8,11 @@ import numpy as np
 from kaminpar_tpu.cli import main
 from kaminpar_tpu.io import load_graph
 
-RGG = "/root/reference/misc/rgg2d.metis"
 
-
-def test_debug_dumps_write_hierarchy_files(tmp_path):
+def test_debug_dumps_write_hierarchy_files(rgg2d_path, tmp_path):
     rc = main(
         [
-            RGG, "-k", "4", "-q",
+            rgg2d_path, "-k", "4", "-q",
             # rgg2d is below the default contraction limit (no levels);
             # force a real hierarchy so the per-level dumps exist
             "--contraction-limit", "64",
@@ -28,7 +26,7 @@ def test_debug_dumps_write_hierarchy_files(tmp_path):
 
     # toplevel graph round-trips through the METIS writer
     top = load_graph(str(tmp_path / "rgg2d.toplevel.metis"))
-    orig = load_graph(RGG)
+    orig = load_graph(rgg2d_path)
     assert top.n == orig.n and top.m == orig.m
 
     # toplevel partition matches the input size and k

@@ -36,15 +36,15 @@ def test_parse_metis_comments_and_isolated():
     assert g.degrees()[2] == 0
 
 
-def test_reference_sample_graphs_agree():
-    metis = load_metis("/root/reference/misc/rgg2d.metis")
-    p32 = load_parhip("/root/reference/misc/rgg2d-32bit.parhip")
-    p64 = load_parhip("/root/reference/misc/rgg2d-64bit.parhip")
+def test_sample_graph_formats_agree(rgg2d_files):
+    metis = load_metis(str(rgg2d_files / "rgg2d.metis"))
+    p32 = load_parhip(str(rgg2d_files / "rgg2d-32bit.parhip"))
+    p64 = load_parhip(str(rgg2d_files / "rgg2d-64bit.parhip"))
     for other in (p32, p64):
         assert np.array_equal(metis.xadj, other.xadj)
         assert np.array_equal(metis.adjncy, other.adjncy)
     validate(metis)
-    assert metis.n == 1024 and metis.m == 2 * 4113
+    assert metis.n == 1024 and metis.m == 2 * 3911
 
 
 def test_metis_round_trip(tmp_path):
@@ -85,16 +85,14 @@ def test_load_graph_auto_detect(tmp_path):
     assert load_graph(pp).m == g.m
 
 
-def test_load_graph_degree_bucket_ordering(tmp_path):
+def test_load_graph_degree_bucket_ordering(rgg2d_path, tmp_path):
     """read_graph NodeOrdering analog: degree-buckets rearrangement."""
     import numpy as np
 
     from kaminpar_tpu.io import load_graph, write_remapping
 
-    g_nat = load_graph("/root/reference/misc/rgg2d.metis")
-    g_db = load_graph(
-        "/root/reference/misc/rgg2d.metis", ordering="degree-buckets"
-    )
+    g_nat = load_graph(rgg2d_path)
+    g_db = load_graph(rgg2d_path, ordering="degree-buckets")
     assert g_db.n == g_nat.n and g_db.m == g_nat.m
     deg = np.diff(g_db.xadj)
     # bucket = floor(log2(deg)) + 1 (0 for isolated) must be sorted

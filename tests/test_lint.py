@@ -63,7 +63,6 @@ BAD_EXPECT = {
     "r2_bad.py": [("R2", 5), ("R2", 9)],
     "r3_bad.py": [("R3", 7), ("R3", 11), ("R3", 16), ("R3", 21)],
     "r4_bad.py": [("R4", 10), ("R4", 17), ("R4", 23)],
-    "r5_bad.py": [("R5", 6), ("R5", 10), ("R5", 18)],
     "r6_bad.py": [("R6", 7), ("R6", 11), ("R6", 15), ("R6", 19)],
     # SPMD collective symmetry: direct, helper-reached, and loop-guarded
     "r7_bad.py": [("R7", 18), ("R7", 24), ("R7", 30)],
@@ -83,7 +82,7 @@ def test_rule_fires_on_bad_fixture(name):
     "name", ["r1_good.py", "r1_quality_good.py", "r1_stream_good.py",
              "r1_dynamic_good.py", "r1_helper_good.py", "r1_ledger_good.py",
              "r1_supervisor_good.py", "r1_metrics_good.py", "r2_good.py",
-             "r3_good.py", "r4_good.py", "r5_good.py", "r6_good.py",
+             "r3_good.py", "r4_good.py", "r6_good.py",
              "r7_good.py", "r8_good.py"]
 )
 def test_rule_silent_on_good_fixture(name):
@@ -155,9 +154,9 @@ def test_baseline_roundtrip_and_diff(tmp_path):
     assert diff.stale == []
 
     # a fresh finding not in the baseline is NEW
-    extra = _findings("r5_bad.py")
+    extra = _findings("r4_bad.py")
     diff = diff_against_baseline(findings + extra, entries)
-    assert [f.rule for f in diff.new] == ["R5"] * len(extra)
+    assert [f.rule for f in diff.new] == ["R4"] * len(extra)
 
     # a fixed finding leaves a STALE entry (the ratchet signal)
     diff = diff_against_baseline(findings[1:], entries)
@@ -187,23 +186,23 @@ def test_cli_exit_codes(tmp_path, capsys):
     assert main([str(tmp_path / "missing.py")]) == 2
     assert main(["--list-rules"]) == 0
     out = capsys.readouterr().out
-    assert "R1" in out and "R5" in out
+    assert "R1" in out and "R9" in out
 
 
 def test_cli_select_subset():
     bad = os.path.join(FIXTURES, "r2_bad.py")
     # selecting a rule the file does not violate -> clean
-    assert main([bad, "--no-baseline", "--select", "R5"]) == 0
+    assert main([bad, "--no-baseline", "--select", "R4"]) == 0
     assert main([bad, "--no-baseline", "--select", "R2"]) == 1
     assert main([bad, "--select", "R42"]) == 2  # unknown rule
 
 
 def test_cli_json_format(capsys):
-    bad = os.path.join(FIXTURES, "r5_bad.py")
+    bad = os.path.join(FIXTURES, "r4_bad.py")
     assert main([bad, "--no-baseline", "--format", "json"]) == 1
     payload = json.loads(capsys.readouterr().out)
     assert payload["total"] == 3
-    assert payload["new"][0]["rule"] == "R5"
+    assert payload["new"][0]["rule"] == "R4"
 
 
 def test_cli_write_baseline_refuses_subsets(tmp_path, capsys):
@@ -368,13 +367,13 @@ def test_r9_clean_on_the_real_repo_pins():
 
 def test_cli_rules_alias_filters(capsys):
     bad = os.path.join(FIXTURES, "r2_bad.py")
-    assert main([bad, "--no-baseline", "--rules", "R5"]) == 0
-    assert main([bad, "--no-baseline", "--rules", "R2,R5"]) == 1
+    assert main([bad, "--no-baseline", "--rules", "R4"]) == 0
+    assert main([bad, "--no-baseline", "--rules", "R2,R4"]) == 1
     capsys.readouterr()
 
 
 def test_cli_json_reports_baseline_entries(capsys):
-    bad = os.path.join(FIXTURES, "r5_bad.py")
+    bad = os.path.join(FIXTURES, "r4_bad.py")
     assert main([bad, "--no-baseline", "--format", "json"]) == 1
     payload = json.loads(capsys.readouterr().out)
     assert payload["baseline_entries"] == 0
@@ -382,7 +381,7 @@ def test_cli_json_reports_baseline_entries(capsys):
 
 
 def test_cli_sarif_format(capsys):
-    bad = os.path.join(FIXTURES, "r5_bad.py")
+    bad = os.path.join(FIXTURES, "r4_bad.py")
     assert main([bad, "--no-baseline", "--format", "sarif"]) == 1
     sarif = json.loads(capsys.readouterr().out)
     assert sarif["version"] == "2.1.0"
@@ -391,9 +390,9 @@ def test_cli_sarif_format(capsys):
     assert {"R1", "R9"} <= rule_ids
     assert run["results"], "findings must surface as results"
     res = run["results"][0]
-    assert res["ruleId"] == "R5"
+    assert res["ruleId"] == "R4"
     loc = res["locations"][0]["physicalLocation"]
-    assert loc["artifactLocation"]["uri"].endswith("r5_bad.py")
+    assert loc["artifactLocation"]["uri"].endswith("r4_bad.py")
     assert loc["region"]["startLine"] >= 1
     assert run["properties"]["totalFindings"] == 3
 

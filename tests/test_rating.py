@@ -24,7 +24,6 @@ from kaminpar_tpu.ops import rating
 from kaminpar_tpu.ops.lp import LPConfig, lp_cluster, lp_round
 from kaminpar_tpu.ops.rating import (
     best_from_slots,
-    best_from_slots_pallas,
     scatter_slot_ratings,
     select_engine,
 )
@@ -287,30 +286,6 @@ def test_bench_path_dormancy_wall_bounded():
     assert len(series) <= 1
     assert not spills
     assert wall < 30.0, f"bench-path clustering took {wall:.1f}s"
-
-
-def test_best_from_slots_pallas_interpret_matches_lax():
-    """The optional Pallas rate+argmax core (platform-gated, lax path
-    default) computes the same unconstrained best/own values in
-    interpret mode."""
-    g = factories.make_rmat(128, 1024, seed=7)
-    dg = device_graph_from_host(g)
-    rng = np.random.default_rng(2)
-    labels = np.arange(dg.n_pad, dtype=np.int32)
-    labels[: g.n] = rng.integers(0, g.n, g.n)
-    lab_j = jnp.asarray(labels)
-    nb = lab_j[dg.dst]
-    slot_label, slot_w, _ = scatter_slot_ratings(
-        dg.src, nb, dg.edge_w, dg.n_pad, 32, 13
-    )
-    # unconstrained reference via the lax path
-    b_ref, w_ref, own_ref = best_from_slots(slot_label, slot_w, lab_j, 13)
-    b_pl, w_pl, own_pl = best_from_slots_pallas(
-        slot_label, slot_w, lab_j, 13, interpret=True
-    )
-    np.testing.assert_array_equal(np.asarray(b_ref), np.asarray(b_pl))
-    np.testing.assert_array_equal(np.asarray(w_ref), np.asarray(w_pl))
-    np.testing.assert_array_equal(np.asarray(own_ref), np.asarray(own_pl))
 
 
 def test_dist_scatter_engine_valid_and_capped():
@@ -654,3 +629,26 @@ def test_scatter_round_has_no_table_wide_irregular_pass():
         lambda sl, sw, lab: best_from_slots(sl, sw, lab, 7)
     )(slot_label, slot_label, state["labels"]).jaxpr
     assert _irregular_ops(rate) == []
+
+
+# ---------------------------------------------------------------------------
+# one gather path (PR 28): every irregular read has one signature
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "module", ["lp", "jet", "contraction", "segments", "rating"]
+)
+def test_ops_have_one_gather_signature(module):
+    """No function of the kernel modules takes a routing plan or the
+    routed branch's per-slot own weight: a second gather path would have
+    to be kept bit for bit by every change to these functions."""
+    import importlib
+    import inspect
+
+    mod = importlib.import_module(f"kaminpar_tpu.ops.{module}")
+    for name, fn in vars(mod).items():
+        fn = getattr(fn, "__wrapped__", fn)  # through jax.jit
+        if inspect.isfunction(fn) and fn.__module__ == mod.__name__:
+            params = inspect.signature(fn).parameters
+            assert not {"plans", "w_own"} & set(params), name

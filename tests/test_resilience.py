@@ -18,7 +18,6 @@ from kaminpar_tpu.resilience import (
     DegradationError,
     DeviceOOM,
     NativeUnavailable,
-    PlanBlowup,
     RefinerRefused,
     faults,
     gate,
@@ -51,9 +50,11 @@ def degraded_sites():
 
 
 def test_parse_plan_specs():
-    rules = faults.parse_plan("native-fm,refiner:nth=3,lane-gather:0.25,all")
+    rules = faults.parse_plan(
+        "native-fm,refiner:nth=3,device-balancer:0.25,all"
+    )
     assert [r.site for r in rules] == [
-        "native-fm", "refiner", "lane-gather", "all",
+        "native-fm", "refiner", "device-balancer", "all",
     ]
     assert rules[1].nth == 3
     assert rules[2].prob == 0.25
@@ -198,16 +199,13 @@ def test_breaker_opens_and_skips_primary():
 
 
 def test_refusals_do_not_latch_breaker():
-    for exc_type in (RefinerRefused, PlanBlowup):
-        for _ in range(policy.BREAKER_THRESHOLD + 2):
-            with_fallback(
-                lambda: (_ for _ in ()).throw(exc_type("refused")),
-                lambda exc: None,
-                site="native-fm" if exc_type is RefinerRefused
-                else "lane-gather",
-            )
+    for _ in range(policy.BREAKER_THRESHOLD + 2):
+        with_fallback(
+            lambda: (_ for _ in ()).throw(RefinerRefused("refused")),
+            lambda exc: None,
+            site="native-fm",
+        )
     assert not policy.breaker_state("native-fm")["open"]
-    assert not policy.breaker_state("lane-gather")["open"]
 
 
 # ---------------------------------------------------------------------------
@@ -457,34 +455,6 @@ def test_chaos_single_site(monkeypatch, plan, cfg):
     assert ev.attrs["fallback"] == faults.SITES[site].fallback
     # and the fault was logged by the harness
     assert {"site": site, "call": 1} in faults.injected_log()
-
-
-def test_chaos_lane_gather_site(monkeypatch):
-    """lane-gather is gated behind TPU-only probes in the pipeline; the
-    chaos contract is exercised at the site wrapper itself."""
-    import jax.numpy as jnp
-
-    from kaminpar_tpu.graphs.csr import device_graph_from_host
-    from kaminpar_tpu.graphs.factories import make_grid_graph
-    from kaminpar_tpu.ops import lane_gather
-
-    monkeypatch.setenv(faults.ENV_VAR, "lane-gather:nth=1")
-    dg = device_graph_from_host(make_grid_graph(8, 8))
-    pack = lane_gather.edge_plans(dg)
-    assert pack is None  # degraded to the XLA gather
-    (ev,) = [e for e in telemetry.events("degraded")
-             if e.attrs["site"] == "lane-gather"]
-    assert ev.attrs["injected"] is True
-    # the capped-plan telemetry still fires for report consumers
-    plans = telemetry.events("lane-gather-plan")
-    assert plans and plans[-1].attrs["capped"] is True
-    # second call (fault spent): a real plan is built and cached (the
-    # blowup cap is lifted — a pad-dominated toy graph legitimately
-    # exceeds the production ratio)
-    monkeypatch.setattr(lane_gather, "PLAN_MAX_SLOT_RATIO", float("inf"))
-    lane_gather.clear_plan_cache()
-    pack2 = lane_gather.edge_plans(dg)
-    assert pack2 is not None
 
 
 def test_chaos_collective_site(monkeypatch):

@@ -4,8 +4,8 @@ Covers the observability contract: span nesting mirrors the timer tree,
 disabled mode records nothing, the Chrome-trace export conforms to the
 trace-event schema, the run report round-trips through JSON and passes
 the checked-in schema (scripts/check_report_schema.py — the tier-1
-schema-drift backstop), and the lane-gather / FM decision events fire on
-forced code paths.
+schema-drift backstop), and the FM decision events fire on forced code
+paths.
 """
 
 import importlib.util
@@ -175,9 +175,6 @@ def test_run_report_roundtrip_and_schema(tmp_path):
     assert isinstance(loaded["result"]["feasible"], bool)
     assert "partitioning" in loaded["scope_tree"]
     assert loaded["comm"]["caveat"]
-    assert loaded["lane_gather"]["mode"] in (
-        "not-probed", "probed", "forced-on", "opt-out"
-    )
     # schema v2 sections: non-empty progress (at least one LP series
     # with per-iteration moved values and one Jet series with cut
     # values) and compile accounting with per-phase seconds
@@ -319,7 +316,7 @@ def test_zero_overhead_jaxpr_when_disabled():
     # the instrumented variant REALLY differs: one extra while-carry
     def fused(p, stats):
         out = lp_mod._lp_refine_fused(
-            dg, p, 4, mbw, jnp.int32(1), cfg, 2, None, stats
+            dg, p, 4, mbw, jnp.int32(1), cfg, 2, stats
         )
         return out[0] if isinstance(out, tuple) else out
 
@@ -731,73 +728,6 @@ def test_schema_accepts_v1_through_v7(tmp_path):
 # ---------------------------------------------------------------------------
 # decision events on forced code paths
 # ---------------------------------------------------------------------------
-
-
-def test_lane_gather_force_enable_event(monkeypatch):
-    from kaminpar_tpu.ops import lane_gather
-
-    telemetry.enable()
-    monkeypatch.setenv("KAMINPAR_TPU_LANE_GATHER", "1")
-    monkeypatch.setattr(lane_gather, "_PROBE_STATUS", {"mode": "not-probed"})
-
-    import jax.numpy as jnp
-
-    class G:
-        pass
-
-    g = G()
-    g.n_pad = 128
-    g.dst = jnp.asarray(np.arange(64) % 128, dtype=jnp.int32)
-    g.src = jnp.asarray(np.arange(64) % 128, dtype=jnp.int32)
-    g.edge_w = jnp.ones(64, dtype=jnp.int32)
-    plans = lane_gather.maybe_edge_plans(g)
-    # force-enable skips the size gate and the timing race, but the
-    # platform/correctness gate still applies — on the CPU test backend
-    # the Mosaic kernel is unavailable, so routing stays off (no crash)
-    assert plans is None
-    events = telemetry.events("lane-gather-probe")
-    assert len(events) == 1 and events[0].attrs["verdict"] == "forced-on"
-    assert events[0].attrs["supported"] is False
-    assert "reason" in events[0].attrs
-    status = lane_gather.probe_status()
-    assert status["mode"] == "forced-on"
-    assert status["env_override"] == "1"
-    # the decision is cached: a second call emits no duplicate event
-    assert lane_gather.maybe_edge_plans(g) is None
-    assert len(telemetry.events("lane-gather-probe")) == 1
-
-
-def test_lane_gather_opt_out_status(monkeypatch):
-    from kaminpar_tpu.ops import lane_gather
-
-    monkeypatch.setenv("KAMINPAR_TPU_LANE_GATHER", "0")
-    monkeypatch.setattr(lane_gather, "_PROBE_STATUS", {"mode": "not-probed"})
-
-    class G:
-        pass
-
-    g = G()
-    assert lane_gather.maybe_edge_plans(g) is None
-    assert lane_gather.probe_status()["mode"] == "opt-out"
-
-
-def test_lane_gather_probe_event_records_verdict(monkeypatch):
-    from kaminpar_tpu.ops import lane_gather
-
-    telemetry.enable()
-    monkeypatch.delenv("KAMINPAR_TPU_LANE_GATHER", raising=False)
-    lane_gather.lane_gather_supported.cache_clear()
-    try:
-        supported = lane_gather.lane_gather_supported()
-        # CPU test platform: the Mosaic kernel is unavailable
-        assert supported is False
-        events = telemetry.events("lane-gather-probe")
-        assert len(events) == 1
-        assert events[0].attrs["verdict"] == "disabled"
-        assert "reason" in events[0].attrs
-        assert lane_gather.probe_status()["mode"] == "probed"
-    finally:
-        lane_gather.lane_gather_supported.cache_clear()
 
 
 def test_fm_refusal_sentinel_and_event():
