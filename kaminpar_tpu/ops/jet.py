@@ -52,9 +52,21 @@ from .segments import (
     prune_candidates_to_budget,
 )
 
-# Below this many edge slots the incremental machinery is not worth the
-# extra programs (mirrors ops/lp.DELTA_MIN_EDGE_SLOTS).
+# From this many edge slots on, Jet prunes its candidates to a row buffer
+# (prune_candidates_to_budget), runs the afterburner over that buffer
+# (packed_afterburner_gain_rows: a different move set, a different cut)
+# and takes the smaller coarse iteration budget (mirrors
+# ops/lp.DELTA_MIN_EDGE_SLOTS).  Conn-table maintenance does not ask it:
+# that works from the movers' rows at every size (CONN_DELTA_DIVISOR).
 DELTA_MIN_EDGE_SLOTS = 1 << 22
+
+# The conn table is updated from the movers' CSR rows while their degrees
+# sum to at most m_pad // CONN_DELTA_DIVISOR slots, and rebuilt otherwise.
+# The update costs ~3 buffer-wide indices where the rebuild costs one
+# edge-wide gather and its streams; 8 catches more of R-MAT's large move
+# sets at k = 16 but costs nearly a k = 2 rebuild, 32 misses half of
+# R-MAT's coarse iterations (PERF.md, PR 29).
+CONN_DELTA_DIVISOR = 16
 
 # Largest dense (n_pad, k) conn table Jet will materialize (int32
 # entries; 2^28 = 1 GiB).  Above it jet_refine degrades to LP
@@ -67,6 +79,15 @@ def _delta_slots(graph: DeviceGraph) -> int | None:
     if m_slots < DELTA_MIN_EDGE_SLOTS:
         return None
     return m_slots // 4
+
+
+def _conn_slots(graph: DeviceGraph) -> int:
+    """Row-buffer width of the conn-table update: the afterburner's
+    buffer where the graph has one, else its own share of the slots."""
+    dslots = _delta_slots(graph)
+    if dslots is not None:
+        return dslots
+    return graph.src.shape[0] // CONN_DELTA_DIVISOR
 
 
 def _full_ratings(graph: DeviceGraph, part: jax.Array, k: int) -> jax.Array:
@@ -141,6 +162,36 @@ def _conn_update_rows(
     )
 
 
+def _conn_step(
+    graph: DeviceGraph,
+    conn: jax.Array,
+    part_before: jax.Array,
+    part_after: jax.Array,
+    k: int,
+    conn_slots: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """The conn table of `part_after` from the table of `part_before`:
+    the movers' rows re-scattered when their degrees sum to at most
+    `conn_slots`, a full rebuild otherwise (bitwise the same table
+    either way).  Returns (conn, 1 if the delta was taken else 0)."""
+    if conn_slots == 0:
+        return _full_ratings(graph, part_after, k), jnp.int32(0)
+    # degree total <= m_pad < 2^31 (device layout)
+    # tpulint: disable=R3
+    changed_edges = jnp.sum(
+        jnp.where(part_before != part_after, graph.degrees, 0),
+        dtype=jnp.int32,
+    )
+    fits = changed_edges <= conn_slots
+    new_conn = lax.cond(
+        fits,
+        lambda args: _conn_update_rows(graph, *args, k, conn_slots),
+        lambda args: _full_ratings(graph, args[2], k),
+        (conn, part_before, part_after),
+    )
+    return new_conn, fits.astype(jnp.int32)
+
+
 def _jet_iteration(
     graph: DeviceGraph,
     part: jax.Array,
@@ -152,10 +203,11 @@ def _jet_iteration(
     balancer_rounds: int,
     wdeg: jax.Array | None = None,
     conn: jax.Array | None = None,
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+) -> Tuple[jax.Array, ...]:
     """One Jet move round.  Returns (new_part, new_lock, ext_sum,
-    new_conn) where ext_sum = sum over real nodes of (weighted degree -
-    connection to own block) in the INPUT partition — the rating table
+    new_conn, conn_delta) where ext_sum = sum over real nodes of
+    (weighted degree - connection to own block) in the INPUT partition —
+    the rating table
     gives the input partition's edge cut for free as ext_sum / 2, saving
     the driver a separate edge-wide cut pass per iteration.  ext_sum =
     2*cut stays in int32 exactly when edge_cut itself would (unlike a
@@ -167,11 +219,14 @@ def _jet_iteration(
     for the INPUT partition (the gain cache Jet's paper assumes).  When
     None it is built from scratch; the returned new_conn matches the
     OUTPUT partition bitwise either way (changed rows re-scattered, or a
-    full rebuild when too many nodes moved — lax.cond picks)."""
+    full rebuild when too many nodes moved — lax.cond picks, see
+    _conn_step).  conn_delta counts the iteration's reconciles (after the
+    Jet moves, after the balancer's) that re-scattered rows: 0, 1 or 2."""
     n_pad = graph.n_pad
     node_ids = jnp.arange(n_pad, dtype=jnp.int32)
     is_real = node_ids < graph.n
     dslots = _delta_slots(graph)
+    conn_slots = _conn_slots(graph)
 
     # ---- find moves (jet_refiner.cc:104-131) ----
     # dense (n, k) rating table: one segment_sum, no edge-list sort (the
@@ -238,24 +293,10 @@ def _jet_iteration(
     new_lock = accept.astype(jnp.int32)  # moved nodes rest next iteration
 
     # ---- maintain the rating table across the jet moves ----
-    # when few nodes changed, re-scatter only their rows
-    def _conn_step(conn_, before, after):
-        if dslots is None:
-            return _full_ratings(graph, after, k)
-        # degree total <= m_pad < 2^31 (device layout)
-        # tpulint: disable=R3
-        changed_edges = jnp.sum(
-            jnp.where(before != after, graph.degrees, 0), dtype=jnp.int32
-        )
-        return lax.cond(
-            changed_edges <= dslots,
-            lambda args: _conn_update_rows(graph, *args, k, dslots),
-            lambda args: _full_ratings(graph, args[2], k),
-            (conn_, before, after),
-        )
-
     if dslots is None:
-        jet_conn = _conn_step(conn, part, new_part)
+        jet_conn, jet_delta = _conn_step(
+            graph, conn, part, new_part, k, conn_slots
+        )
     else:
         # accepted movers are a subset of the pruned candidate set, whose
         # rows the afterburner ALREADY expanded and gathered — the conn
@@ -269,6 +310,7 @@ def _jet_iteration(
         jet_conn = _scatter_conn_delta_cols(
             conn, from_u, new_b, dst_b, w_m, k, n_pad
         )
+        jet_delta = jnp.int32(1)
 
     # ---- rebalance (jet_refiner.cc:185-187) ----
     # while_loop, not fori: Jet iterations usually keep the partition
@@ -305,13 +347,13 @@ def _jet_iteration(
     )
     # reconcile the table only when the balancer actually moved something
     # (the common case is a feasible partition and zero balancer rounds)
-    new_conn = lax.cond(
+    new_conn, bal_delta = lax.cond(
         jnp.any(bal_part != new_part),
-        lambda args: _conn_step(*args),
-        lambda args: args[0],
+        lambda args: _conn_step(graph, *args, k, conn_slots),
+        lambda args: (args[0], jnp.int32(0)),
         (jet_conn, new_part, bal_part),
     )
-    return bal_part, new_lock, ext_sum, new_conn
+    return bal_part, new_lock, ext_sum, new_conn, jet_delta + bal_delta
 
 
 @partial(
@@ -370,7 +412,7 @@ def _jet_chunk(
         salt = (
             seed.astype(jnp.int32) * 31321 + rnd * 2221 + i * 1566083941
         ) & 0x7FFFFFFF
-        new_part, lock, ext_sum, conn = _jet_iteration(
+        new_part, lock, ext_sum, conn, conn_delta = _jet_iteration(
             graph,
             part,
             lock,
@@ -406,9 +448,11 @@ def _jet_chunk(
             # cut of the state entering iteration i; moved = locked
             # (accepted) movers of this iteration; fruitless after the
             # improvement test — the convergence picture Jet's paper
-            # plots (and the reference's statistics registry prints)
+            # plots (and the reference's statistics registry prints);
+            # conn_delta = conn-table reconciles served by the movers'
+            # rows instead of a rebuild (0..2)
             stats = progress_mod.record(
-                stats, i, cut, jnp.sum(lock), fruitless
+                stats, i, cut, jnp.sum(lock), fruitless, conn_delta
             )
         return (j + 1, fruitless, new_part, lock, best, best_cut, conn,
                 stats)
@@ -529,7 +573,7 @@ def _jet_refine_impl(
             conn = _jet_build_conn(graph, part, k)
         # per-round progress buffer, row-indexed by the global iteration
         # so it rides across host-driven chunks without a host pull
-        stats = progress_mod.new_buffer(max_iterations, 3) if rec else None
+        stats = progress_mod.new_buffer(max_iterations, 4) if rec else None
         t0 = progress_mod.now()
         i = 0
         closed = False
@@ -572,8 +616,8 @@ def _jet_refine_impl(
             # ONE host pull per round, after the loop exited (the chunk
             # driver's fruitless readback already synced the stream)
             progress_mod.emit(
-                "jet", ("cut", "moved", "fruitless"), stats, t0,
-                round=rnd, best_cut=int(best_cut),
+                "jet", ("cut", "moved", "fruitless", "conn_delta"), stats,
+                t0, round=rnd, best_cut=int(best_cut),
             )
         # rollback to best (jet_refiner.cc:221-227): the round continues
         # from the best partition seen

@@ -11,13 +11,19 @@ asked of the finished table (two gathers at table width, the plain
 reference of tests/test_rating.py) against decided per edge (one more dst
 gather and two owner streams, as ops/lp.lp_round does), and against the
 same with the room bit-packed beside the label into the one word
-labels[dst] moves.  Every timing is the minimum
+labels[dst] moves.  With --conn-delta, and nothing else, for each shape
+and k: Jet's conn table rebuilt (ops/jet._full_ratings) against updated
+from the movers' rows (_conn_update_rows) through a buffer of m_pad // 8,
+// 16 and // 32 slots, the movers' degrees filling the smallest: what
+ops/jet.CONN_DELTA_DIVISOR rests on.  Every timing is the minimum
 of REPS launches ending in block_until_ready; the labels[dst] gather both
 engines share is timed alone so it can be subtracted.  A small program
 compiles in ~25 s on the chip: name only what you need.
 
 Usage: python scripts/microbench_csr_stream.py [--shapes coarse,fine,mesh]
     [--ks 2,4,8,16,32] [--columns 1,4,8] [--no-scatter] [--slots]
+    python scripts/microbench_csr_stream.py --conn-delta
+    [--shapes fine,coarse,mesh] [--ks 2,16]
 (TPU; a CPU run only proves the script runs.)  Writes
 chiprun_out/microbench_csr_stream.json.
 """
@@ -42,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from kaminpar_tpu.graphs.csr import device_graph_from_host
+from kaminpar_tpu.ops import jet
 from kaminpar_tpu.ops import segments as seg
 
 REPS = 5
@@ -59,6 +66,13 @@ SHAPES = {
 # slots a pass of the scatter rating at a shape: the preset's 32, doubled
 # by the coarsener on rmat-s16's level 0 (average degree 26 > 16)
 SLOTS = {"coarse": 32, "fine": 64, "tiny": 32}
+CONN_DIVISORS = (8, 16, 32)
+
+
+def skew(rng, n):
+    """Node probabilities with R-MAT-like skew."""
+    p = (rng.permutation(n) + 1.0) ** -0.8
+    return p / p.sum()
 
 
 def skewed_graph(rng, n, m):
@@ -66,14 +80,29 @@ def skewed_graph(rng, n, m):
     timings depend on index counts and locality, not on symmetry)."""
     from kaminpar_tpu.graphs.host import HostGraph
 
-    rank = rng.permutation(n) + 1.0
-    p = rank ** -0.8
-    p /= p.sum()
+    p = skew(rng, n)
     deg = rng.multinomial(m, p)
     xadj = np.concatenate([[0], np.cumsum(deg)]).astype(np.int64)
     adjncy = rng.choice(n, size=m, p=p).astype(np.int32)
     return HostGraph(xadj=xadj, adjncy=adjncy, node_weights=None,
                      edge_weights=rng.integers(1, 50, m).astype(np.int64))
+
+
+def symmetric_skewed_graph(rng, n, m):
+    """The same skew with every edge stored in both directions under one
+    weight: a conn table is updated from its movers' rows only where the
+    neighbour's row holds the edge too."""
+    from kaminpar_tpu.graphs.host import HostGraph
+
+    p = skew(rng, n)
+    u, v = rng.choice(n, size=(2, m // 2), p=p).astype(np.int32)
+    w = rng.integers(1, 50, m // 2).astype(np.int64)
+    src, dst = np.concatenate([u, v]), np.concatenate([v, u])
+    order = np.argsort(src, kind="stable")
+    xadj = np.concatenate(
+        [[0], np.cumsum(np.bincount(src, minlength=n))]).astype(np.int64)
+    return HostGraph(xadj=xadj, adjncy=dst[order], node_weights=None,
+                     edge_weights=np.concatenate([w, w])[order])
 
 
 def timeit(fn, *args):
@@ -141,6 +170,34 @@ def slots_row(rng, graph, num_slots):
         packed_ms=timeit(packed, graph, labels, weights, cap))
 
 
+def conn_delta_row(rng, graph, k):
+    """ms for the conn table of a partition a few nodes away from the one
+    the table in hand matches: rebuilt, and updated through each buffer
+    (each checked against the rebuild)."""
+    n_pad, m_pad = graph.n_pad, graph.m_pad
+    before = jnp.asarray(rng.integers(0, k, n_pad).astype(np.int32))
+    # movers in random order while their rows fit the smallest buffer
+    degrees = np.asarray(graph.degrees)
+    order = rng.permutation(n_pad)
+    fits = np.cumsum(degrees[order]) <= m_pad // max(CONN_DIVISORS)
+    moved = np.zeros(n_pad, bool)
+    moved[order[fits]] = True
+    after = jnp.where(jnp.asarray(moved), (before + 1) % k, before)
+    conn = jet._full_ratings(graph, before, k)
+    want = jet._full_ratings(graph, after, k)
+    row = dict(op="conn_delta", k=k, movers=int(moved.sum()),
+               mover_edges=int(degrees[moved].sum()),
+               full_ms=timeit(lambda g, p: jet._full_ratings(g, p, k),
+                              graph, after))
+    for divisor in CONN_DIVISORS:
+        fn = lambda g, c, b, a: jet._conn_update_rows(
+            g, c, b, a, k, m_pad // divisor)
+        assert bool(jnp.all(fn(graph, conn, before, after) == want))
+        row[f"delta_div{divisor}_ms"] = timeit(fn, graph, conn, before,
+                                               after)
+    return row
+
+
 def ints(text):
     return [int(x) for x in text.split(",") if x]
 
@@ -152,6 +209,7 @@ def main():
     parser.add_argument("--columns", type=ints, default=[])
     parser.add_argument("--no-scatter", action="store_true")
     parser.add_argument("--slots", action="store_true")
+    parser.add_argument("--conn-delta", action="store_true")
     args = parser.parse_args()
     dev = jax.devices()[0]
     out = {"device": {"platform": dev.platform, "kind": dev.device_kind},
@@ -160,11 +218,18 @@ def main():
     rng = np.random.default_rng(0)
     for name in args.shapes.split(","):
         n, m, n_pad, m_pad = SHAPES[name]
-        graph = device_graph_from_host(skewed_graph(rng, n, m), n_pad=n_pad,
+        make = symmetric_skewed_graph if args.conn_delta else skewed_graph
+        graph = device_graph_from_host(make(rng, n, m), n_pad=n_pad,
                                        m_pad=m_pad)
         values = jnp.asarray(
             rng.integers(0, 2**31 - 1, n_pad).astype(np.int32))
         shape = {"shape": name, "n_pad": n_pad, "m_pad": m_pad, "m": m}
+        if args.conn_delta:
+            for k in args.ks:
+                row = dict(shape, **conn_delta_row(rng, graph, k))
+                out["rows"].append(row)
+                print(json.dumps(row), flush=True)
+            continue
         if not args.no_scatter:
             row = dict(
                 shape, op="owner_column",

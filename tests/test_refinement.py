@@ -4,6 +4,7 @@ recomputation)."""
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from kaminpar_tpu.context import FMRefinementContext, JetRefinementContext
 from kaminpar_tpu.graphs import device_graph_from_host, factories
@@ -231,6 +232,129 @@ def test_jet_candidate_pruning_quality_class(monkeypatch):
         jet_mod._jet_chunk.clear_cache()
     # same class: pruning costs at most a few percent on this workload
     assert cut_pruned <= 1.1 * cut_full
+
+
+def _jet_case(kind: str, k: int, heavy: bool):
+    """A graph, caps and a random start for the conn-buffer tests."""
+    if kind == "rmat":
+        # padded as the chip pads (half the slots and more are padding):
+        # R-MAT's move sets at k = 16 straddle a sixteenth of that
+        host, m_pad = factories.make_rmat(1 << 10, 12_000, seed=13), 1 << 16
+    else:
+        host, m_pad = factories.make_grid_graph(32, 32), None
+    if heavy:
+        # one weight per undirected edge, large enough that gains at
+        # R-MAT's hubs leave the packed afterburner's clip range at k = 16
+        ew = np.asarray(
+            np.random.default_rng(3).integers(1, 100_000, len(host.adjncy))
+        )
+        src = np.repeat(np.arange(host.n), np.diff(host.xadj))
+        lo, hi = np.minimum(src, host.adjncy), np.maximum(src, host.adjncy)
+        host.edge_weights = ew[np.unique(
+            lo.astype(np.int64) * host.n + hi, return_inverse=True)[1]]
+    g = device_graph_from_host(host, m_pad=m_pad)
+    nw = np.asarray(g.node_w)[: int(g.n)]
+    cap = jnp.full(k, int(1.05 * np.ceil(nw.sum() / k)), dtype=jnp.int32)
+    p0 = np.zeros(g.n_pad, np.int32)
+    p0[: int(g.n)] = np.random.default_rng(k).integers(0, k, int(g.n))
+    return g, cap, jnp.asarray(p0)
+
+
+@pytest.mark.parametrize("heavy", [False, True], ids=["unit", "heavy"])
+@pytest.mark.parametrize("kind", ["rmat", "grid"])
+@pytest.mark.parametrize("k", [2, 8, 16])
+def test_jet_conn_buffer_matches_full_rebuilds(monkeypatch, k, kind, heavy):
+    """Jet with the conn table kept by its movers' rows (buffer of
+    m_pad // CONN_DELTA_DIVISOR slots, the shipped size) returns bitwise
+    the partition of Jet that rebuilds the table at every reconcile
+    (buffer forced to 0), on runs that have reconciles on both sides of
+    the threshold."""
+    import kaminpar_tpu.ops.jet as jet_mod
+    from kaminpar_tpu import telemetry
+
+    g, cap, p0 = _jet_case(kind, k, heavy)
+    assert jet_mod._conn_slots(g) == g.src.shape[0] // 16 > 0
+
+    def run():
+        telemetry.reset()
+        out = np.asarray(jet_refine(
+            g, p0, k, cap, jnp.int32(4), JetRefinementContext(), 1, 2))
+        delta = [c for s in telemetry.progress_series("jet")
+                 for c in s.series["conn_delta"]]
+        return out, delta
+
+    def run_with(slots):
+        monkeypatch.setattr(jet_mod, "_conn_slots", lambda graph: slots)
+        jet_mod._jet_chunk.clear_cache()
+        try:
+            return run()
+        finally:
+            jet_mod._jet_chunk.clear_cache()
+
+    telemetry.enable()
+    shipped, delta = run()
+    rebuilt, none = run_with(0)
+    # a buffer as wide as the edge array takes every reconcile: its
+    # counter is the number of reconciles an iteration had
+    _, reconciles = run_with(g.src.shape[0])
+    assert set(none) == {0} and set(reconciles) <= {1, 2}
+    assert any(d < r for d, r in zip(delta, reconciles)), delta
+    assert any(d > 0 for d in delta), delta
+    np.testing.assert_array_equal(shipped, rebuilt)
+
+
+@pytest.mark.parametrize("over", [0, 1], ids=["fits", "one-over"])
+def test_conn_step_threshold(over):
+    """_conn_step takes the movers' rows while their degrees sum to at
+    most the buffer and rebuilds from one more slot on; the table is the
+    rebuilt one either way."""
+    import kaminpar_tpu.ops.jet as jet_mod
+
+    g = device_graph_from_host(factories.make_grid_graph(16, 16))
+    k = 4
+    rng = np.random.default_rng(0)
+    before = _pad_part(g, rng.integers(0, k, int(g.n)))
+    movers = np.array([17, 40, 100, 200])  # interior nodes, degree 4
+    after = before.at[movers].set((before[movers] + 1) % k)
+    changed_edges = int(np.asarray(g.degrees)[movers].sum())
+    conn = jet_mod._full_ratings(g, before, k)
+    got, took = jet_mod._conn_step(
+        g, conn, before, after, k, changed_edges - over)
+    assert int(took) == 1 - over
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(jet_mod._full_ratings(g, after, k)))
+
+
+def test_jet_conn_delta_counter_rides_only_the_stats_buffer():
+    """The conn_delta counter is the fourth column of the `jet` progress
+    series; with telemetry off _jet_chunk's loop has the carries it had
+    before the counter (j, fruitless, part, lock, best, best_cut, conn)
+    and with it on exactly one more, the stats buffer."""
+    import jax
+
+    import kaminpar_tpu.ops.jet as jet_mod
+    from kaminpar_tpu.telemetry import progress as progress_mod
+
+    g, cap, p0 = _jet_case("grid", 4, False)
+    k = 4
+    conn = jet_mod._full_ratings(g, p0, k)
+    wdeg = jnp.zeros(g.n_pad, jnp.int32)
+
+    def chunk(stats):
+        return jax.make_jaxpr(lambda part, stats: jet_mod._jet_chunk(
+            g, part, jnp.zeros_like(part), part, jnp.int32(2**31 - 1),
+            jnp.int32(0), conn, jnp.int32(0), k, cap, jnp.float32(0.25),
+            jnp.float32(0.999), jnp.int32(1), jnp.int32(0), jnp.int32(4),
+            wdeg, 2**30, 4, stats))(p0, stats)
+
+    def carries(jaxpr):
+        (pjit,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "jit"]
+        loops = [e for e in pjit.params["jaxpr"].jaxpr.eqns
+                 if e.primitive.name == "while"]
+        return max(len(e.outvars) for e in loops)
+
+    assert carries(chunk(None)) == 7
+    assert carries(chunk(progress_mod.new_buffer(4, 4))) == 8
 
 
 def test_prune_candidates_to_budget_semantics():
