@@ -81,6 +81,18 @@ def _delta_slots(graph: DeviceGraph) -> int | None:
     return m_slots // 4
 
 
+def iteration_path(graph: DeviceGraph, k: int) -> str:
+    """Which iteration `jet_refine` runs on `graph` at `k`, from the shapes
+    alone: `jet-rows` (candidates pruned to a row buffer, the afterburner
+    over that buffer), `jet-edges` (the edge-wide afterburner) or `jet-lp`
+    (no dense table: LP refinement rounds).  The refiner names a timer
+    scope after it, so a trace of a run with telemetry off still says
+    which Jet a level ran."""
+    if graph.n_pad * k > JET_DENSE_MAX_ENTRIES:
+        return "jet-lp"
+    return "jet-edges" if _delta_slots(graph) is None else "jet-rows"
+
+
 def _conn_slots(graph: DeviceGraph) -> int:
     """Row-buffer width of the conn-table update: the afterburner's
     buffer where the graph has one, else its own share of the slots."""
@@ -205,7 +217,7 @@ def _jet_iteration(
     conn: jax.Array | None = None,
 ) -> Tuple[jax.Array, ...]:
     """One Jet move round.  Returns (new_part, new_lock, ext_sum,
-    new_conn, conn_delta) where ext_sum = sum over real nodes of
+    new_conn, conn_delta, pruned) where ext_sum = sum over real nodes of
     (weighted degree - connection to own block) in the INPUT partition —
     the rating table
     gives the input partition's edge cut for free as ext_sum / 2, saving
@@ -221,7 +233,9 @@ def _jet_iteration(
     OUTPUT partition bitwise either way (changed rows re-scattered, or a
     full rebuild when too many nodes moved — lax.cond picks, see
     _conn_step).  conn_delta counts the iteration's reconciles (after the
-    Jet moves, after the balancer's) that re-scattered rows: 0, 1 or 2."""
+    Jet moves, after the balancer's) that re-scattered rows: 0, 1 or 2;
+    pruned the candidates prune_candidates_to_budget dropped (0 on the
+    edge-wide path, which has no budget)."""
     n_pad = graph.n_pad
     node_ids = jnp.arange(n_pad, dtype=jnp.int32)
     is_real = node_ids < graph.n
@@ -269,10 +283,13 @@ def _jet_iteration(
             part, next_part, gain, candidate, k,
         )
         owner_c = dst_b = w_b = from_u = to_u = None
+        pruned = jnp.int32(0)
     else:
+        found = candidate
         candidate = prune_candidates_to_budget(
             candidate, gain, graph.degrees, salt ^ 0x5BD1E995, dslots
         )
+        pruned = jnp.sum(found & ~candidate, dtype=ACC_DTYPE)
         next_part = jnp.where(candidate, best, part)
         owner_c, _, edge_id, valid, start, end = expand_active_rows(
             graph.row_ptr, graph.degrees, candidate, dslots
@@ -353,7 +370,8 @@ def _jet_iteration(
         lambda args: (args[0], jnp.int32(0)),
         (jet_conn, new_part, bal_part),
     )
-    return bal_part, new_lock, ext_sum, new_conn, jet_delta + bal_delta
+    return (bal_part, new_lock, ext_sum, new_conn, jet_delta + bal_delta,
+            pruned)
 
 
 @partial(
@@ -412,7 +430,7 @@ def _jet_chunk(
         salt = (
             seed.astype(jnp.int32) * 31321 + rnd * 2221 + i * 1566083941
         ) & 0x7FFFFFFF
-        new_part, lock, ext_sum, conn, conn_delta = _jet_iteration(
+        new_part, lock, ext_sum, conn, conn_delta, pruned = _jet_iteration(
             graph,
             part,
             lock,
@@ -450,9 +468,10 @@ def _jet_chunk(
             # improvement test — the convergence picture Jet's paper
             # plots (and the reference's statistics registry prints);
             # conn_delta = conn-table reconciles served by the movers'
-            # rows instead of a rebuild (0..2)
+            # rows instead of a rebuild (0..2); pruned = candidates the
+            # row budget dropped (they compete again next iteration)
             stats = progress_mod.record(
-                stats, i, cut, jnp.sum(lock), fruitless, conn_delta
+                stats, i, cut, jnp.sum(lock), fruitless, conn_delta, pruned
             )
         return (j + 1, fruitless, new_part, lock, best, best_cut, conn,
                 stats)
@@ -573,7 +592,7 @@ def _jet_refine_impl(
             conn = _jet_build_conn(graph, part, k)
         # per-round progress buffer, row-indexed by the global iteration
         # so it rides across host-driven chunks without a host pull
-        stats = progress_mod.new_buffer(max_iterations, 4) if rec else None
+        stats = progress_mod.new_buffer(max_iterations, 5) if rec else None
         t0 = progress_mod.now()
         i = 0
         closed = False
@@ -616,8 +635,9 @@ def _jet_refine_impl(
             # ONE host pull per round, after the loop exited (the chunk
             # driver's fruitless readback already synced the stream)
             progress_mod.emit(
-                "jet", ("cut", "moved", "fruitless", "conn_delta"), stats,
-                t0, round=rnd, best_cut=int(best_cut),
+                "jet",
+                ("cut", "moved", "fruitless", "conn_delta", "pruned"),
+                stats, t0, round=rnd, best_cut=int(best_cut),
             )
         # rollback to best (jet_refiner.cc:221-227): the round continues
         # from the best partition seen
@@ -640,7 +660,7 @@ def jet_refine(
 ) -> jax.Array:
     """Jet refinement entry point; picks coarse/fine temperatures by level
     (jet_refiner.cc:40-49: every level except the finest counts as coarse)."""
-    if graph.n_pad * k > JET_DENSE_MAX_ENTRIES:
+    if iteration_path(graph, k) == "jet-lp":
         # huge k: the dense (n, k) conn table Jet's incremental machinery
         # rides would not fit HBM (16 GB at n=1M, k=4096).  Degrade to
         # bulk-synchronous LP refinement rounds — the sort2 rating engine

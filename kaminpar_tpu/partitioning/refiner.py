@@ -223,7 +223,7 @@ class RefinerPipeline:
                         max_rounds=self.ctx.refinement.balancer.max_rounds,
                     )
         elif algorithm == RefinementAlgorithm.JET:
-            from ..ops.jet import jet_refine
+            from ..ops.jet import iteration_path, jet_refine
 
             jet_ctx = self.ctx.refinement.jet
             if self.light:
@@ -234,7 +234,12 @@ class RefinerPipeline:
                 )
 
             def step(partition):
-                with timer.scoped_timer("jet"):
+                # directly under `jet`, the iteration the shapes resolve
+                # to (`jet-rows`, `jet-edges`, `jet-lp`): the pattern of
+                # coarsener._lp_clustering_scope's `rating-<engine>`
+                with timer.scoped_timer("jet"), timer.scoped_timer(
+                    iteration_path(graph, k)
+                ):
                     return jet_refine(
                         graph,
                         partition,

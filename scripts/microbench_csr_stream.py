@@ -13,9 +13,10 @@ gather and two owner streams, as ops/lp.lp_round does), and against the
 same with the room bit-packed beside the label into the one word
 labels[dst] moves.  With --conn-delta, and nothing else, for each shape
 and k: Jet's conn table rebuilt (ops/jet._full_ratings) against updated
-from the movers' rows (_conn_update_rows) through a buffer of m_pad // 8,
-// 16 and // 32 slots, the movers' degrees filling the smallest: what
-ops/jet.CONN_DELTA_DIVISOR rests on.  Every timing is the minimum
+from the movers' rows (_conn_update_rows) through a buffer of m_pad // 4
+(what a graph of 2^22 slots or more uses), // 8, // 16 and // 32 slots,
+the movers' degrees filling the smallest: what ops/jet.CONN_DELTA_DIVISOR
+rests on.  Every timing is the minimum
 of REPS launches ending in block_until_ready; the labels[dst] gather both
 engines share is timed alone so it can be subtracted.  A small program
 compiles in ~25 s on the chip: name only what you need.
@@ -23,7 +24,7 @@ compiles in ~25 s on the chip: name only what you need.
 Usage: python scripts/microbench_csr_stream.py [--shapes coarse,fine,mesh]
     [--ks 2,4,8,16,32] [--columns 1,4,8] [--no-scatter] [--slots]
     python scripts/microbench_csr_stream.py --conn-delta
-    [--shapes fine,coarse,mesh] [--ks 2,16]
+    [--shapes fine,coarse,mesh,large] [--ks 2,16]
 (TPU; a CPU run only proves the script runs.)  Writes
 chiprun_out/microbench_csr_stream.json.
 """
@@ -60,13 +61,16 @@ SHAPES = {
     "fine": (41_761, 1_083_716, 1 << 16, 1 << 21),
     "mesh": (131_072, 786_374, 1 << 18, 1 << 20),
     "mesh1": (26_901, 160_468, 1 << 15, 1 << 20),
+    # level 0 of rmat-s17 at --seed 1: the large side of the 1 << 22 gate,
+    # where Jet's conn update works through the afterburner's m_pad // 4
+    "large": (80_170, 2_207_668, 1 << 17, 1 << 22),
     # a rehearsal on the CPU, no level of any cell
     "tiny": (500, 6_000, 1 << 9, 1 << 13),
 }
 # slots a pass of the scatter rating at a shape: the preset's 32, doubled
 # by the coarsener on rmat-s16's level 0 (average degree 26 > 16)
 SLOTS = {"coarse": 32, "fine": 64, "tiny": 32}
-CONN_DIVISORS = (8, 16, 32)
+CONN_DIVISORS = (4, 8, 16, 32)
 
 
 def skew(rng, n):

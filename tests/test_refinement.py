@@ -326,10 +326,11 @@ def test_conn_step_threshold(over):
 
 
 def test_jet_conn_delta_counter_rides_only_the_stats_buffer():
-    """The conn_delta counter is the fourth column of the `jet` progress
-    series; with telemetry off _jet_chunk's loop has the carries it had
-    before the counter (j, fruitless, part, lock, best, best_cut, conn)
-    and with it on exactly one more, the stats buffer."""
+    """The conn_delta and pruned counters are the fourth and fifth column
+    of the `jet` progress series; with telemetry off _jet_chunk's loop
+    has the carries it had before the counters (j, fruitless, part, lock,
+    best, best_cut, conn) and with it on exactly one more, the stats
+    buffer."""
     import jax
 
     import kaminpar_tpu.ops.jet as jet_mod
@@ -354,7 +355,7 @@ def test_jet_conn_delta_counter_rides_only_the_stats_buffer():
         return max(len(e.outvars) for e in loops)
 
     assert carries(chunk(None)) == 7
-    assert carries(chunk(progress_mod.new_buffer(4, 4))) == 8
+    assert carries(chunk(progress_mod.new_buffer(4, 5))) == 8
 
 
 def test_prune_candidates_to_budget_semantics():
