@@ -56,16 +56,16 @@ from .segments import (
 # (prune_candidates_to_budget), runs the afterburner over that buffer
 # (packed_afterburner_gain_rows: a different move set, a different cut)
 # and takes the smaller coarse iteration budget (mirrors
-# ops/lp.DELTA_MIN_EDGE_SLOTS).  Conn-table maintenance does not ask it:
-# that works from the movers' rows at every size (CONN_DELTA_DIVISOR).
+# ops/lp.DELTA_MIN_EDGE_SLOTS).  A reconcile through _conn_step does not
+# ask it: that takes CONN_DELTA_DIVISOR's buffer at every size.
 DELTA_MIN_EDGE_SLOTS = 1 << 22
 
-# The conn table is updated from the movers' CSR rows while their degrees
-# sum to at most m_pad // CONN_DELTA_DIVISOR slots, and rebuilt otherwise.
-# The update costs ~3 buffer-wide indices where the rebuild costs one
-# edge-wide gather and its streams; 8 catches more of R-MAT's large move
-# sets at k = 16 but costs nearly a k = 2 rebuild, 32 misses half of
-# R-MAT's coarse iterations (PERF.md, PR 29).
+# _conn_step updates the conn table from the movers' CSR rows while their
+# degrees sum to at most m_pad // CONN_DELTA_DIVISOR slots, and rebuilds
+# it otherwise, on either side of the gate above.  The update costs per
+# slot of its buffer, full or not: 8 costs nearly a k = 2 rebuild, 4
+# twice one, 32 misses half of R-MAT's coarse iterations at k = 16
+# (PERF.md, PR 29 and 31).
 CONN_DELTA_DIVISOR = 16
 
 # Largest dense (n_pad, k) conn table Jet will materialize (int32
@@ -94,11 +94,7 @@ def iteration_path(graph: DeviceGraph, k: int) -> str:
 
 
 def _conn_slots(graph: DeviceGraph) -> int:
-    """Row-buffer width of the conn-table update: the afterburner's
-    buffer where the graph has one, else its own share of the slots."""
-    dslots = _delta_slots(graph)
-    if dslots is not None:
-        return dslots
+    """Row-buffer width of a _conn_step reconcile, whatever the path."""
     return graph.src.shape[0] // CONN_DELTA_DIVISOR
 
 

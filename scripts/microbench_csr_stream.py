@@ -11,12 +11,19 @@ asked of the finished table (two gathers at table width, the plain
 reference of tests/test_rating.py) against decided per edge (one more dst
 gather and two owner streams, as ops/lp.lp_round does), and against the
 same with the room bit-packed beside the label into the one word
-labels[dst] moves.  With --conn-delta, and nothing else, for each shape
-and k: Jet's conn table rebuilt (ops/jet._full_ratings) against updated
-from the movers' rows (_conn_update_rows) through a buffer of m_pad // 4
-(what a graph of 2^22 slots or more uses), // 8, // 16 and // 32 slots,
-the movers' degrees filling the smallest: what ops/jet.CONN_DELTA_DIVISOR
-rests on.  Every timing is the minimum
+labels[dst] moves.  With --conn-delta, and none of the above, for each
+shape and k: Jet's conn table rebuilt (ops/jet._full_ratings) against
+updated from the movers' rows (_conn_update_rows) through a buffer of
+m_pad // 4 (the afterburner's row buffer past the gate), // 8, // 16
+(what _conn_step uses) and // 32 slots, the movers' degrees filling the
+smallest: what ops/jet.CONN_DELTA_DIVISOR rests on.  With
+--jet-iteration, and none of the above, for each shape and k: ONE
+ops/jet._jet_iteration on both paths, from the same graph, the same
+partition (a random one settled by SETTLE edge-wide iterations), the same
+table and the same salt: `jet-rows` as the program runs it from
+jet.DELTA_MIN_EDGE_SLOTS slots on, and `jet-edges` with that gate raised
+past the shape for this process alone; ms, and what each iteration
+counted (movers, conn_delta, pruned).  Every timing is the minimum
 of REPS launches ending in block_until_ready; the labels[dst] gather both
 engines share is timed alone so it can be subtracted.  A small program
 compiles in ~25 s on the chip: name only what you need.
@@ -25,6 +32,8 @@ Usage: python scripts/microbench_csr_stream.py [--shapes coarse,fine,mesh]
     [--ks 2,4,8,16,32] [--columns 1,4,8] [--no-scatter] [--slots]
     python scripts/microbench_csr_stream.py --conn-delta
     [--shapes fine,coarse,mesh,large] [--ks 2,16]
+    python scripts/microbench_csr_stream.py --jet-iteration
+    [--shapes large] [--ks 2,16]
 (TPU; a CPU run only proves the script runs.)  Writes
 chiprun_out/microbench_csr_stream.json.
 """
@@ -62,7 +71,7 @@ SHAPES = {
     "mesh": (131_072, 786_374, 1 << 18, 1 << 20),
     "mesh1": (26_901, 160_468, 1 << 15, 1 << 20),
     # level 0 of rmat-s17 at --seed 1: the large side of the 1 << 22 gate,
-    # where Jet's conn update works through the afterburner's m_pad // 4
+    # where Jet prunes to and runs its afterburner over m_pad // 4 slots
     "large": (80_170, 2_207_668, 1 << 17, 1 << 22),
     # a rehearsal on the CPU, no level of any cell
     "tiny": (500, 6_000, 1 << 9, 1 << 13),
@@ -71,6 +80,10 @@ SHAPES = {
 # by the coarsener on rmat-s16's level 0 (average degree 26 > 16)
 SLOTS = {"coarse": 32, "fine": 64, "tiny": 32}
 CONN_DIVISORS = (4, 8, 16, 32)
+# iterations that settle the random start of --jet-iteration (the coarse
+# budget): the timed one then moves what an iteration of a refiner call
+# moves, not what the first one from a random partition does
+SETTLE = 12
 
 
 def skew(rng, n):
@@ -109,15 +122,20 @@ def symmetric_skewed_graph(rng, n, m):
                      edge_weights=np.concatenate([w, w])[order])
 
 
-def timeit(fn, *args):
-    fn_j = jax.jit(fn)
-    jax.block_until_ready(fn_j(*args))  # compile
+def best_ms(fn_j, *args):
+    """Minimum of REPS launches of a function that has compiled."""
     best = float("inf")
     for _ in range(REPS):
         t0 = time.perf_counter()
         jax.block_until_ready(fn_j(*args))
         best = min(best, time.perf_counter() - t0)
     return best * 1e3
+
+
+def timeit(fn, *args):
+    fn_j = jax.jit(fn)
+    jax.block_until_ready(fn_j(*args))  # compile
+    return best_ms(fn_j, *args)
 
 
 def slots_row(rng, graph, num_slots):
@@ -202,6 +220,59 @@ def conn_delta_row(rng, graph, k):
     return row
 
 
+def jet_step(graph, k, caps, gate):
+    """One jitted _jet_iteration that resolves its path under `gate`
+    (jet.DELTA_MIN_EDGE_SLOTS is read while tracing; the program's own
+    value is back after every call)."""
+    step = jax.jit(lambda g, part, lock, conn, salt: jet._jet_iteration(
+        g, part, lock, k, caps, jnp.float32(0.25), salt, 4, conn=conn))
+
+    def call(part, lock, conn, salt):
+        shipped, jet.DELTA_MIN_EDGE_SLOTS = jet.DELTA_MIN_EDGE_SLOTS, gate
+        try:
+            return step(graph, part, lock, conn, salt)
+        finally:
+            jet.DELTA_MIN_EDGE_SLOTS = shipped
+
+    return call
+
+
+def jet_iteration_row(rng, graph, k):
+    """ms of one level-0 Jet iteration (fine temperature, 4 balancer
+    rounds, epsilon 0.03) on the rows path and on the edge-wide path."""
+    n_pad, m_pad = graph.n_pad, graph.m_pad
+    node_w = np.asarray(graph.node_w)
+    caps = jnp.full(k, int(1.03 * np.ceil(node_w.sum() / k)), jnp.int32)
+    part = jnp.asarray(np.where(
+        np.arange(n_pad) < int(graph.n), rng.integers(0, k, n_pad), 0
+    ).astype(np.int32))
+    paths = {
+        # a shape under the gate takes the rows path only for a rehearsal
+        "rows": jet_step(graph, k, caps,
+                         min(jet.DELTA_MIN_EDGE_SLOTS, m_pad)),
+        "edges": jet_step(graph, k, caps, 2 * m_pad),
+    }
+    lock = jnp.zeros(n_pad, jnp.int32)
+    conn = jet._full_ratings(graph, part, k)
+    salts = [jnp.int32((12345 + i * 1566083941) & 0x7FFFFFFF)
+             for i in range(SETTLE + 1)]
+    for salt in salts[:-1]:
+        part, lock, _, conn, _, _ = paths["edges"](part, lock, conn, salt)
+    row = dict(op="jet_iteration", k=k, settle=SETTLE,
+               conn_slots=jet._conn_slots(graph))
+    after = {}
+    for name, step in paths.items():
+        args = (part, lock, conn, salts[-1])
+        after[name], new_lock, _, _, conn_delta, pruned = step(*args)
+        row[f"{name}_ms"] = best_ms(step, *args)
+        row[name] = dict(
+            accepted=int(new_lock.sum()),
+            changed=int((after[name] != part).sum()),
+            conn_delta=int(conn_delta), pruned=int(pruned))
+    row["same_partition"] = bool(jnp.all(after["rows"] == after["edges"]))
+    return row
+
+
 def ints(text):
     return [int(x) for x in text.split(",") if x]
 
@@ -214,7 +285,11 @@ def main():
     parser.add_argument("--no-scatter", action="store_true")
     parser.add_argument("--slots", action="store_true")
     parser.add_argument("--conn-delta", action="store_true")
+    parser.add_argument("--jet-iteration", action="store_true")
     args = parser.parse_args()
+    jet_rows = [fn for flag, fn in (("conn_delta", conn_delta_row),
+                                    ("jet_iteration", jet_iteration_row))
+                if getattr(args, flag)]
     dev = jax.devices()[0]
     out = {"device": {"platform": dev.platform, "kind": dev.device_kind},
            "reps": REPS, "rows": []}
@@ -222,17 +297,18 @@ def main():
     rng = np.random.default_rng(0)
     for name in args.shapes.split(","):
         n, m, n_pad, m_pad = SHAPES[name]
-        make = symmetric_skewed_graph if args.conn_delta else skewed_graph
+        make = symmetric_skewed_graph if jet_rows else skewed_graph
         graph = device_graph_from_host(make(rng, n, m), n_pad=n_pad,
                                        m_pad=m_pad)
         values = jnp.asarray(
             rng.integers(0, 2**31 - 1, n_pad).astype(np.int32))
         shape = {"shape": name, "n_pad": n_pad, "m_pad": m_pad, "m": m}
-        if args.conn_delta:
-            for k in args.ks:
-                row = dict(shape, **conn_delta_row(rng, graph, k))
-                out["rows"].append(row)
-                print(json.dumps(row), flush=True)
+        if jet_rows:
+            for make_row in jet_rows:
+                for k in args.ks:
+                    row = dict(shape, **make_row(rng, graph, k))
+                    out["rows"].append(row)
+                    print(json.dumps(row), flush=True)
             continue
         if not args.no_scatter:
             row = dict(

@@ -164,6 +164,17 @@ def test_pruned_rides_the_progress_series(case):
                    if level > 0) > 0
 
 
+def test_conn_delta_rides_the_progress_series(case):
+    """0, 1 or 2 reconciles an iteration served by rows; past the gate
+    the Jet moves' own always is (it reuses the afterburner's rows), and
+    the counter replays with the partition."""
+    assert all(set(s["conn_delta"]) <= {0, 1, 2}
+               for _, s in case.closed.series)
+    assert all(set(s["conn_delta"]) <= {1, 2} for _, s in case.opened.series)
+    assert ([s["conn_delta"] for _, s in case.opened.series]
+            == [s["conn_delta"] for _, s in case.replay.series])
+
+
 def _iteration(graph, k):
     part = jnp.asarray(
         (np.arange(graph.n_pad) % k).astype(np.int32))

@@ -260,36 +260,51 @@ def _jet_case(kind: str, k: int, heavy: bool):
     return g, cap, jnp.asarray(p0)
 
 
-@pytest.mark.parametrize("heavy", [False, True], ids=["unit", "heavy"])
-@pytest.mark.parametrize("kind", ["rmat", "grid"])
-@pytest.mark.parametrize("k", [2, 8, 16])
-def test_jet_conn_buffer_matches_full_rebuilds(monkeypatch, k, kind, heavy):
+@pytest.mark.parametrize(
+    "k, kind, heavy, rows",
+    [pytest.param(k, kind, heavy, False,
+                  id=f"{k}-{kind}-{'heavy' if heavy else 'unit'}")
+     for heavy in (False, True) for kind in ("rmat", "grid")
+     for k in (2, 8, 16)]
+    # past the gate, the cases whose balancer moves straddle the buffer
+    + [pytest.param(16, "rmat", False, True, id="16-rmat-unit-rows"),
+       pytest.param(16, "rmat", True, True, id="16-rmat-heavy-rows"),
+       pytest.param(16, "grid", False, True, id="16-grid-unit-rows")],
+)
+def test_jet_conn_buffer_matches_full_rebuilds(monkeypatch, k, kind, heavy,
+                                               rows):
     """Jet with the conn table kept by its movers' rows (buffer of
     m_pad // CONN_DELTA_DIVISOR slots, the shipped size) returns bitwise
     the partition of Jet that rebuilds the table at every reconcile
     (buffer forced to 0), on runs that have reconciles on both sides of
-    the threshold."""
+    the threshold.  With `rows` the gate is lowered to the graph's m_pad:
+    the Jet moves' reconcile then rides the afterburner's rows (always
+    counted) and the buffer serves the balancer's alone."""
     import kaminpar_tpu.ops.jet as jet_mod
     from kaminpar_tpu import telemetry
 
     g, cap, p0 = _jet_case(kind, k, heavy)
+    if rows:
+        monkeypatch.setattr(jet_mod, "DELTA_MIN_EDGE_SLOTS", g.src.shape[0])
+    assert jet_mod.iteration_path(g, k) == (
+        "jet-rows" if rows else "jet-edges")
     assert jet_mod._conn_slots(g) == g.src.shape[0] // 16 > 0
 
     def run():
         telemetry.reset()
-        out = np.asarray(jet_refine(
-            g, p0, k, cap, jnp.int32(4), JetRefinementContext(), 1, 2))
+        jet_mod._jet_chunk.clear_cache()
+        try:
+            out = np.asarray(jet_refine(
+                g, p0, k, cap, jnp.int32(4), JetRefinementContext(), 1, 2))
+        finally:
+            jet_mod._jet_chunk.clear_cache()
         delta = [c for s in telemetry.progress_series("jet")
                  for c in s.series["conn_delta"]]
         return out, delta
 
     def run_with(slots):
         monkeypatch.setattr(jet_mod, "_conn_slots", lambda graph: slots)
-        jet_mod._jet_chunk.clear_cache()
-        try:
-            return run()
-        finally:
-            jet_mod._jet_chunk.clear_cache()
+        return run()
 
     telemetry.enable()
     shipped, delta = run()
@@ -297,10 +312,40 @@ def test_jet_conn_buffer_matches_full_rebuilds(monkeypatch, k, kind, heavy):
     # a buffer as wide as the edge array takes every reconcile: its
     # counter is the number of reconciles an iteration had
     _, reconciles = run_with(g.src.shape[0])
-    assert set(none) == {0} and set(reconciles) <= {1, 2}
+    always = int(rows)  # the afterburner's rows serve the Jet moves' own
+    assert set(none) == {always} and set(reconciles) <= {1, 2}
     assert any(d < r for d, r in zip(delta, reconciles)), delta
-    assert any(d > 0 for d in delta), delta
+    assert any(d > always for d in delta), delta
     np.testing.assert_array_equal(shipped, rebuilt)
+
+
+@pytest.mark.parametrize("rows", [False, True], ids=["jet-edges", "jet-rows"])
+def test_conn_reconciles_take_their_own_buffer_at_every_size(
+        monkeypatch, rows):
+    """Every reconcile _conn_step takes goes through
+    m_pad // CONN_DELTA_DIVISOR slots on both sides of the gate (two an
+    iteration under it, the balancer's past it), whatever the
+    afterburner's buffer is."""
+    import kaminpar_tpu.ops.jet as jet_mod
+
+    g, cap, p0 = _jet_case("grid", 4, False)
+    m_pad = g.src.shape[0]
+    if rows:
+        monkeypatch.setattr(jet_mod, "DELTA_MIN_EDGE_SLOTS", m_pad)
+    assert jet_mod._delta_slots(g) == (m_pad // 4 if rows else None)
+    want = m_pad // jet_mod.CONN_DELTA_DIVISOR
+    assert jet_mod._conn_slots(g) == want
+    widths = []
+    real = jet_mod._conn_update_rows
+
+    def recording(graph, conn, before, after, k, dslots):
+        widths.append(dslots)
+        return real(graph, conn, before, after, k, dslots)
+
+    monkeypatch.setattr(jet_mod, "_conn_update_rows", recording)
+    jet_mod._jet_iteration(
+        g, p0, jnp.zeros_like(p0), 4, cap, jnp.float32(0.75), jnp.int32(5), 4)
+    assert widths == [want] * (1 if rows else 2)
 
 
 @pytest.mark.parametrize("over", [0, 1], ids=["fits", "one-over"])
