@@ -294,8 +294,9 @@ class RefinerPipeline:
                         self.ctx.refinement.fm,
                         seed=seed,
                         # reference-style worker pool (fm_refiner.cc:48);
-                        # 1 on this dev box (one logical CPU) keeps runs
-                        # bitwise-deterministic
+                        # num_workers is 1 in every preset, whatever
+                        # the host's cores (13 beside the chip): one
+                        # thread is what replays bitwise
                         threads=self.ctx.parallel.num_workers,
                     )
         else:

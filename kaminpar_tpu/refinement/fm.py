@@ -6,14 +6,30 @@ thread-local delta partitions and a shared border-node queue.  FM's
 priority-queue-driven, one-node-at-a-time control flow has no efficient TPU
 mapping (the reference's own Jet paper makes the same observation — Jet is
 its bulk-synchronous replacement and runs on device here, ops/jet.py).  FM
-therefore stays host-side, mirroring the reference's *sequential* FM
-structure with a global gain PQ over border nodes, best-prefix rollback and
-the simple stopping rule (num_fruitless_moves).
+therefore stays host-side, behind one entry, `fm_refine_host`, with two
+engines:
 
-The per-node gain bookkeeping uses the dense gain cache
-(refinement/gains.HostDenseGainCache, the DenseGainCache strategy): an
-(n, k) connection matrix built once per pass and updated incrementally on
-each move, so best-move queries are O(k) instead of O(deg).
+* **native** (native/fm.cpp, the engine a build with a toolchain runs):
+  the reference's *localized batch* FM — regions grown from
+  `num_seed_nodes` border seeds against a delta gain overlay, each
+  region's best prefix committed — on `threads` workers (1 in every
+  preset: one thread replays bitwise).
+* **numpy** (`_fm_pass` below; the fallback twin where the library is
+  unavailable, and `KAMINPAR_TPU_NO_NATIVE_FM=1`): the reference's
+  *sequential* FM structure with a global gain PQ over border nodes,
+  best-prefix rollback and the simple stopping rule
+  (num_fruitless_moves).  Its per-node gain bookkeeping uses the dense
+  gain cache (refinement/gains.HostDenseGainCache, the DenseGainCache
+  strategy): an (n, k) connection matrix built once per pass and updated
+  incrementally on each move, so best-move queries are O(k) instead of
+  O(deg).
+
+Timer scopes (utils/timer.py; each is a profiler span): the caller's
+`kway-fm` holds `graph-download` (the level read back), then `fm-native`
+or `fm-numpy`, named by the engine that ran (both where the native call
+gave up and the twin took over; a native refusal returns from
+`fm-native` at once, runs no twin and leaves the partition unchanged),
+then `partition-upload` (the padded labels going back to the device).
 """
 
 from __future__ import annotations
@@ -27,6 +43,7 @@ from ..context import FMRefinementContext
 from ..graphs.csr import DeviceGraph, host_graph_from_device
 from ..graphs.host import HostGraph
 from ..telemetry import progress as progress_mod
+from ..utils.timer import scoped_timer
 from .gains import create_host_gain_cache
 
 
@@ -41,9 +58,10 @@ def fm_refine_host(
 ):
     """Refine a device partition with host FM; returns a device partition.
 
-    Runs ctx.num_iterations passes; each pass processes border nodes from a
-    global max-gain PQ with best-prefix rollback (FMRefiner::refine
-    structure, fm_refiner.cc)."""
+    Runs ctx.num_iterations passes of the native localized batch FM, or
+    of the numpy twin where that is unavailable or opted out of: border
+    nodes from a global max-gain PQ with best-prefix rollback
+    (FMRefiner::refine structure, fm_refiner.cc)."""
     import jax.numpy as jnp
 
     graph = host_graph_from_device(dgraph)
@@ -55,6 +73,7 @@ def fm_refine_host(
 
     import os
 
+    @scoped_timer("fm-numpy")
     def _numpy_fm() -> np.ndarray:
         node_w = graph.node_weight_array()
         edge_w = graph.edge_weight_array()
@@ -101,9 +120,10 @@ def fm_refine_host(
             # parallel localized scheme minus threads: seeded regions
             # grown against a delta gain overlay, best prefixes
             # committed); refines `part` in place
-            improvement = native.fm_refine(
-                graph, part, k, max_bw, ctx, seed, threads=threads
-            )
+            with scoped_timer("fm-native"):
+                improvement = native.fm_refine(
+                    graph, part, k, max_bw, ctx, seed, threads=threads
+                )
             if improvement is None:
                 raise NativeUnavailable(
                     "native FM library unavailable (build failed or "
@@ -137,9 +157,10 @@ def fm_refine_host(
 
         part = with_fallback(_native_fm, _fm_fallback, site="native-fm")
 
-    padded = np.zeros(dgraph.n_pad, dtype=np.int32)
-    padded[:n] = part
-    return jnp.asarray(padded)
+    with scoped_timer("partition-upload"):
+        padded = np.zeros(dgraph.n_pad, dtype=np.int32)
+        padded[:n] = part
+        return jnp.asarray(padded)
 
 
 def _fm_pass(graph, part, node_w, edge_w, max_bw, k, ctx, rng):

@@ -9,6 +9,7 @@ session, for the trees that must not depend on it.
 
 import glob
 import os
+from contextlib import contextmanager
 from types import SimpleNamespace
 
 import pytest
@@ -20,6 +21,9 @@ from kaminpar_tpu.utils.timer import REQUEST_SPAN, SPAN_PREFIX, Timer
 #: reads back from the device, one where the device waits for the host
 NEW_SCOPES = {"graph-download", "extend-pull", "refine-probe",
               "balance-check", "partition-download", "isolated-nodes"}
+#: directly under every `kway-fm` (the `strong` preset's host FM): the
+#: level read back, the engine that ran, the labels going back up
+FM_SCOPES = {"graph-download", "fm-native", "partition-upload"}
 #: the nodes the benchmark's span metrics address (perfbench/harness/
 #: timer_tree.py), by path or by name wherever they sit
 PHASE_PATHS = ("partitioning.coarsening", "partitioning.initial-partitioning",
@@ -71,23 +75,53 @@ def _disabled(t: Timer) -> None:
         t.enabled = True
 
 
-def _partition():
+def _partition(preset="default", spec="gen:rmat;n=8192;m=60000;seed=3", k=4):
     import kaminpar_tpu as ktp
     from kaminpar_tpu.graphs.factories import generate
     from kaminpar_tpu.utils.logger import OutputLevel
 
-    graph = generate("gen:rmat;n=8192;m=60000;seed=3")
-    solver = ktp.KaMinPar("default")
+    graph = generate(spec)
+    solver = ktp.KaMinPar(preset)
     solver.set_output_level(OutputLevel.QUIET)
-    part = solver.set_graph(graph).compute_partition(k=4, epsilon=0.03, seed=1)
+    part = solver.set_graph(graph).compute_partition(k=k, epsilon=0.03, seed=1)
     return graph, part, _tree(timer.GLOBAL_TIMER.root)
+
+
+@contextmanager
+def _profiler_session(trace_dir):
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.enable_hlo_proto = False
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+def _program_spans(trace_dir):
+    """(line, start_ns, end_ns, name, stats) of every program span of the
+    one trace under `trace_dir`, outermost first."""
+    from jax.profiler import ProfileData
+
+    (xplane_path,) = glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    spans = []
+    for plane in ProfileData.from_file(xplane_path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(SPAN_PREFIX):
+                    spans.append((line.name, ev.start_ns,
+                                  ev.start_ns + ev.duration_ns, ev.name,
+                                  {str(k): str(v) for k, v in ev.stats}))
+    spans.sort(key=lambda s: (s[1], -s[2]))
+    return spans
 
 
 @pytest.fixture(scope="module")
 def session(tmp_path_factory):
-    import jax
-    from jax.profiler import ProfileData
-
     scenarios = {"nested": _nested, "unwound": _unwound,
                  "disabled": _disabled}
     outside = {}
@@ -98,30 +132,14 @@ def session(tmp_path_factory):
     graph, part_outside, tree_outside = _partition()
 
     trace_dir = str(tmp_path_factory.mktemp("trace"))
-    options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 0
-    options.enable_hlo_proto = False
-    jax.profiler.start_trace(trace_dir, profiler_options=options)
-    try:
+    with _profiler_session(trace_dir):
         inside, timers = {}, {}
         for name, scenario in scenarios.items():
             timers[name] = t = Timer()
             scenario(t)
             inside[name] = _tree(t.root)
         _, part_inside, tree_inside = _partition()
-    finally:
-        jax.profiler.stop_trace()
-    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
-                                     "*.xplane.pb"))
-    spans = []  # (line, start_ns, end_ns, name, stats)
-    for plane in ProfileData.from_file(path).planes:
-        for line in plane.lines:
-            for ev in line.events:
-                if ev.name.startswith(SPAN_PREFIX):
-                    spans.append((line.name, ev.start_ns,
-                                  ev.start_ns + ev.duration_ns, ev.name,
-                                  {str(k): str(v) for k, v in ev.stats}))
-    spans.sort(key=lambda s: (s[1], -s[2]))
+    spans = _program_spans(trace_dir)
     return SimpleNamespace(
         spans=spans, outside=outside, inside=inside, timers=timers,
         graph=graph, part_outside=part_outside, part_inside=part_inside,
@@ -257,3 +275,82 @@ def test_the_rating_engine_is_a_scope_under_lp_clustering(session):
     scatter = "partitioning.coarsening.lp-clustering.rating-scatter"
     assert scatter in tree
     assert SPAN_PREFIX + scatter in {s[3] for s in session.spans}
+
+
+#: a skewed graph at k = 4, and a mesh at the k of the benchmark's
+#: `strong` cell (three k-doublings, one of them refined lightly)
+STRONG_REQUESTS = {"rmat-k4": ("gen:rmat;n=8192;m=60000;seed=3", 4),
+                   "delaunay-k16": ("gen:delaunay;n=4096;seed=1", 16)}
+
+
+@pytest.fixture(scope="module", params=list(STRONG_REQUESTS))
+def strong_session(request, tmp_path_factory):
+    """One `strong` request (Jet and the host k-way FM alternated) in a
+    profiler session of its own: its spans and its timer tree."""
+    trace_dir = str(tmp_path_factory.mktemp("trace-strong"))
+    with _profiler_session(trace_dir):
+        _, _, tree = _partition("strong", *STRONG_REQUESTS[request.param])
+    return SimpleNamespace(spans=_program_spans(trace_dir), tree=tree)
+
+
+def test_the_fm_scopes_are_spans_inside_their_kway_fm(strong_session):
+    """Every `kway-fm` call holds one span each of the read-back, the
+    engine and the upload, in that order and inside it; the spans are
+    the timer tree, path for path and count for count."""
+    counts = {}
+    for _, _, _, name, _ in strong_session.spans:
+        if name != REQUEST_SPAN:
+            path = name[len(SPAN_PREFIX):]
+            counts[path] = counts.get(path, 0) + 1
+    assert counts == strong_session.tree
+    calls = [s for s in strong_session.spans if s[3].endswith(".kway-fm")]
+    assert calls
+    for line, lo, hi, name, _ in calls:
+        inside = [s for s in strong_session.spans
+                  if s[3].startswith(name + ".") and lo <= s[1] and s[2] <= hi]
+        assert [s[3][len(name) + 1:] for s in inside] == [
+            "graph-download", "fm-native", "partition-upload"]
+        assert all(s[0] == line for s in inside)
+        assert all(a[2] <= b[1] for a, b in zip(inside, inside[1:]))
+    names = {path.rsplit(".", 1)[-1] for path in strong_session.tree}
+    assert "lp-refinement" not in names and "fm-numpy" not in names
+
+
+def test_the_benchmarks_layers_do_not_move_for_the_paths_that_were_there(
+        session, strong_session):
+    """Every older path keeps the layer `phase_reduce.layer_of` gave it,
+    and a `strong` request's paths, the FM scopes among them, fall into
+    one of the four layers (which one is the benchmark's to say)."""
+    from perfbench.harness import phase_reduce
+
+    up = "partitioning.uncoarsening"
+    assert {
+        path: phase_reduce.layer_of(path) for path in (
+            "partitioning", "partitioning.coarsening.lp-clustering",
+            "partitioning.coarsening.contraction",
+            "partitioning.initial-partitioning.graph-download",
+            up + ".jet", up + ".jet.jet-edges", up + ".lp-refinement",
+            up + ".overload-balancer", up + ".underload-balancer",
+            up + ".refine-probe", up + ".extend-pull.graph-download",
+            up + ".extend-partition.jet.jet-edges",
+            "partitioning.partition-download")
+    } == {
+        "partitioning": ("driver", ""),
+        "partitioning.coarsening.lp-clustering": ("coarsening", "coarsening"),
+        "partitioning.coarsening.contraction": ("coarsening", "coarsening"),
+        "partitioning.initial-partitioning.graph-download": ("driver", ""),
+        up + ".jet": ("refinement", "jet"),
+        up + ".jet.jet-edges": ("refinement", "jet"),
+        up + ".lp-refinement": ("refinement", "lp-refinement"),
+        up + ".overload-balancer": ("refinement", "overload-balancer"),
+        up + ".underload-balancer": ("refinement", "underload-balancer"),
+        up + ".refine-probe": ("driver", ""),
+        up + ".extend-pull.graph-download": ("extend", "extend-pull"),
+        up + ".extend-partition.jet.jet-edges": ("refinement", "jet"),
+        "partitioning.partition-download": ("driver", ""),
+    }
+    layers = {"coarsening", "refinement", "extend", "driver"}
+    for tree in (session.tree_inside, strong_session.tree):
+        assert {phase_reduce.layer_of(path)[0] for path in tree} <= layers
+    fm_paths = [p for p in strong_session.tree if "kway-fm" in p.split(".")]
+    assert {p.rsplit(".", 1)[-1] for p in fm_paths} == FM_SCOPES | {"kway-fm"}
