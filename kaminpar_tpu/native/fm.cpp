@@ -22,8 +22,9 @@
 // delta overlay re-checks gains before applying, and the global table
 // stays exact because every update is an exact integer fetch_add.
 //
-// Dense (n, k) gain table (gains/sparse_gain_cache.h lineage), sparse
-// delta map, adaptive (Osipov-Sanders) or simple stopping.
+// Dense (n, k) gain table (gains/sparse_gain_cache.h lineage), delta
+// overlay of arena rows behind a flat node-indexed slot array, adaptive
+// (Osipov-Sanders) or simple stopping.
 
 #include <algorithm>
 #include <atomic>
@@ -98,47 +99,53 @@ void build_conn(Ctx& c) {
 // Delta overlay (delta_gain_caches.h analog): tentative partition and
 // gain-table deltas for the current batch.  Touched nodes get a dense
 // ARENA row copy of their (k-wide) connection row plus a tentative
-// block field — one hash lookup per row access instead of k map probes
-// per gain query (the hot path of the whole refiner).
+// block field.  A node finds its arena slot in a flat node-indexed array
+// (-1 = untouched this batch): a row access is one array read, where a
+// hash map cost a probe (~22 a tentative move, the hot path of the
+// whole refiner).  The array is n wide once a worker and pass; a batch
+// resets only the entries it set, by its list of the nodes it touched (there
+// are hundreds of batches a call, each touching a few thousand nodes).
 struct Delta {
   const Ctx* c;
-  std::unordered_map<int64_t, int32_t> slot;  // u -> arena index
-  std::vector<int64_t> rows;                  // arena, k per slot
-  std::vector<int32_t> blocks;                // arena slot -> tent. block
+  std::vector<int32_t> slot_of;  // u -> arena slot, -1 = untouched
+  std::vector<int32_t> node_of;  // arena slot -> u (the reset list)
+  std::vector<int64_t> rows;     // arena, k per slot
+  std::vector<int32_t> blocks;   // arena slot -> tent. block
   std::vector<int64_t> bw_delta;
 
-  explicit Delta(const Ctx& ctx) : c(&ctx), bw_delta(ctx.k, 0) {
-    slot.reserve(1 << 14);
-  }
+  explicit Delta(const Ctx& ctx)
+      : c(&ctx), slot_of(ctx.n, -1), bw_delta(ctx.k, 0) {}
   void clear() {
-    slot.clear();
+    for (const int32_t u : node_of) slot_of[u] = -1;
+    node_of.clear();
     rows.clear();
     blocks.clear();
     std::fill(bw_delta.begin(), bw_delta.end(), 0);
   }
   // arena row of u, materialized from the global table on first touch
   int64_t* row(int64_t u) {
-    auto [it, fresh] = slot.try_emplace(u, (int32_t)blocks.size());
-    if (fresh) {
+    int32_t s = slot_of[u];
+    if (s < 0) {
+      s = slot_of[u] = (int32_t)node_of.size();
+      node_of.push_back((int32_t)u);
       const size_t base = rows.size();
       rows.resize(base + c->k);
       for (int64_t b = 0; b < c->k; ++b)
         rows[base + b] = c->conn_at(u, b);
       blocks.push_back(c->part_at(u));
     }
-    return rows.data() + (int64_t)it->second * c->k;
+    return rows.data() + (int64_t)s * c->k;
   }
   int32_t block(int64_t u) const {
-    auto it = slot.find(u);
-    return it == slot.end() ? c->part_at(u) : blocks[it->second];
+    const int32_t s = slot_of[u];
+    return s < 0 ? c->part_at(u) : blocks[s];
   }
   // row view: the arena row when touched, else a temp copy of the
   // global row (atomic loads — the global row may be concurrently
   // updated by other batches' commits)
   const int64_t* row_view(int64_t u, int64_t* scratch) const {
-    auto it = slot.find(u);
-    if (it != slot.end())
-      return rows.data() + (int64_t)it->second * c->k;
+    const int32_t s = slot_of[u];
+    if (s >= 0) return rows.data() + (int64_t)s * c->k;
     for (int64_t b = 0; b < c->k; ++b) scratch[b] = c->conn_at(u, b);
     return scratch;
   }
@@ -146,7 +153,7 @@ struct Delta {
   // tentatively move u from -> to, updating neighbor rows
   void move(int64_t u, int32_t from, int32_t to) {
     row(u);  // materialize so the block override has a slot
-    blocks[slot.find(u)->second] = to;
+    blocks[slot_of[u]] = to;
     bw_delta[from] -= c->node_w[u];
     bw_delta[to] += c->node_w[u];
     for (int64_t e = c->xadj[u]; e < c->xadj[u + 1]; ++e) {
@@ -213,6 +220,54 @@ bool commit_move(Ctx& c, int64_t u, int32_t from, int32_t to) {
   return true;
 }
 
+// The batch's candidate moves, popped in descending (gain, tie, node,
+// target) order: exactly what one binary heap over all of them pops.
+// Most entries a batch pushes are never popped (four of five on a mesh:
+// the region stops or hits its cap first, and they sit below every move
+// it makes), so only those at or above a gain FLOOR are kept heap-ordered;
+// the rest wait unordered, and a push below the floor is an append.
+// When the heap runs empty the floor drops to the gain of the waiting
+// entries' best sixteenth (the top gain bucket where gains repeat, as
+// on an unweighted mesh; a sixteenth of them where every gain is
+// distinct, so the scan is paid for by the pops it feeds).
+struct MoveQueue {
+  using Entry = std::tuple<int64_t, uint32_t, int64_t, int32_t>;
+  std::vector<Entry> heap;  // gain >= floor, heap-ordered
+  std::vector<Entry> rest;  // gain < floor, unordered
+  int64_t floor = INT64_MAX;
+
+  bool empty() const { return heap.empty() && rest.empty(); }
+  void push(const Entry& e) {
+    if (std::get<0>(e) >= floor) {
+      heap.push_back(e);
+      std::push_heap(heap.begin(), heap.end());
+    } else {
+      rest.push_back(e);
+    }
+  }
+  Entry pop() {
+    if (heap.empty()) lower_floor();
+    std::pop_heap(heap.begin(), heap.end());
+    const Entry e = heap.back();
+    heap.pop_back();
+    return e;
+  }
+  void lower_floor() {
+    const auto by_gain = [](const Entry& a, const Entry& b) {
+      return std::get<0>(a) > std::get<0>(b);
+    };
+    const auto nth = rest.begin() + rest.size() / 16;
+    std::nth_element(rest.begin(), nth, rest.end(), by_gain);
+    floor = std::get<0>(*nth);
+    const auto waiting = std::partition(
+        rest.begin(), rest.end(),
+        [&](const Entry& e) { return std::get<0>(e) < floor; });
+    heap.assign(waiting, rest.end());
+    rest.erase(waiting, rest.end());
+    std::make_heap(heap.begin(), heap.end());
+  }
+};
+
 struct Move {
   int64_t u;
   int32_t from, to;
@@ -226,8 +281,7 @@ int64_t run_batch(Ctx& c, Delta& d, std::atomic<int32_t>* owner,
                   double alpha, int64_t num_fruitless, int use_adaptive,
                   Rng& rng, std::vector<int64_t>& scratch) {
   d.clear();
-  using Entry = std::tuple<int64_t, uint32_t, int64_t, int32_t>;
-  std::priority_queue<Entry> pq;
+  MoveQueue pq;
   std::vector<int64_t> touched;
 
   auto claim = [&](int64_t u) {
@@ -257,8 +311,7 @@ int64_t run_batch(Ctx& c, Delta& d, std::atomic<int32_t>* owner,
   const size_t max_moves = 4096;  // region safety cap
 
   while (!pq.empty() && moves.size() < max_moves) {
-    auto [g, tie, u, t] = pq.top();
-    pq.pop();
+    auto [g, tie, u, t] = pq.pop();
     if (owner[u].load(kRelaxed) != my_id) continue;  // lost to a commit
     // stale check: gains shift as the region moves.  Re-queue only on a
     // GAIN change — the target may legitimately differ on ties (random

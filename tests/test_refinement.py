@@ -2,12 +2,20 @@
 refinement unit coverage, e.g. gain_cache_test.cc validating gains against
 recomputation)."""
 
+import hashlib
+import os
+import re
+import subprocess
+import sys
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from mesh_reference import delaunay_mesh, recursive_coordinate_bisection
 from kaminpar_tpu.context import FMRefinementContext, JetRefinementContext
 from kaminpar_tpu.graphs import device_graph_from_host, factories
+from kaminpar_tpu.graphs.host import from_edge_list
 from kaminpar_tpu.ops import metrics
 from kaminpar_tpu.ops.balancer import overload_balance, underload_balance
 from kaminpar_tpu.ops.jet import jet_refine
@@ -573,6 +581,101 @@ def test_fm_sparse_compact_hashing_cache():
     assert imp_dn is not None and imp_dn > 0
     after_dn = int(metrics.edge_cut(dg, _pad_part(dg, part_dn)))
     assert after <= int(1.15 * after_dn) + 5
+
+
+def _fm_replay_case(name):
+    """`(graph, start)` of a golden-digest case: `start(k)` is a
+    deterministic partition that knows nothing of FM (coordinate blocks
+    of the mesh's points, index blocks elsewhere)."""
+    if name == "delaunay-2000":
+        points, g = delaunay_mesh(2000, seed=3)
+        return g, lambda k: recursive_coordinate_bisection(points, k)
+    if name == "rmat-1024":
+        # make_rmat merges its parallel edges into weights (up to 24 here)
+        g = factories.make_rmat(1 << 10, 8000, seed=5)
+    else:
+        # 40 x 40 grid, edge weights up to 2^20 from the endpoints alone
+        grid = factories.make_grid_graph(40, 40)
+        src = grid.edge_sources().astype(np.int64)
+        dst = grid.adjncy.astype(np.int64)
+        e = np.stack([src, dst], axis=1)[src < dst]
+        w = 1 + (e[:, 0] * 2654435761 + e[:, 1] * 40503) % (1 << 20)
+        g = from_edge_list(1600, e, w)
+    return g, lambda k: (np.arange(g.n) * k // g.n).astype(np.int32)
+
+
+#: (graph, k, seed) -> (sha1 of the refined int32 labels, returned gain),
+#: taken from the engine of PR 32 (`Delta` over an `unordered_map`) before
+#: PR 33 touched fm.cpp: whatever the engine's bookkeeping becomes, one
+#: thread returns these bytes.
+FM_GOLDEN = {
+    ("delaunay-2000", 2, 1): ("ca8372f102bee2efaafb2c048303137eddb0f0a9", 19),
+    ("delaunay-2000", 2, 7): ("a4f947bebfdfe488c51a795d2d0117c7add8d0f4", 19),
+    ("delaunay-2000", 4, 1): ("801637ae033db21e76629f1d7b5a4aa3490fa088", 31),
+    ("delaunay-2000", 4, 7): ("e132d72f36909591a418d6e860bca947dd53387f", 28),
+    ("delaunay-2000", 16, 1): ("3e286fa47b040533e94fc179512814ab4b26efe4", 110),
+    ("delaunay-2000", 16, 7): ("76817d1828194cdb6a6dea389c76ecc7e1a14c70", 106),
+    ("rmat-1024", 2, 1): ("72c94973342e53c7921f11c9a31dae5c88e45aec", 1910),
+    ("rmat-1024", 2, 7): ("d79f411c716fc0d201f7c4f09be59781856e27a9", 1835),
+    ("rmat-1024", 4, 1): ("7e28ec3b5d39a7eb331fa4a2dd2081a183f1a967", 2044),
+    ("rmat-1024", 4, 7): ("22fc3f4abd7c314b0911dc783b143a75d4843927", 2047),
+    ("rmat-1024", 16, 1): ("4e0592a1bd8363d6f325d814e96b31c16e25fcac", 1464),
+    ("rmat-1024", 16, 7): ("59a6c3088770ed8e0aba7d6f4a53355c779eeb53", 1412),
+    # distinct heavy weights leave no tie to break: at k = 2 both seeds
+    # end in the same labels
+    ("grid-40x40", 2, 1): ("e7cc219caf6a3499ec2e50c150efa87de3bbb758", 881680),
+    ("grid-40x40", 2, 7): ("e7cc219caf6a3499ec2e50c150efa87de3bbb758", 881680),
+    ("grid-40x40", 4, 1): ("e2e86400dd59c6e45818cf891fa96076e426c1e1", 13019656),
+    ("grid-40x40", 4, 7): ("797ff19506c3755bf1ff7ba5834afd869f737cb8", 14666744),
+    ("grid-40x40", 16, 1): ("cd2c9ab05bc879deedfec3c25825b75b72bc899a", 84136595),
+    ("grid-40x40", 16, 7): ("06a11524498b2e8cd99a47d881c8626cc098cfa5", 65955219),
+}
+
+
+@pytest.mark.parametrize("name,k,seed", sorted(FM_GOLDEN))
+def test_fm_native_replays_the_recorded_partition(name, k, seed):
+    from kaminpar_tpu import native
+
+    if not native.available():
+        pytest.skip("no native lib")
+    g, start = _fm_replay_case(name)
+    part = start(k)
+    cap = np.full(
+        k, int(1.03 * np.ceil(g.node_weight_array().sum() / k)), np.int64
+    )
+    gain = native.fm_refine(g, part, k, cap, FMRefinementContext(), seed=seed)
+    assert (hashlib.sha1(part.tobytes()).hexdigest(), gain) == FM_GOLDEN[
+        name, k, seed
+    ]
+
+
+def test_microbench_fm_prints_one_digest_twice():
+    """scripts/microbench_fm.py, the replay that sizes an FM change
+    without the pipeline, runs at n = 4,096 and is a replay: two
+    processes print the same cut and the same sha1 of the labels."""
+    from kaminpar_tpu import native
+
+    if not native.available():
+        pytest.skip("no native lib")
+    script = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "scripts", "microbench_fm.py",
+    )
+    last = []
+    for _ in range(2):
+        proc = subprocess.run(
+            [sys.executable, script, "--n", "4096", "--k", "16", "--seed",
+             "1", "--calls", "2"],
+            capture_output=True, text=True, timeout=300,
+        )
+        assert proc.returncode == 0, proc.stdout + proc.stderr
+        lines = proc.stdout.strip().splitlines()
+        assert len(lines) == 4, proc.stdout  # start, two calls, the total
+        last.append(re.fullmatch(
+            r"microbench_fm: 2 calls \S+ s (cut \d+ sha1 [0-9a-f]{40})",
+            lines[-1],
+        ).group(1))
+    assert last[0] == last[1]
 
 
 def test_jet_large_k_degrades_to_lp():
