@@ -9,6 +9,10 @@ multilevel orchestration run on the host; multi-chip scaling uses
 jax.sharding meshes with XLA collectives instead of MPI.
 """
 
+import time as _time
+
+_IMPORT_START = _time.perf_counter()  # first line: the package's own import
+
 from .graphs import (  # noqa: F401
     HostGraph,
     DeviceGraph,
@@ -25,3 +29,12 @@ from .presets import create_context_by_preset_name, get_preset_names  # noqa: F4
 from .kaminpar import KaMinPar, context_from_preset  # noqa: F401
 
 __version__ = "0.1.0"
+
+# set-up on the program's own account, telemetry on or off: the
+# jax.monitoring listeners, and what the lines above took (modules
+# imported lazily later are not in it; jax's own import is, unless the
+# caller imported jax first)
+from .telemetry import compile_account as _compile_account  # noqa: E402
+
+_compile_account.install()
+_compile_account.note_package_import(_time.perf_counter() - _IMPORT_START)

@@ -603,6 +603,11 @@ def main(argv: Optional[List[str]] = None) -> int:
             print(quality_line)
     if args.timers and not args.quiet:
         print(timer.GLOBAL_TIMER.render())
+        # what of the wall was tracing, lowering and compiling or loading,
+        # by scope and by executable (on with telemetry off too)
+        from .telemetry import compile_account
+
+        print(compile_account.render())
     if args.machine_timers and not args.quiet:
         print("TIMERS " + timer.GLOBAL_TIMER.render_machine())
     if args.heap_profile and not args.quiet:

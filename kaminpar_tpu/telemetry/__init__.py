@@ -114,13 +114,14 @@ def enable() -> None:
     global _enabled
     _enabled = True
     # The three accountings hook jax at install time: compile-cost
-    # accounting listens on jax.monitoring, the perf observatory wraps
-    # the backend-compile boundary and the execution ledger the
-    # executable-call boundary.  Each install is idempotent and its
-    # hooks pass straight through while telemetry is disabled (or
-    # KAMINPAR_TPU_PERF=0 / KAMINPAR_TPU_LEDGER=0).  A hook that no
-    # longer fits the installed jax raises here rather than leaving an
-    # empty section in the report.
+    # accounting listens on jax.monitoring (the package's import has
+    # installed it already: it runs with telemetry off too), the perf
+    # observatory wraps the backend-compile boundary and the execution
+    # ledger the executable-call boundary.  Each install is idempotent,
+    # and the latter two pass straight through while telemetry is
+    # disabled (or KAMINPAR_TPU_PERF=0 / KAMINPAR_TPU_LEDGER=0).  A hook
+    # that no longer fits the installed jax raises here rather than
+    # leaving an empty section in the report.
     from . import compile_account, ledger, perf
 
     compile_account.install()

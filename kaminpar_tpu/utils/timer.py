@@ -22,6 +22,7 @@ from typing import Dict, Optional
 from jax.profiler import TraceAnnotation
 
 from .. import telemetry
+from ..telemetry import compile_account
 
 #: every program span in a profiler trace starts with this (the benchmark
 #: does not import the program: perfbench/harness/phase_reduce.py and
@@ -237,11 +238,15 @@ def _span_exit_attrs(state: Optional[dict], sync_s: Optional[float]) -> dict:
 GLOBAL_TIMER = Timer()
 
 
+@contextmanager
 def request_span(**args):
     """The root profiler span of one request.  An annotation only, not a
     timer node: every scope of the request lies inside it on one thread,
-    and that containment is what ties a trace's spans to the request."""
-    return TraceAnnotation(REQUEST_SPAN, **args)
+    and that containment is what ties a trace's spans to the request.
+    The compile account notes the same interval on `perf_counter` as the
+    process's n-th request (telemetry/compile_account.request)."""
+    with compile_account.request(), TraceAnnotation(REQUEST_SPAN, **args):
+        yield
 
 
 @contextmanager

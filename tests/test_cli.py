@@ -50,6 +50,8 @@ def test_cli_partitions_and_writes_output(rgg2d_path, tmp_path, capfd):
     captured = capfd.readouterr()  # fd-level: the logger binds the real stderr
     assert "RESULT cut=" in captured.err
     assert "TIME io=" in captured.out
+    # -T: the timer tree, then the compile account (telemetry off)
+    assert "set-up of this process:" in captured.out
 
     part = np.loadtxt(out, dtype=np.int32)
     assert part.shape == (1024,)

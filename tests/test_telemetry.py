@@ -495,11 +495,15 @@ def test_compile_accounting_attributes_to_open_scope():
     assert snap["totals"]["compile_s"] > 0
     assert "compile-probe" in snap["phases"]
     assert snap["phases"]["compile-probe"]["compiles"] >= 1
-    # disabled: the listeners stay installed but record nothing
+    # disabled: the account goes on (the set-up a benchmark cell
+    # measures is taken with telemetry off)
     compile_account.reset()
     telemetry.disable()
-    jax.jit(lambda x: x * 3 + 2)(jnp.arange(8)).block_until_ready()
-    assert compile_account.snapshot()["totals"]["compiles"] == 0
+    with timer.GLOBAL_TIMER.scope("compile-probe-off"):
+        jax.jit(lambda x: x * 3 + 2)(jnp.arange(8)).block_until_ready()
+    snap = compile_account.snapshot()
+    assert snap["totals"]["compiles"] >= 1
+    assert snap["phases"]["compile-probe-off"]["compile_s"] > 0
 
 
 # ---------------------------------------------------------------------------
