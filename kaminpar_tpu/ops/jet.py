@@ -52,20 +52,25 @@ from .segments import (
     prune_candidates_to_budget,
 )
 
-# From this many edge slots on, Jet prunes its candidates to a row buffer
-# (prune_candidates_to_budget), runs the afterburner over that buffer
-# (packed_afterburner_gain_rows: a different move set, a different cut)
-# and takes the smaller coarse iteration budget (mirrors
-# ops/lp.DELTA_MIN_EDGE_SLOTS).  A reconcile through _conn_step does not
-# ask it: that takes CONN_DELTA_DIVISOR's buffer at every size.
+# From this many edge slots on, Jet PRUNES its candidates to a row buffer
+# of m_pad // 4 slots (prune_candidates_to_budget: a different move set,
+# a different cut), so its afterburner always runs over that buffer
+# (_rows_filter), and takes the smaller coarse iteration budget (mirrors
+# ops/lp.DELTA_MIN_EDGE_SLOTS).  Under it nothing is pruned: an
+# iteration runs the same row afterburner through CONN_DELTA_DIVISOR's
+# buffer when its candidates' rows fit it, and the edge-wide one
+# (_edges_filter) when they do not; the partition is the same either
+# way.  A reconcile through _conn_step does not ask the gate: that takes
+# CONN_DELTA_DIVISOR's buffer at every size.
 DELTA_MIN_EDGE_SLOTS = 1 << 22
 
-# _conn_step updates the conn table from the movers' CSR rows while their
-# degrees sum to at most m_pad // CONN_DELTA_DIVISOR slots, and rebuilds
-# it otherwise, on either side of the gate above.  The update costs per
-# slot of its buffer, full or not: 8 costs nearly a k = 2 rebuild, 4
-# twice one, 32 misses half of R-MAT's coarse iterations at k = 16
-# (PERF.md, PR 29 and 31).
+# Under the gate an iteration runs its afterburner over the candidates'
+# CSR rows while their degrees sum to at most m_pad // CONN_DELTA_DIVISOR
+# slots; _conn_step updates the conn table from the movers' rows through
+# a buffer of the same width, and rebuilds it otherwise, on either side
+# of the gate.  Either costs per slot of the buffer, full or not: 8
+# costs nearly a k = 2 rebuild, 4 twice one, 32 misses half of R-MAT's
+# coarse iterations at k = 16 (PERF.md, PR 29, 31 and 35).
 CONN_DELTA_DIVISOR = 16
 
 # Largest dense (n_pad, k) conn table Jet will materialize (int32
@@ -84,7 +89,10 @@ def _delta_slots(graph: DeviceGraph) -> int | None:
 def iteration_path(graph: DeviceGraph, k: int) -> str:
     """Which iteration `jet_refine` runs on `graph` at `k`, from the shapes
     alone: `jet-rows` (candidates pruned to a row buffer, the afterburner
-    over that buffer), `jet-edges` (the edge-wide afterburner) or `jet-lp`
+    always over that buffer), `jet-edges` (nothing pruned; the afterburner
+    over the candidates' rows in the iterations where they fit
+    `_conn_slots`, edge-wide in the others: the `rows` column of the
+    progress series says which, the name follows the shapes) or `jet-lp`
     (no dense table: LP refinement rounds).  The refiner names a timer
     scope after it, so a trace of a run with telemetry off still says
     which Jet a level ran."""
@@ -94,7 +102,8 @@ def iteration_path(graph: DeviceGraph, k: int) -> str:
 
 
 def _conn_slots(graph: DeviceGraph) -> int:
-    """Row-buffer width of a _conn_step reconcile, whatever the path."""
+    """Row-buffer width of a _conn_step reconcile, whatever the path, and
+    of the row afterburner under the gate."""
     return graph.src.shape[0] // CONN_DELTA_DIVISOR
 
 
@@ -200,6 +209,101 @@ def _conn_step(
     return new_conn, fits.astype(jnp.int32)
 
 
+def _find_moves(
+    graph: DeviceGraph,
+    conn: jax.Array,
+    part: jax.Array,
+    lock: jax.Array,
+    k: int,
+    gain_temp: jax.Array,
+    salt: jax.Array,
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """Jet's find step (jet_refiner.cc:104-131) from the dense (n, k)
+    rating table of `part`: one segment_sum, no edge-list sort (the
+    gain-cache strategy Jet's paper assumes; caps checked by the
+    balancer, so require_fit=False like the reference's candidate step).
+    Returns (best external block, gain of moving there, connection to
+    the own block, candidate): every unlocked real border node whose
+    gain is above -floor(gain_temp * conn_own) is a candidate."""
+    best, best_conn, conn_own = best_from_dense(
+        conn, part, jnp.zeros((k,), ACC_DTYPE), graph.node_w,
+        jnp.zeros((k,), ACC_DTYPE), salt, require_fit=False,
+    )
+    gain = best_conn - conn_own
+    threshold = -jnp.floor(gain_temp * conn_own.astype(jnp.float32)).astype(
+        jnp.int32
+    )
+    is_real = jnp.arange(graph.n_pad, dtype=jnp.int32) < graph.n
+    candidate = is_real & (best >= 0) & (lock == 0) & (gain > threshold)
+    return best, gain, conn_own, candidate
+
+
+def _rows_filter(
+    graph: DeviceGraph,
+    conn: jax.Array,
+    part: jax.Array,
+    next_part: jax.Array,
+    gain: jax.Array,
+    candidate: jax.Array,
+    k: int,
+    slots: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """The afterburner over the candidates' CSR rows, laid into a buffer
+    of `slots` slots that the caller knows they fit, and the conn table
+    of the accepted moves from the same buffer.  Returns (accept, conn).
+
+    Accepted movers are a subset of the candidates, whose rows the
+    afterburner has expanded and gathered: the conn update reuses
+    (owner_c, dst_b, w_b) and the (from, to) block columns the
+    afterburner returns (bit-packed endpoint metadata, one gather per
+    endpoint), so its only new irregular op is the accept gather.  Edges
+    of rejected candidates contribute weight 0."""
+    n_pad = graph.n_pad
+    owner_c, _, edge_id, valid, start, end = expand_active_rows(
+        graph.row_ptr, graph.degrees, candidate, slots
+    )
+    eid = jnp.clip(edge_id, 0, graph.src.shape[0] - 1)
+    dst_b = jnp.where(valid, graph.dst[eid], n_pad - 1)
+    w_b = jnp.where(valid, graph.edge_w[eid], 0)
+    adj_gain, from_u, to_u = packed_afterburner_gain_rows(
+        owner_c, dst_b, w_b, start, end,
+        part, next_part, gain, candidate, k,
+    )
+    accept = candidate & (adj_gain > 0)
+    acc_o = accept[owner_c]
+    w_m = jnp.where(acc_o, w_b, 0).astype(ACC_DTYPE)
+    new_b = jnp.where(acc_o, to_u, from_u)
+    return accept, _scatter_conn_delta_cols(
+        conn, from_u, new_b, dst_b, w_m, k, n_pad
+    )
+
+
+def _edges_filter(
+    graph: DeviceGraph,
+    part: jax.Array,
+    next_part: jax.Array,
+    gain: jax.Array,
+    candidate: jax.Array,
+    k: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """The afterburner over the whole edge array (two edge-wide passes,
+    one of them the `meta[dst]` gather), for candidates whose rows
+    overflow _conn_slots, and the conn table rebuilt.  Returns (accept,
+    conn).  No _conn_step here: where the candidates overflow the buffer
+    the movers do too (0 of 110 overflowing iterations of `rmat-s16.k16`
+    at --seed 1-3 had movers that fit; PERF.md, PR 35), and a third row
+    expansion in every _jet_chunk (beside the row filter's and the
+    balancer's) costs code on the device and seconds of lowering."""
+    adj_gain = packed_afterburner_gain(
+        graph.src, graph.dst, graph.edge_w, graph.row_ptr,
+        part, next_part, gain, candidate, k,
+    )
+    accept = candidate & (adj_gain > 0)
+    return accept, _full_ratings(
+        graph, jnp.where(accept, next_part, part), k
+    )
+
+
 def _jet_iteration(
     graph: DeviceGraph,
     part: jax.Array,
@@ -213,9 +317,9 @@ def _jet_iteration(
     conn: jax.Array | None = None,
 ) -> Tuple[jax.Array, ...]:
     """One Jet move round.  Returns (new_part, new_lock, ext_sum,
-    new_conn, conn_delta, pruned) where ext_sum = sum over real nodes of
-    (weighted degree - connection to own block) in the INPUT partition —
-    the rating table
+    new_conn, conn_delta, pruned, rows) where ext_sum = sum over real
+    nodes of (weighted degree - connection to own block) in the INPUT
+    partition — the rating table
     gives the input partition's edge cut for free as ext_sum / 2, saving
     the driver a separate edge-wide cut pass per iteration.  ext_sum =
     2*cut stays in int32 exactly when edge_cut itself would (unlike a
@@ -226,104 +330,81 @@ def _jet_iteration(
     `conn` is the incrementally-maintained dense (n, k) connection table
     for the INPUT partition (the gain cache Jet's paper assumes).  When
     None it is built from scratch; the returned new_conn matches the
-    OUTPUT partition bitwise either way (changed rows re-scattered, or a
-    full rebuild when too many nodes moved — lax.cond picks, see
-    _conn_step).  conn_delta counts the iteration's reconciles (after the
-    Jet moves, after the balancer's) that re-scattered rows: 0, 1 or 2;
-    pruned the candidates prune_candidates_to_budget dropped (0 on the
-    edge-wide path, which has no budget)."""
-    n_pad = graph.n_pad
-    node_ids = jnp.arange(n_pad, dtype=jnp.int32)
-    is_real = node_ids < graph.n
+    OUTPUT partition bitwise either way.  After the Jet moves it comes
+    from the candidates' rows where the afterburner ran over them and
+    from a full rebuild where it ran edge-wide; after the balancer's from
+    the movers' rows or a rebuild (lax.cond picks, see _conn_step).
+    conn_delta counts the iteration's two reconciles that re-scattered
+    rows: 0, 1 or 2; pruned the candidates prune_candidates_to_budget
+    dropped (0 under the gate, which has no budget); rows is 1 where the
+    afterburner ran over the candidates' rows (always past the gate;
+    under it where they fit _conn_slots) and 0 where it ran edge-wide."""
     dslots = _delta_slots(graph)
     conn_slots = _conn_slots(graph)
 
-    # ---- find moves (jet_refiner.cc:104-131) ----
-    # dense (n, k) rating table: one segment_sum, no edge-list sort (the
-    # gain-cache strategy Jet's paper assumes; caps checked by the
-    # balancer, so require_fit=False like the reference's candidate step)
     if conn is None:
         conn = _full_ratings(graph, part, k)
-    best, best_conn, conn_own = best_from_dense(
-        conn, part, jnp.zeros((k,), ACC_DTYPE), graph.node_w,
-        jnp.zeros((k,), ACC_DTYPE), salt, require_fit=False,
+    best, gain, conn_own, candidate = _find_moves(
+        graph, conn, part, lock, k, gain_temp, salt
     )
     if wdeg is not None:
+        is_real = jnp.arange(graph.n_pad, dtype=jnp.int32) < graph.n
         ext_sum = jnp.sum(
             jnp.where(is_real, wdeg - conn_own, 0).astype(ACC_DTYPE)
         )
     else:
         ext_sum = jnp.int32(0)
-    gain = best_conn - conn_own  # gain of moving to best external block
-    is_border = best >= 0
-    threshold = -jnp.floor(gain_temp * conn_own.astype(jnp.float32)).astype(
-        jnp.int32
-    )
-    candidate = (
-        is_real & is_border & (lock == 0) & (gain > threshold)
-    )
 
-    # ---- filter: afterburner (jet_refiner.cc:133-170) ----
-    # bit-packed endpoint metadata + streaming row sums, with a runtime
-    # clip-range guard; see segments.packed_afterburner_gain_rows
-    # (shared with LP refinement).
-    # Only edges of CANDIDATE rows contribute to the filter.  On large
-    # graphs the candidate set is first PRUNED to the best-gain subset
-    # whose rows fit the delta buffer (two-stage candidate pruning), so
-    # the filter's two gathers ALWAYS run at buffer width — no edge-wide
-    # fallback; pruned candidates compete again next iteration.
-    if dslots is None:
-        next_part = jnp.where(candidate, best, part)
-        adj_gain = packed_afterburner_gain(
-            graph.src, graph.dst, graph.edge_w, graph.row_ptr,
-            part, next_part, gain, candidate, k,
-        )
-        owner_c = dst_b = w_b = from_u = to_u = None
-        pruned = jnp.int32(0)
-    else:
+    # ---- filter: afterburner (jet_refiner.cc:133-170), execute
+    # (:172-183), and the rating table kept across the jet moves ----
+    # Only edges of CANDIDATE rows contribute to the filter, so it runs
+    # over those rows wherever a buffer holds them (_rows_filter: every
+    # pass at buffer width, the table updated from the same buffer).
+    # Past the gate the candidates are first PRUNED to the best-gain
+    # subset whose rows fit m_pad // 4 (two-stage candidate pruning;
+    # pruned candidates compete again next iteration), so they always
+    # fit.  Under it nothing is pruned: lax.cond takes the rows through
+    # _conn_step's buffer when the candidates' degrees sum to at most
+    # its width (a number already in hand, an n-wide reduce) and the
+    # edge-wide filter with a rebuild otherwise.  adj_gain of a
+    # non-candidate differs between the two and is masked by `candidate`
+    # in both; a candidate's is the same integers summed over the same
+    # row, and the packed / exact guard reads candidates' gains only:
+    # the partition and the table are bitwise the same whichever ran.
+    pruned = jnp.int32(0)
+    if dslots is not None:
         found = candidate
         candidate = prune_candidates_to_budget(
             candidate, gain, graph.degrees, salt ^ 0x5BD1E995, dslots
         )
         pruned = jnp.sum(found & ~candidate, dtype=ACC_DTYPE)
-        next_part = jnp.where(candidate, best, part)
-        owner_c, _, edge_id, valid, start, end = expand_active_rows(
-            graph.row_ptr, graph.degrees, candidate, dslots
+    next_part = jnp.where(candidate, best, part)
+    filter_args = (part, next_part, gain, candidate)
+    if dslots is not None:
+        rows = jnp.bool_(True)
+        accept, jet_conn = _rows_filter(graph, conn, *filter_args, k, dslots)
+    elif conn_slots == 0:
+        rows = jnp.bool_(False)
+        accept, jet_conn = _edges_filter(graph, *filter_args, k)
+    else:
+        # degree total <= m_pad < 2^31 (device layout)
+        # tpulint: disable=R3
+        cand_edges = jnp.sum(
+            jnp.where(candidate, graph.degrees, 0), dtype=jnp.int32
         )
-        eid = jnp.clip(edge_id, 0, graph.src.shape[0] - 1)
-        dst_b = jnp.where(valid, graph.dst[eid], n_pad - 1)
-        w_b = jnp.where(valid, graph.edge_w[eid], 0)
-        # bit-packed endpoint metadata: one gather per endpoint; the
-        # owner's (from, to) blocks come back for the conn-delta reuse
-        adj_gain, from_u, to_u = packed_afterburner_gain_rows(
-            owner_c, dst_b, w_b, start, end,
-            part, next_part, gain, candidate, k,
+        rows = cand_edges <= conn_slots
+        accept, jet_conn = lax.cond(
+            rows,
+            lambda conn, *args: _rows_filter(
+                graph, conn, *args, k, conn_slots
+            ),
+            lambda conn, *args: _edges_filter(graph, *args, k),
+            conn, *filter_args,
         )
-    accept = candidate & (adj_gain > 0)
-
-    # ---- execute (jet_refiner.cc:172-183) ----
+    # the row filter serves the Jet moves' reconcile from its buffer
+    rows = rows.astype(jnp.int32)
     new_part = jnp.where(accept, next_part, part)
     new_lock = accept.astype(jnp.int32)  # moved nodes rest next iteration
-
-    # ---- maintain the rating table across the jet moves ----
-    if dslots is None:
-        jet_conn, jet_delta = _conn_step(
-            graph, conn, part, new_part, k, conn_slots
-        )
-    else:
-        # accepted movers are a subset of the pruned candidate set, whose
-        # rows the afterburner ALREADY expanded and gathered — the conn
-        # update reuses (owner_c, dst_b, w_b) and the (from, to) block
-        # columns the afterburner returned; the only new irregular op is
-        # the accept gather.  Edges of rejected candidates contribute
-        # weight 0.
-        acc_o = accept[owner_c]
-        w_m = jnp.where(acc_o, w_b, 0).astype(ACC_DTYPE)
-        new_b = jnp.where(acc_o, to_u, from_u)
-        jet_conn = _scatter_conn_delta_cols(
-            conn, from_u, new_b, dst_b, w_m, k, n_pad
-        )
-        jet_delta = jnp.int32(1)
 
     # ---- rebalance (jet_refiner.cc:185-187) ----
     # while_loop, not fori: Jet iterations usually keep the partition
@@ -366,8 +447,8 @@ def _jet_iteration(
         lambda args: (args[0], jnp.int32(0)),
         (jet_conn, new_part, bal_part),
     )
-    return (bal_part, new_lock, ext_sum, new_conn, jet_delta + bal_delta,
-            pruned)
+    return (bal_part, new_lock, ext_sum, new_conn, rows + bal_delta, pruned,
+            rows)
 
 
 @partial(
@@ -426,7 +507,8 @@ def _jet_chunk(
         salt = (
             seed.astype(jnp.int32) * 31321 + rnd * 2221 + i * 1566083941
         ) & 0x7FFFFFFF
-        new_part, lock, ext_sum, conn, conn_delta, pruned = _jet_iteration(
+        (new_part, lock, ext_sum, conn, conn_delta, pruned,
+         rows) = _jet_iteration(
             graph,
             part,
             lock,
@@ -465,9 +547,12 @@ def _jet_chunk(
             # plots (and the reference's statistics registry prints);
             # conn_delta = conn-table reconciles served by the movers'
             # rows instead of a rebuild (0..2); pruned = candidates the
-            # row budget dropped (they compete again next iteration)
+            # row budget dropped (they compete again next iteration);
+            # rows = 1 where the afterburner ran over the candidates'
+            # rows, 0 where it ran edge-wide
             stats = progress_mod.record(
-                stats, i, cut, jnp.sum(lock), fruitless, conn_delta, pruned
+                stats, i, cut, jnp.sum(lock), fruitless, conn_delta, pruned,
+                rows,
             )
         return (j + 1, fruitless, new_part, lock, best, best_cut, conn,
                 stats)
@@ -588,7 +673,7 @@ def _jet_refine_impl(
             conn = _jet_build_conn(graph, part, k)
         # per-round progress buffer, row-indexed by the global iteration
         # so it rides across host-driven chunks without a host pull
-        stats = progress_mod.new_buffer(max_iterations, 5) if rec else None
+        stats = progress_mod.new_buffer(max_iterations, 6) if rec else None
         t0 = progress_mod.now()
         i = 0
         closed = False
@@ -632,7 +717,7 @@ def _jet_refine_impl(
             # driver's fruitless readback already synced the stream)
             progress_mod.emit(
                 "jet",
-                ("cut", "moved", "fruitless", "conn_delta", "pruned"),
+                ("cut", "moved", "fruitless", "conn_delta", "pruned", "rows"),
                 stats, t0, round=rnd, best_cut=int(best_cut),
             )
         # rollback to best (jet_refiner.cc:221-227): the round continues

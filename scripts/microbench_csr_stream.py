@@ -23,7 +23,13 @@ partition (a random one settled by SETTLE edge-wide iterations), the same
 table and the same salt: `jet-rows` as the program runs it from
 jet.DELTA_MIN_EDGE_SLOTS slots on, and `jet-edges` with that gate raised
 past the shape for this process alone; ms, and what each iteration
-counted (movers, conn_delta, pruned).  Every timing is the minimum
+counted (movers, conn_delta, pruned, rows).  At a shape UNDER that gate
+the pair is the one the iteration chooses between by itself: the row
+afterburner through _conn_slots (jet._rows_filter) against the edge-wide
+one followed by _conn_step (the iteration as it was before PR 35) from the
+same state, all nodes locked but a random set whose rows fill half the
+buffer, so the candidates fit it; their summed degree is printed.  Every
+timing is the minimum
 of REPS launches ending in block_until_ready; the labels[dst] gather both
 engines share is timed alone so it can be subtracted.  A small program
 compiles in ~25 s on the chip: name only what you need.
@@ -33,7 +39,7 @@ Usage: python scripts/microbench_csr_stream.py [--shapes coarse,fine,mesh]
     python scripts/microbench_csr_stream.py --conn-delta
     [--shapes fine,coarse,mesh,large] [--ks 2,16]
     python scripts/microbench_csr_stream.py --jet-iteration
-    [--shapes large] [--ks 2,16]
+    [--shapes large,fine,mesh] [--ks 2,16]
 (TPU; a CPU run only proves the script runs.)  Writes
 chiprun_out/microbench_csr_stream.json.
 """
@@ -220,21 +226,40 @@ def conn_delta_row(rng, graph, k):
     return row
 
 
-def jet_step(graph, k, caps, gate):
-    """One jitted _jet_iteration that resolves its path under `gate`
-    (jet.DELTA_MIN_EDGE_SLOTS is read while tracing; the program's own
-    value is back after every call)."""
+def parent_filter(graph, conn, part, next_part, gain, candidate, k, slots):
+    """jet._rows_filter's answer as an iteration under the gate computed
+    it before PR 35: the edge-wide afterburner, then _conn_step through
+    the same buffer (the rebuild _edges_filter adds is dead code here)."""
+    accept, _ = jet._edges_filter(graph, part, next_part, gain, candidate, k)
+    moved = jnp.where(accept, next_part, part)
+    return accept, jet._conn_step(graph, conn, part, moved, k, slots)[0]
+
+
+def jet_step(graph, k, caps, gate, rows_filter=None):
+    """One jitted _jet_iteration that resolves its path under `gate` and
+    filters its rows through `rows_filter` (default: the program's).
+    Both are read while tracing; the program's own are back after every
+    call."""
     step = jax.jit(lambda g, part, lock, conn, salt: jet._jet_iteration(
         g, part, lock, k, caps, jnp.float32(0.25), salt, 4, conn=conn))
 
     def call(part, lock, conn, salt):
-        shipped, jet.DELTA_MIN_EDGE_SLOTS = jet.DELTA_MIN_EDGE_SLOTS, gate
+        shipped = jet.DELTA_MIN_EDGE_SLOTS, jet._rows_filter
+        jet.DELTA_MIN_EDGE_SLOTS = gate
+        jet._rows_filter = rows_filter or jet._rows_filter
         try:
             return step(graph, part, lock, conn, salt)
         finally:
-            jet.DELTA_MIN_EDGE_SLOTS = shipped
+            jet.DELTA_MIN_EDGE_SLOTS, jet._rows_filter = shipped
 
     return call
+
+
+def candidate_edges(graph, part, lock, conn, k, salt):
+    """Summed degree of the candidates jet_step's iteration finds."""
+    *_, candidate = jet._find_moves(
+        graph, conn, part, lock, k, jnp.float32(0.25), salt)
+    return int(jnp.sum(jnp.where(candidate, graph.degrees, 0)))
 
 
 def jet_iteration_row(rng, graph, k):
@@ -246,30 +271,44 @@ def jet_iteration_row(rng, graph, k):
     part = jnp.asarray(np.where(
         np.arange(n_pad) < int(graph.n), rng.integers(0, k, n_pad), 0
     ).astype(np.int32))
-    paths = {
-        # a shape under the gate takes the rows path only for a rehearsal
-        "rows": jet_step(graph, k, caps,
-                         min(jet.DELTA_MIN_EDGE_SLOTS, m_pad)),
-        "edges": jet_step(graph, k, caps, 2 * m_pad),
-    }
+    under_gate = m_pad < jet.DELTA_MIN_EDGE_SLOTS
+    if under_gate:
+        paths = {"rows": jet_step(graph, k, caps, 2 * m_pad),
+                 "edges": jet_step(graph, k, caps, 2 * m_pad, parent_filter)}
+    else:
+        paths = {"rows": jet_step(graph, k, caps, jet.DELTA_MIN_EDGE_SLOTS),
+                 "edges": jet_step(graph, k, caps, 2 * m_pad)}
     lock = jnp.zeros(n_pad, jnp.int32)
     conn = jet._full_ratings(graph, part, k)
     salts = [jnp.int32((12345 + i * 1566083941) & 0x7FFFFFFF)
              for i in range(SETTLE + 1)]
     for salt in salts[:-1]:
-        part, lock, _, conn, _, _ = paths["edges"](part, lock, conn, salt)
-    row = dict(op="jet_iteration", k=k, settle=SETTLE,
-               conn_slots=jet._conn_slots(graph))
-    after = {}
+        part, lock, _, conn, *_ = paths["edges"](part, lock, conn, salt)
+    conn_slots = jet._conn_slots(graph)
+    row = dict(op="jet_iteration", k=k, settle=SETTLE, conn_slots=conn_slots,
+               under_gate=under_gate)
+    if under_gate:
+        # unlocked: nodes in random order while their rows fill half the
+        # buffer, so whatever the find step makes of them fits it
+        order = rng.permutation(n_pad)
+        free = np.cumsum(np.asarray(graph.degrees)[order]) <= conn_slots // 2
+        locked = np.ones(n_pad, np.int32)
+        locked[order[free]] = 0
+        lock = jnp.asarray(locked)
+        row["candidate_edges"] = candidate_edges(
+            graph, part, lock, conn, k, salts[-1])
+    after, table = {}, {}
     for name, step in paths.items():
         args = (part, lock, conn, salts[-1])
-        after[name], new_lock, _, _, conn_delta, pruned = step(*args)
+        (after[name], new_lock, _, table[name], conn_delta, pruned,
+         rows) = step(*args)
         row[f"{name}_ms"] = best_ms(step, *args)
         row[name] = dict(
             accepted=int(new_lock.sum()),
             changed=int((after[name] != part).sum()),
-            conn_delta=int(conn_delta), pruned=int(pruned))
+            conn_delta=int(conn_delta), pruned=int(pruned), rows=int(rows))
     row["same_partition"] = bool(jnp.all(after["rows"] == after["edges"]))
+    row["same_table"] = bool(jnp.all(table["rows"] == table["edges"]))
     return row
 
 

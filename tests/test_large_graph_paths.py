@@ -155,7 +155,7 @@ def test_every_jet_scope_names_the_iteration_it_resolved_to(case):
 
 def test_pruned_rides_the_progress_series(case):
     assert all(set(s["pruned"]) == {0} for _, s in case.closed.series)
-    assert all(len(s) == 5 and min(s["pruned"]) >= 0
+    assert all(len(s) == 6 and min(s["pruned"]) >= 0
                for _, s in case.opened.series)
     if case.k == 4:
         # the coarse call's first iteration finds more rows than
@@ -173,6 +173,22 @@ def test_conn_delta_rides_the_progress_series(case):
     assert all(set(s["conn_delta"]) <= {1, 2} for _, s in case.opened.series)
     assert ([s["conn_delta"] for _, s in case.opened.series]
             == [s["conn_delta"] for _, s in case.replay.series])
+
+
+def test_rows_rides_the_progress_series(case):
+    """The sixth column: 1 in every iteration of a `jet-rows` call (the
+    pruned candidates always fit their buffer); under the gate 1 where
+    the candidates' rows fit `_conn_slots` and 0 where the afterburner
+    ran edge-wide, and a row iteration counts its own reconcile."""
+    assert all(list(s)[5] == "rows" for _, s in case.opened.series)
+    assert all(set(s["rows"]) == {1} for _, s in case.opened.series)
+    closed = [s for _, s in case.closed.series]
+    assert all(set(s["rows"]) <= {0, 1} for s in closed)
+    assert {r for s in closed for r in s["rows"]} == {0, 1}
+    assert all(d >= r for s in closed
+               for d, r in zip(s["conn_delta"], s["rows"]))
+    assert ([s["rows"] for _, s in case.opened.series]
+            == [s["rows"] for _, s in case.replay.series])
 
 
 def _iteration(graph, k):

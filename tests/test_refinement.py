@@ -330,10 +330,10 @@ def test_jet_conn_buffer_matches_full_rebuilds(monkeypatch, k, kind, heavy,
 @pytest.mark.parametrize("rows", [False, True], ids=["jet-edges", "jet-rows"])
 def test_conn_reconciles_take_their_own_buffer_at_every_size(
         monkeypatch, rows):
-    """Every reconcile _conn_step takes goes through
-    m_pad // CONN_DELTA_DIVISOR slots on both sides of the gate (two an
-    iteration under it, the balancer's past it), whatever the
-    afterburner's buffer is."""
+    """Every reconcile _conn_step takes (the balancer's, on both sides
+    of the gate) goes through m_pad // CONN_DELTA_DIVISOR slots, whatever
+    the afterburner's buffer is; the afterburner's is m_pad // 4 past
+    the gate and that same sixteenth under it."""
     import kaminpar_tpu.ops.jet as jet_mod
 
     g, cap, p0 = _jet_case("grid", 4, False)
@@ -343,17 +343,23 @@ def test_conn_reconciles_take_their_own_buffer_at_every_size(
     assert jet_mod._delta_slots(g) == (m_pad // 4 if rows else None)
     want = m_pad // jet_mod.CONN_DELTA_DIVISOR
     assert jet_mod._conn_slots(g) == want
-    widths = []
-    real = jet_mod._conn_update_rows
+    widths, filters = [], []
+    real, real_filter = jet_mod._conn_update_rows, jet_mod._rows_filter
 
     def recording(graph, conn, before, after, k, dslots):
         widths.append(dslots)
         return real(graph, conn, before, after, k, dslots)
 
+    def recording_filter(*args):
+        filters.append(args[-1])
+        return real_filter(*args)
+
     monkeypatch.setattr(jet_mod, "_conn_update_rows", recording)
+    monkeypatch.setattr(jet_mod, "_rows_filter", recording_filter)
     jet_mod._jet_iteration(
         g, p0, jnp.zeros_like(p0), 4, cap, jnp.float32(0.75), jnp.int32(5), 4)
-    assert widths == [want] * (1 if rows else 2)
+    assert widths == [want]
+    assert filters == [m_pad // 4 if rows else want]
 
 
 @pytest.mark.parametrize("over", [0, 1], ids=["fits", "one-over"])
@@ -379,11 +385,11 @@ def test_conn_step_threshold(over):
 
 
 def test_jet_conn_delta_counter_rides_only_the_stats_buffer():
-    """The conn_delta and pruned counters are the fourth and fifth column
-    of the `jet` progress series; with telemetry off _jet_chunk's loop
-    has the carries it had before the counters (j, fruitless, part, lock,
-    best, best_cut, conn) and with it on exactly one more, the stats
-    buffer."""
+    """The conn_delta, pruned and rows counters are the fourth, fifth and
+    sixth column of the `jet` progress series; with telemetry off
+    _jet_chunk's loop has the carries it had before the counters (j,
+    fruitless, part, lock, best, best_cut, conn) and with it on exactly
+    one more, the stats buffer, one column wider for `rows`."""
     import jax
 
     import kaminpar_tpu.ops.jet as jet_mod
@@ -401,14 +407,24 @@ def test_jet_conn_delta_counter_rides_only_the_stats_buffer():
             jnp.float32(0.999), jnp.int32(1), jnp.int32(0), jnp.int32(4),
             wdeg, 2**30, 4, stats))(p0, stats)
 
-    def carries(jaxpr):
+    def loop(jaxpr):
+        """_jet_chunk's iteration loop: the `while` with most carries."""
         (pjit,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "jit"]
-        loops = [e for e in pjit.params["jaxpr"].jaxpr.eqns
-                 if e.primitive.name == "while"]
-        return max(len(e.outvars) for e in loops)
+        return max((e for e in pjit.params["jaxpr"].jaxpr.eqns
+                    if e.primitive.name == "while"),
+                   key=lambda e: len(e.outvars))
 
-    assert carries(chunk(None)) == 7
-    assert carries(chunk(progress_mod.new_buffer(4, 5))) == 8
+    off = loop(chunk(None))
+    assert len(off.outvars) == 7
+    assert len(loop(chunk(progress_mod.new_buffer(4, 6))).outvars) == 8
+    # the buffer is as wide as the series has names: one of five columns
+    # (the series before `rows`) does not take the record
+    with pytest.raises(ValueError):
+        chunk(progress_mod.new_buffer(4, 5))
+    # telemetry off, every carry is a scalar, a node-wide vector or the
+    # table: nothing of the stats rides the loop
+    assert {v.aval.shape for v in off.outvars} == {
+        (), (g.n_pad,), (g.n_pad, k)}
 
 
 def test_prune_candidates_to_budget_semantics():
