@@ -11,8 +11,13 @@ per phase of the run:
   backend_compiles  executables - cache_loads
   seconds           summed backend_compile_duration (compile or load)
 
-``telemetry/compile_account.py`` listens to the same events but only
-while program telemetry is on, which the benchmark never turns on."""
+``kaminpar_tpu/telemetry/compile_account.py`` listens to the same events
+with program telemetry off too (since PR 34) and keeps one record an
+executable; ``layer_metrics/_setup_account.py`` reads it for
+``trace_lower_s``, ``first_request_s``, ``setup_unattributed_s`` and
+``package_import_s``.  These listeners stay the benchmark's own count:
+``compile_s`` and ``executables`` do not rest on the program's word, and
+they alone know what the WINDOW compiled (``correct`` needs that)."""
 
 from __future__ import annotations
 

@@ -6,9 +6,9 @@ of its ``GLOBAL_TIMER`` is a host event named ``SPAN_PREFIX`` + the
 scope's dotted path (``kaminpar/partitioning.uncoarsening.jet``), and one
 event ``kaminpar/request`` (stats ``k``, ``n``, ``m``) spans the whole
 ``compute_partition``.  All of them lie on the Python thread's line,
-properly nested inside the request.  The prefix is spelled here, in
-``kaminpar_tpu/utils/timer.py`` and in ``PERF.md``; the benchmark does
-not import the program.
+properly nested inside the request.  The prefix is spelled in
+``trace_reduce.py``, in ``kaminpar_tpu/utils/timer.py`` and in
+``PERF.md``; the benchmark does not import the program.
 
 The join (pinned by ``tests/data/small.xplane.pb``, a v5e trace): every
 ``XLA Modules`` event of the device plane carries a ``run_id`` stat, and
@@ -32,9 +32,8 @@ from collections import Counter
 
 from .timer_tree import REFINER_SCOPES
 from .trace_reduce import (DEVICE_PLANE, HOST_PLANE, MODULE_LINE, OP_LINE,
-                           _events, _label_gap, union)
+                           SPAN_PREFIX, _events, _label_gap, union)
 
-SPAN_PREFIX = "kaminpar/"
 REQUEST_SPAN = SPAN_PREFIX + "request"
 ENQUEUE_EVENT = "DoEnqueueProgram"
 RUN_ID = "run_id"

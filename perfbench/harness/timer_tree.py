@@ -9,9 +9,10 @@ from __future__ import annotations
 
 from statistics import median
 
-#: the scopes of the refinement layer, wherever they sit in the tree
+#: the scopes of the refinement layer, wherever they sit in the tree;
+#: ``kway-fm`` is the host k-way FM of ``strong`` (the device idles in it)
 REFINER_SCOPES = ("jet", "lp-refinement", "overload-balancer",
-                  "underload-balancer")
+                  "underload-balancer", "kway-fm")
 
 
 def snapshot(node) -> dict:

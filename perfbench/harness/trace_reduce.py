@@ -40,6 +40,10 @@ _KIND = re.compile(r"kind=(k[A-Za-z]+)")
 _SHAPE = re.compile(r"^\(?([a-z0-9]+\[[0-9,]*\])")
 _FINGERPRINT = re.compile(r"\(\d+\)$")  # jit_f(<fingerprint>) -> jit_f
 _LABEL_MIN_SHARE = 0.2
+#: every scope of the program's ``GLOBAL_TIMER`` is a host event named
+#: this + the scope's dotted path (``kaminpar_tpu/utils/timer.py``)
+SPAN_PREFIX = "kaminpar/"
+_SPAN_MIN_SHARE = 0.5
 
 
 def instruction(text: str) -> dict:
@@ -131,16 +135,29 @@ def _module_of(modules: list, instant: float) -> str:
 
 
 def _label_gap(host_events: list, start: float, end: float) -> str:
-    """The host event that covers most of the gap; among equals the
-    shortest, which is the innermost.  ``host`` where none covers a fifth."""
+    """What the host was doing in the gap.  The innermost (shortest)
+    program span that covers at least half of it, where there is one: a
+    gap runs a few milliseconds past a long host call on both sides (a
+    read-back's tail, an upload), so the enclosing span covers all of it
+    and the call 99 %, and the call is the answer.  Otherwise the host
+    event that covers most of the gap, among equals the shortest;
+    ``host`` where none covers a fifth."""
     best, best_key = "host", None
+    span, span_length = None, None
     for ev_start, ev_end, name in host_events:
         overlap = min(end, ev_end) - max(start, ev_start)
         if overlap <= 0:
             continue
-        key = (overlap, -(ev_end - ev_start))
+        length = ev_end - ev_start
+        if (name.startswith(SPAN_PREFIX)
+                and overlap >= _SPAN_MIN_SHARE * (end - start)
+                and (span is None or length < span_length)):
+            span, span_length = name, length
+        key = (overlap, -length)
         if best_key is None or key > best_key:
             best, best_key = name, key
+    if span is not None:
+        return span
     if best_key is None or best_key[0] < _LABEL_MIN_SHARE * (end - start):
         return "host"
     return best

@@ -57,8 +57,9 @@ class Request:
 def check_sample(solver, graph, csr: dict, part, k: int,
                  epsilon: float) -> dict:
     """One returned partition against the guarantees the configuration
-    states: ``{"partition", "cut", "max_block_weight", "errors"}``."""
-    errors = []
+    states: ``{"partition", "cut", "reported_cut", "max_block_weight",
+    "bound", "errors"}``."""
+    errors, reported = [], None
     if solver.last_anytime is not None:
         errors.append(f"wound down early: {solver.last_anytime}")
     part = np.asarray(part)
@@ -69,9 +70,9 @@ def check_sample(solver, graph, csr: dict, part, k: int,
         if reported != checked["cut"]:
             errors.append(f"the program reports cut {reported}, the "
                           f"benchmark counts {checked['cut']}")
-    return {"partition": part, "cut": checked["cut"],
+    return {"partition": part, "cut": checked["cut"], "reported_cut": reported,
             "max_block_weight": checked["max_block_weight"],
-            "errors": errors}
+            "bound": checked["bound"], "errors": errors}
 
 
 def serve_traced(request: Request, trace_dir: str) -> dict:

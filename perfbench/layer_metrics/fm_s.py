@@ -3,9 +3,9 @@ wherever they sit: the host k-way FM refiner of the ``strong`` preset,
 with its read-back of the level (``graph-download``), the engine
 (``fm-native`` or ``fm-numpy``) and the labels going back up
 (``partition-upload``); the device runs nothing meanwhile.  Median over
-the run's untraced partitions; 0.0 where no request called FM.  The
-accepted ``refinement_s`` does not hold these seconds
-(``harness/timer_tree.REFINER_SCOPES`` does not name ``kway-fm``)."""
+the run's untraced partitions; 0.0 where no request called FM.
+``refinement_s`` holds these seconds too (``kway-fm`` is one of
+``harness/timer_tree.REFINER_SCOPES``)."""
 
 from perfbench.harness import timer_tree
 

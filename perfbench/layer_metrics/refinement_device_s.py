@@ -1,6 +1,7 @@
 """Device seconds of the launches enqueued under a refiner span (``jet``,
-``lp-refinement``, ``overload-balancer``, ``underload-balancer``) of the
-traced request, wherever it sits (``harness/phase_reduce.py``)."""
+``lp-refinement``, ``overload-balancer``, ``underload-balancer``,
+``kway-fm``: the host FM's few microseconds of read-back and upload) of
+the traced request, wherever it sits (``harness/phase_reduce.py``)."""
 
 from perfbench.harness import phase_reduce
 

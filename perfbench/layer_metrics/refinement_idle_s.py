@@ -1,6 +1,7 @@
 """Seconds of the traced request in which the device ran nothing and the
-innermost open program span was a refiner's
-(``harness/phase_reduce.py``)."""
+innermost open program span was a refiner's or lay below one
+(``harness/phase_reduce.py``); under ``strong`` nearly all of it is the
+host FM's (``kway-fm``: ``fm_s`` and a few hundredths)."""
 
 from perfbench.harness import phase_reduce
 

@@ -1,6 +1,6 @@
 """Sum of the refiners' timer nodes (jet, lp-refinement, overload-balancer,
-underload-balancer) wherever they sit under partitioning, median over
-the run's untraced partitions."""
+underload-balancer, and kway-fm, the host FM of ``strong``) wherever they
+sit under partitioning, median over the run's untraced partitions."""
 
 from perfbench.harness import timer_tree
 

@@ -9,7 +9,9 @@ mode); generate the cell's graph on the host from ``--seed`` with the
 benchmark's own generator; one warm-up partition, which loads or
 compiles every executable the request uses (set-up ends here); the
 measured window, in which the same request is replayed back to back
-(``harness/window.py``); the checks that decide ``correct``; the result.
+(``harness/window.py``); the checks that decide ``correct``
+(``harness/validate.py``: each partition on its own, and the replays
+together as the configuration's ``guarantees.replay`` states); the result.
 
 Program telemetry stays off in both kinds of run, so ``--trace 1`` loads
 the very executables ``--trace 0`` compiled.  The traced run wraps the
@@ -18,10 +20,12 @@ with ``harness/trace_reduce.py``; the per-layer metrics are read by the
 files under ``layer_metrics/``, one each.
 
 The last line of standard output is one JSON object: ``correct``,
-``attempted``, ``failed``, ``metrics``, ``device``, and ``breakdown``
-when traced.  ``--trace 0`` reports the cell's end-to-end metrics,
-``--trace 1`` its per-layer metrics.  Any error before the window is a
-non-zero exit and no result line.
+``attempted``, ``failed``, ``metrics``, ``device``, ``breakdown`` when
+traced, and last ``compared``: each number ``correct`` compared beside
+its limit (also the last lines of standard error).  ``--trace 0``
+reports the cell's end-to-end metrics, ``--trace 1`` its per-layer
+metrics.  Any error before the window is a non-zero exit and no result
+line.
 """
 
 from __future__ import annotations
@@ -55,38 +59,25 @@ def parse_args(argv):
 
 
 def end_to_end(samples: list, setup_s: float) -> dict:
+    from perfbench.harness.validate import median_cut
+
     walls = [s["wall_s"] for s in samples if not s.get("traced")]
     return {"partition_s": median(walls) if walls else None,
-            "cut": samples[0]["cut"] if samples else None,
-            "setup_s": setup_s}
+            "cut": median_cut(samples), "setup_s": setup_s}
 
 
-def verdict(samples: list, raised, window_compile: dict) -> tuple:
-    """``(failed, reasons)``: partitions that failed, and everything
-    that makes the run incorrect."""
-    import numpy as np
+def replay_of(registry, config: dict) -> dict:
+    """The configuration's replay guarantee, or a non-zero exit where
+    its file states one the benchmark does not know."""
+    from perfbench.harness.validate import replay_guarantee
 
-    reasons, failed = [], 0
-    for i, sample in enumerate(samples):
-        if sample["errors"]:
-            failed += 1
-            reasons.extend(f"partition {i}: {e}" for e in sample["errors"])
-    if raised is not None:
-        failed += 1
-        reasons.append(f"a partition raised {raised}")
-    for i, sample in enumerate(samples[1:], 1):
-        if not np.array_equal(sample["partition"], samples[0]["partition"]):
-            differ = int((sample["partition"]
-                          != samples[0]["partition"]).sum())
-            reasons.append(f"partition {i} differs from partition 0 in "
-                           f"{differ} labels")
-    if window_compile["executables"]:
-        reasons.append(
-            f"{window_compile['executables']} executables were compiled or "
-            "loaded inside the window")
-    if not samples:
-        reasons.append("no partition ended")
-    return failed, reasons
+    cut_bound = next((m["bound"] for m in registry.manifest["end_to_end"]
+                      if m["name"] == "cut"), None)
+    try:
+        return replay_guarantee(config.get("guarantees"), cut_bound)
+    except ValueError as exc:
+        sys.exit(f"perfbench: FAIL: configuration {config.get('name')!r}: "
+                 f"{exc}")
 
 
 def traced_report(registry, workload: str, chips: int, run: dict,
@@ -138,7 +129,7 @@ def main(argv=None) -> int:
         sys.exit(f"perfbench: FAIL: no kaminpar_tpu package in {ROOT}: the "
                  "benchmark measures the program of its checkout")
 
-    from perfbench.harness import timer_tree
+    from perfbench.harness import timer_tree, validate
     from perfbench.harness.device import memory_peak_bytes, require_device
     from perfbench.harness.listeners import CompileListener
     from perfbench.harness.registry import Registry
@@ -148,6 +139,7 @@ def main(argv=None) -> int:
     cell = registry.workload(args.workload)
     config = registry.config(cell["config"])
     traffic = registry.traffic(cell["traffic"])
+    guarantee = replay_of(registry, config)
     chips = int(cell["chips"])
     device = require_device(chips)
     t_device = time.perf_counter()
@@ -201,8 +193,12 @@ def main(argv=None) -> int:
         f"median {median(walls) if walls else 0:.4f} "
         f"max {max(walls, default=0):.4f} s")
     window_compile = listener.phase_counts("window")
-    failed, reasons = verdict([warm] + samples, window["raised"],
-                              window_compile)
+    served = [warm] + samples
+    failed, reasons = validate.verdict(served, window["raised"],
+                                       window_compile, guarantee)
+    say(f"replay {guarantee['replay']}: "
+        f"{validate.distinct_partitions(served)} distinct partitions among "
+        f"the warm-up's and the window's {len(samples)}")
 
     attempted = len(samples) + (window["raised"] is not None)
     for reason in reasons:
@@ -229,7 +225,13 @@ def main(argv=None) -> int:
                "compile": {"setup": setup, "window": window_compile},
                "memory_peak_bytes": peak_bytes}
         traced_report(registry, args.workload, chips, run, result)
+    # last in the line and last on standard error: what was compared
+    result["compared"] = validate.compared(served, failed, window_compile,
+                                           guarantee)
     print(json.dumps(result), flush=True)
+    for name, (number, limit) in result["compared"].items():
+        print(f"perfbench: compared: {name} {number} limit {limit}",
+              file=sys.stderr, flush=True)
     return 0
 
 

@@ -3,9 +3,9 @@ trees), on recorded trees whose answers are known by construction: a
 ``strong`` request of a program that writes the engine and upload scopes
 under ``kway-fm``, the same request of a program that writes ``kway-fm``
 as one opaque scope (the parent of the PR that added them), and a
-``default`` request, which reads 0.  What the accepted readers make of
-``kway-fm`` today is rehearsed on a synthetic profile: they do not know
-it, and that test changes with the roll-up (run by hand)."""
+``default`` request, which reads 0.  What the layer roll-up makes of
+``kway-fm`` is rehearsed on a synthetic profile: a scope of the
+refinement layer since PR 36 (run by hand)."""
 
 from types import SimpleNamespace as NS
 
@@ -107,30 +107,39 @@ def test_a_strong_request_reads_its_fm(inner):
         assert FM + ".fm-native" in pr.render(run["phases"])
 
 
-def test_the_accepted_readers_do_not_know_kway_fm():
-    """Today: FM's idle seconds (200 + 300 us of spans less the 4 us the
-    device ran in them) are the driver's to the accepted roll-up, and its
-    host seconds are not in ``refinement_s``; with or without the scopes
-    below ``kway-fm`` they read the same.  The ``benchmark`` PR that
-    teaches ``layer_of`` and ``REFINER_SCOPES`` about ``kway-fm`` changes
-    this test with them."""
+def test_kway_fm_is_a_scope_of_the_refinement_layer():
+    """FM's idle seconds (200 + 300 us of spans less the 4 us the device
+    ran in them) are refinement's, its host seconds are in
+    ``refinement_s``, its transfer program's device seconds in
+    ``refinement_device_s``, and the driver keeps what no layer names;
+    with or without the scopes below ``kway-fm`` they read the same."""
     for name in INNER + ("fm-numpy",):
-        assert pr.layer_of(f"{FM}.{name}") == ("driver", "")
-    assert pr.layer_of(FM) == ("driver", "")
+        assert pr.layer_of(f"{FM}.{name}") == ("refinement", "kway-fm")
+    assert pr.layer_of(FM) == ("refinement", "kway-fm")
     with_scopes, opaque, default = _run(True), _run(True, False), _run(False)
     for name in ("driver_idle_s", "refinement_idle_s", "refinement_device_s",
                  "jet_device_s", "phase_attributed_share", "refinement_s",
-                 "jet_s"):
+                 "jet_s", "extend_s"):
         assert _read(name, with_scopes) == pytest.approx(_read(name, opaque))
-    # the driver's idle is FM's and that of the spans no layer names
+    # the driver's idle is that of the spans no layer names, FM or no FM
     rest = sum(row["idle_s"]
                for path, row in with_scopes["phases"]["spans"].items()
                if "kway-fm" not in path and not path.endswith(".jet"))
-    assert _read("driver_idle_s", with_scopes) == pytest.approx(
-        496 * US + rest)
+    assert _read("driver_idle_s", with_scopes) == pytest.approx(rest)
+    jet_idle = sum(row["idle_s"]
+                   for path, row in with_scopes["phases"]["spans"].items()
+                   if path.endswith(".jet"))
     assert _read("refinement_idle_s", with_scopes) == pytest.approx(
-        _read("refinement_idle_s", default))
-    assert _read("refinement_s", with_scopes) == pytest.approx(2.0)
+        496 * US + jet_idle)
+    # the two FM calls end where the driver's glue began: that glue is
+    # the only idle that differs from the request without FM
+    assert _read("refinement_idle_s", default) == pytest.approx(jet_idle)
+    assert _read("refinement_s", with_scopes) == pytest.approx(2.0 + 8.2)
+    assert _read("refinement_s", default) == pytest.approx(2.0)
+    assert _read("jet_s", with_scopes) == pytest.approx(2.0)
+    assert _read("refinement_device_s", with_scopes) == pytest.approx(
+        _read("jet_device_s", with_scopes) + 4 * US)
+    assert _read("extend_s", with_scopes) == 0.0
     assert _read("phase_attributed_share", with_scopes) == pytest.approx(100.0)
 
 

@@ -48,6 +48,8 @@ def test_configs_and_workloads_name_files_that_exist():
         with open(os.path.join(REPO, config["file"])) as f:
             body = json.load(f)
         assert body["source"] == config["source"]
+        # how a replay relates to another is stated, not left to the default
+        assert body["guarantees"]["replay"] in ("bitwise", "feasible")
         assert os.path.isfile(os.path.join(
             BENCH, "generators", body["generator"] + ".py"))
         for key in config["reduced"]:

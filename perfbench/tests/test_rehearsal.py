@@ -1,10 +1,13 @@
 """The whole command on the CPU at a tiny size, in a temporary checkout
-that ADDS a configuration, a traffic mix, a generator family and a
-per-layer metric as new files and new manifest entries only (run by hand:
-``JAX_PLATFORMS=cpu pytest perfbench/tests``; ~2 min).
+that ADDS two configurations (one states ``"replay": "feasible"``, one
+states nothing and so replays bitwise), a traffic mix, a generator
+family and a per-layer metric as new files and new manifest entries only
+(run by hand: ``JAX_PLATFORMS=cpu pytest perfbench/tests``; ~3 min).
 
 The CPU is accepted only through ``cpu_override.py``, which lives here
-and not in ``run.py``.  Nothing a run prints here is a device number."""
+and not in ``run.py``.  Nothing a run prints here is a device number.
+``fault_override.py`` breaks the timed path underneath the same command,
+and ``correct`` has to come out false."""
 
 import hashlib
 import json
@@ -77,21 +80,26 @@ def checkout(tmp_path_factory):
     bench = root / "perfbench"
     (bench / "generators" / "grid.py").write_text(GRID_GENERATOR)
     (bench / "layer_metrics" / "upload_s.py").write_text(UPLOAD_METRIC)
-    (bench / "configs" / "grid-64.json").write_text(json.dumps({
-        "name": "grid-64", "source": "test", "generator": "grid",
-        "params": {"rows": 64, "cols": 64}, "graph_seed_base": 10,
-        "preset": "default"}))
+    grid = {"name": "grid-64", "source": "test", "generator": "grid",
+            "params": {"rows": 64, "cols": 64}, "graph_seed_base": 10,
+            "preset": "default"}
+    (bench / "configs" / "grid-64.json").write_text(json.dumps(dict(
+        grid, guarantees={"replay": "feasible", "replay_cut_within": 0.04})))
+    # the same deployment stating nothing: replays have to be bitwise equal
+    (bench / "configs" / "grid-64-bitwise.json").write_text(json.dumps(dict(
+        grid, name="grid-64-bitwise")))
     (bench / "traffic" / "k4.json").write_text(json.dumps({
         "name": "k4", "k": 4, "epsilon": 0.03, "callers": 1,
         "pattern": "replay"}))
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    manifest["configs"].append({
-        "name": "grid-64", "source": "test", "reduced": [], "why": "test",
-        "file": "perfbench/configs/grid-64.json"})
-    manifest["workloads"].append({
-        "name": "grid-64.k4", "config": "grid-64", "traffic": "k4",
-        "chips": 1, "why": "test"})
+    for name in ("grid-64", "grid-64-bitwise"):
+        manifest["configs"].append({
+            "name": name, "source": "test", "reduced": [], "why": "test",
+            "file": f"perfbench/configs/{name}.json"})
+        manifest["workloads"].append({
+            "name": name + ".k4", "config": name, "traffic": "k4",
+            "chips": 1, "why": "test"})
     manifest["per_layer"].append({
         "name": "upload_s", "unit": "s", "better": "lower",
         "source": "program_span", "layer": "driver", "moves": "partition_s",
@@ -103,23 +111,32 @@ def checkout(tmp_path_factory):
     return root
 
 
-def _run(root, *args):
+def _run(root, *args, cell="grid-64.k4", fault=None, stderr=False):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    script = ([os.path.join(HERE, "cpu_override.py")] if fault is None else
+              [os.path.join(HERE, "fault_override.py"), fault, "--cpu"])
     proc = subprocess.run(
-        [sys.executable, os.path.join(HERE, "cpu_override.py"), str(root),
-         "--workload", "grid-64.k4", *args],
+        [sys.executable, *script, str(root), "--workload", cell, *args],
         capture_output=True, text=True, timeout=900, env=env, cwd=str(root))
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
-    return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stdout
+    result = json.loads(proc.stdout.strip().splitlines()[-1])
+    return result, (proc.stderr if stderr else proc.stdout)
 
 
 def test_untraced_run_reports_the_end_to_end_metrics(checkout):
     result, out = _run(checkout, "--seed", "1", "--seconds", "4",
                        "--trace", "0")
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "compared"]
     assert result["correct"] is True, out
+    # the configuration states `feasible`; the program replays bitwise
+    # all the same, and the run says so
+    assert "replay feasible: 1 distinct partitions" in out
+    assert result["compared"]["cut_off_median"] == [0.0, 0.04]
+    assert result["compared"]["window_executables"] == [0, 0]
+    heaviest, bound = result["compared"]["max_block_weight"]
+    assert 0 < heaviest <= bound
     assert result["attempted"] >= 2 and result["failed"] == 0
     assert set(result["metrics"]) == {"partition_s", "cut", "setup_s"}
     assert result["metrics"]["cut"]["value"] > 0
@@ -150,6 +167,50 @@ def test_traced_run_reports_the_new_and_the_old_layer_metrics(checkout):
     assert result["correct"] is False
     assert "the trace holds no device operation" in out
     assert result["metrics"]["extend_s"]["value"] > 0  # k=4: one doubling
+
+
+def test_what_was_compared_ends_standard_error(checkout):
+    result, err = _run(checkout, "--seed", "1", "--seconds", "2",
+                       "--trace", "0", cell="grid-64-bitwise.k4", stderr=True)
+    assert result["correct"] is True
+    assert result["compared"]["labels_differing"] == [0, 0]
+    last = err.strip().splitlines()[-len(result["compared"]):]
+    assert [line.split()[2] for line in last] == list(result["compared"])
+    assert all(line.startswith("perfbench: compared: ") for line in last)
+
+
+def test_an_answer_altered_where_it_is_produced_is_not_correct(checkout):
+    """One label of every second answer: a `bitwise` configuration is
+    broken, and the partitions as such are still sound."""
+    result, out = _run(checkout, "--seed", "1", "--seconds", "3",
+                       "--trace", "0", cell="grid-64-bitwise.k4",
+                       fault="label")
+    assert result["correct"] is False and result["failed"] == 0
+    assert "differs from partition 0 in 1 labels" in out
+    assert result["compared"]["labels_differing"] == [1, 0]
+    assert "replay bitwise: 2 distinct partitions" in out
+
+
+def test_the_same_fault_is_inside_what_a_feasible_configuration_states(
+        checkout):
+    """Not a defect of the rule: the cut moves by a few edges of ~1,000.
+    The line says that the replays were not bitwise equal."""
+    result, out = _run(checkout, "--seed", "1", "--seconds", "3",
+                       "--trace", "0", fault="label")
+    assert result["correct"] is True, out
+    assert "replay feasible: 2 distinct partitions" in out
+    off, within = result["compared"]["cut_off_median"]
+    assert 0 < off < within
+
+
+def test_an_infeasible_answer_is_not_correct_under_feasible_either(checkout):
+    result, out = _run(checkout, "--seed", "1", "--seconds", "3",
+                       "--trace", "0", fault="infeasible")
+    assert result["correct"] is False
+    assert result["failed"] == result["attempted"] >= 2
+    assert "infeasible: block weight" in out
+    heaviest, bound = result["compared"]["max_block_weight"]
+    assert heaviest > bound
 
 
 def test_another_seed_is_another_graph(checkout):

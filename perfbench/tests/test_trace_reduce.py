@@ -55,6 +55,37 @@ def test_synthetic_profile():
     assert out["idle_gaps"] == [["ExtractSubgraphs", pytest.approx(200e-6)]]
 
 
+def test_a_gap_is_named_by_the_innermost_program_span_over_half_of_it():
+    """A 2.5 ms host call with the device idle: the gap begins 5 us before
+    the call (the read-back's tail) and ends 10 us after it (the upload),
+    so the enclosing spans cover all of it and the call 99.4 %.  The call
+    names it.  A gap of which no program span covers half keeps the old
+    rule: the host event that covers most of it."""
+    up = tr.SPAN_PREFIX + "partitioning.uncoarsening"
+    ops = [_ev("fusion.1", 0, 100), _ev("fusion.2", 2600, 50),
+           _ev("fusion.3", 2850, 50), _ev("fusion.4", 3300, 10)]
+    modules = [_ev("jit_a(1)", 0, 100), _ev("jit_b(2)", 2600, 50),
+               _ev("jit_c(3)", 2850, 50), _ev("jit_d(4)", 3300, 10)]
+    device = NS(name="/device:TPU:0", lines=[
+        NS(name="XLA Modules", events=modules),
+        NS(name="XLA Ops", events=ops)])
+    host = NS(name="/host:CPU", lines=[NS(name="python", events=[
+        _ev(tr.SPAN_PREFIX + "request", 0, 3000),
+        _ev(up, 10, 2980),
+        _ev(up + ".kway-fm", 90, 2520),
+        _ev(up + ".kway-fm.graph-download", 91, 12),
+        _ev(up + ".kway-fm.fm-native", 105, 2485),
+        _ev("TransferFromDevice", 92, 10),
+        _ev("PjitFunction(f)", 3050, 250)])])
+    out = tr.reduce_profile(NS(planes=[host, device]))
+    assert out["idle_gaps"] == [
+        [up + ".kway-fm.fm-native", pytest.approx(2500e-6)],
+        # 2900..3300: the program's spans cover a quarter, jax's call 62 %
+        ["PjitFunction(f)", pytest.approx(400e-6)],
+        # 2650..2850: inside the uncoarsening span and nothing narrower
+        [up, pytest.approx(200e-6)]]
+
+
 def test_no_device_plane_reduces_to_none():
     profile = _profile()
     profile.planes = [p for p in profile.planes
