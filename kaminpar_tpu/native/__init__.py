@@ -223,6 +223,7 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         i64, p_i64, p_i32, p_i64, p_i64, i64, p_i64,
         np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS,WRITEABLE"),
         i64, i64, f64, i64, i32, ctypes.c_uint64, i64,
+        np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS,WRITEABLE"),
     ]
     lib.kmp_fm_refine_sparse.restype = i64
     lib.kmp_fm_refine_sparse.argtypes = lib.kmp_fm_refine.argtypes
@@ -447,20 +448,31 @@ def ml_bipartition_attempts(graph, max_block_weights, ip_ctx, seeds):
 
 
 # fm_refine's refusal sentinel: native FM could not run at this (n, k).
-# INT64_MIN, matching fm.cpp — NOT a small negative, which a threaded run
-# whose commit prefix was cut short by a cap race can legitimately return.
+# INT64_MIN, matching fm.cpp; every other return is the exact cut
+# improvement, never negative.
 FM_REFUSED = -(1 << 63)
+
+#: fm.cpp's stats out-array (`FmStat`), slot by slot: worker threads,
+#: passes, batches, moves committed and kept, commits the cap refused,
+#: moves a commit undid, the batches' estimated gain, the exact gain
+#: (the return value).
+FM_STATS = (
+    "threads", "passes", "batches", "committed", "cap_refusals",
+    "undone_moves", "estimated_gain", "exact_gain",
+)
 
 
 def fm_refine(graph, partition, k, max_block_weights, fm_ctx, seed: int,
-              threads: int = 1, force_sparse: bool = False):
+              threads: int = 1, force_sparse: bool = False,
+              stats: Optional[dict] = None):
     """Run the native localized batch FM on a HostGraph partition.
 
     Native counterpart of the reference's parallel localized FM scheme
     (see fm.cpp header); refines `partition` IN PLACE and returns the
     total cut improvement, or None when the native library is
-    unavailable.  `threads` > 1 runs the reference-style worker pool
-    (NodeTracker claims + atomic gain table); 1 is bitwise-deterministic.
+    unavailable.  `threads` > 1 grows each round's regions on a worker
+    pool and commits them in batch order with exact gains: the labels
+    depend on the seed, not on the thread count (>= 2) or the timing.
 
     Above the dense-table size limit the native side automatically
     switches to the sparse compact-hashing gain cache
@@ -472,7 +484,10 @@ def fm_refine(graph, partition, k, max_block_weights, fm_ctx, seed: int,
     k above the sparse engine's 16-bit packed-tag limit (0xFFFF) with the
     dense (n, k) table also unaffordable — so the caller can tell "FM
     did not run" from "FM found no improvement"; the refusal is also
-    recorded as an `fm-refused` telemetry event for the run report."""
+    recorded as an `fm-refused` telemetry event for the run report.
+
+    `stats`, a dict, receives the call's counters under `FM_STATS`'
+    names (all 0 where the engine did not run)."""
     lib = get_lib()
     if lib is None or graph.n == 0 or k <= 1:
         return None
@@ -483,6 +498,7 @@ def fm_refine(graph, partition, k, max_block_weights, fm_ctx, seed: int,
     max_bw = np.ascontiguousarray(max_block_weights, dtype=np.int64)
     assert partition.dtype == np.int32 and partition.flags.c_contiguous
     fn = lib.kmp_fm_refine_sparse if force_sparse else lib.kmp_fm_refine
+    counters = np.zeros(len(FM_STATS), dtype=np.int64)
     ret = int(
         fn(
             graph.n, xadj, adjncy, node_w, edge_w, int(k), max_bw,
@@ -492,8 +508,11 @@ def fm_refine(graph, partition, k, max_block_weights, fm_ctx, seed: int,
             1,  # adaptive stopping (the reference's default for FM)
             int(seed) & 0xFFFFFFFFFFFFFFFF,
             max(1, int(threads)),
+            counters,
         )
     )
+    if stats is not None:
+        stats.update(zip(FM_STATS, (int(v) for v in counters)))
     if ret == FM_REFUSED:
         from .. import telemetry
         from ..utils.logger import log_warning

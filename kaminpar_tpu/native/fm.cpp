@@ -3,24 +3,28 @@
 // The native analog of the reference's parallel localized FM
 // (kaminpar-shm/refinement/fm/fm_refiner.cc:48-110 FMRefiner/
 // LocalizedFMRefiner, gains/delta_gain_caches.h:202): seed nodes are
-// polled from a shared border queue, each batch grows a localized region
-// speculatively against a DELTA overlay of the partition and gain table,
-// and only the best prefix of the batch's moves is committed to the
-// global state; non-moved region nodes are released for later batches.
+// taken from the pass's shuffled border, each batch grows a localized
+// region speculatively against a DELTA overlay of the partition and gain
+// table, and only the best prefix of the batch's moves is committed to
+// the global state; non-moved region nodes are released for later
+// batches.
 //
-// Threading mirrors the reference's scheme: a pool of workers pulls seed
-// batches from the shared border queue; per-node ownership claims (the
-// NodeTracker analog, fm_refiner.cc NodeTracker) keep regions disjoint,
-// global partition/gain-table/block-weight accesses go through relaxed
-// std::atomic_ref (the reference's atomic gain cache), and commits
-// re-check the block-weight caps with fetch_add + rollback so the cap
-// is NEVER exceeded — stricter than the reference's transient
-// overshoot.  num_threads <= 1 runs the identical code on one thread
-// and visits exactly the old sequential state sequence (rerun
-// determinism for tests and 1-CPU hosts).  Stale gains from concurrent
-// commits are tolerated exactly like the reference tolerates them: the
-// delta overlay re-checks gains before applying, and the global table
-// stays exact because every update is an exact integer fetch_add.
+// One thread runs the batches one after another: each sees every earlier
+// commit, its delta is exact and its prefix always fits.
+//
+// More threads run a pass in ROUNDS of kRoundBatches batches, where the
+// reference's pool races.  The workers grow a round's regions in
+// parallel against the state the last round left and write nothing
+// shared (regions of one round may overlap); between rounds one thread
+// commits the round's batches in batch order.  A commit replays the
+// batch's prefix move by move against the global state: it stops at a
+// node an earlier batch of the round moved, or at a block the cap
+// refuses, takes each move's EXACT gain from the global table, keeps the
+// best sub-prefix (none where none gains) and undoes the rest.  So each
+// batch's kept gain is exact and >= 0, the return value is exactly
+// cut_in - cut_out >= 0, no block ever passes its cap, and the partition
+// depends on the seed alone, not on the thread count (>= 2) or on the
+// threads' timing.  The caller may pass a stats array (FmStat).
 //
 // Dense (n, k) gain table (gains/sparse_gain_cache.h lineage), delta
 // overlay of arena rows behind a flat node-indexed slot array, adaptive
@@ -28,6 +32,7 @@
 
 #include <algorithm>
 #include <atomic>
+#include <barrier>
 #include <cmath>
 #include <cstdint>
 #include <cstring>
@@ -56,6 +61,8 @@ struct Rng {
 
 constexpr auto kRelaxed = std::memory_order_relaxed;
 
+// Plain loads and stores throughout: workers only read the global state,
+// and only while no batch commits (refine_rounds).
 struct Ctx {
   int64_t n, k;
   const int64_t* xadj;
@@ -67,24 +74,44 @@ struct Ctx {
   std::vector<int64_t> conn;  // dense (n, k) connection table
   std::vector<int64_t> bw;    // global block weights
 
-  // relaxed-atomic views of the shared state (plain loads/stores when
-  // single-threaded; the values are identical either way)
-  int64_t conn_at(int64_t u, int64_t b) const {
-    return std::atomic_ref(const_cast<int64_t&>(conn[u * k + b]))
-        .load(kRelaxed);
-  }
-  int32_t part_at(int64_t u) const {
-    return std::atomic_ref(const_cast<int32_t&>(part[u])).load(kRelaxed);
-  }
-  int64_t bw_at(int64_t b) const {
-    return std::atomic_ref(const_cast<int64_t&>(bw[b])).load(kRelaxed);
-  }
+  int64_t conn_at(int64_t u, int64_t b) const { return conn[u * k + b]; }
+  int32_t part_at(int64_t u) const { return part[u]; }
+  int64_t bw_at(int64_t b) const { return bw[b]; }
 };
 
-// per-node ownership within a pass (NodeTracker analog):
-// kFree = claimable, kMoved = committed this pass, else owning batch id
+// per-node state within a pass (NodeTracker analog): kFree = claimable,
+// kMoved = committed this pass, else (one thread) the owning batch id
 constexpr int32_t kFree = -1;
 constexpr int32_t kMoved = -2;
+
+// batches a round of a pass on more than one thread
+constexpr size_t kRoundBatches = 32;
+
+// kmp_fm_refine's stats out-array, one slot each (native/__init__.py's
+// FM_STATS names them in this order)
+enum FmStat {
+  kStatThreads,        // worker threads
+  kStatPasses,         // passes run
+  kStatBatches,        // batches that grew a region
+  kStatCommitted,      // moves committed and kept
+  kStatCapRefusals,    // commits the block-weight cap refused
+  kStatUndoneMoves,    // moves a commit undid (past the best sub-prefix)
+  kStatEstimatedGain,  // the batches' delta gains for their prefixes
+  kStatExactGain,      // cut_in - cut_out, the return value
+  kNumStats
+};
+
+struct Counters {
+  int64_t batches = 0, committed = 0, cap_refusals = 0, undone = 0,
+          estimated = 0;
+};
+
+struct Params {
+  int64_t num_seeds;
+  double alpha;
+  int64_t num_fruitless;
+  int use_adaptive;
+};
 
 void build_conn(Ctx& c) {
   std::fill(c.conn.begin(), c.conn.end(), 0);
@@ -141,8 +168,7 @@ struct Delta {
     return s < 0 ? c->part_at(u) : blocks[s];
   }
   // row view: the arena row when touched, else a temp copy of the
-  // global row (atomic loads — the global row may be concurrently
-  // updated by other batches' commits)
+  // global row
   const int64_t* row_view(int64_t u, int64_t* scratch) const {
     const int32_t s = slot_of[u];
     if (s >= 0) return rows.data() + (int64_t)s * c->k;
@@ -195,29 +221,21 @@ std::pair<int64_t, int32_t> best_move(const Delta& d, int64_t u, Rng& rng,
   return {best_gain, best_t};
 }
 
-// commit a move to the GLOBAL state with a cap re-check: concurrent
-// batches may have filled the target block since the delta check, so
-// reserve the weight first and roll back on overshoot.  Returns false
-// (and leaves the state untouched) when the target no longer fits —
-// the block-weight cap is never exceeded, even transiently beyond this
-// one reservation.
-bool commit_move(Ctx& c, int64_t u, int32_t from, int32_t to) {
+bool fits(const Ctx& c, int64_t u, int32_t to) {
+  return c.bw[to] + c.node_w[u] <= c.max_bw[to];
+}
+
+// move u from -> to in the global state
+void apply_move(Ctx& c, int64_t u, int32_t from, int32_t to) {
   const int64_t w = c.node_w[u];
-  std::atomic_ref bw_to(c.bw[to]);
-  if (bw_to.fetch_add(w, kRelaxed) + w > c.max_bw[to]) {
-    bw_to.fetch_sub(w, kRelaxed);
-    return false;
-  }
-  std::atomic_ref(c.bw[from]).fetch_sub(w, kRelaxed);
-  std::atomic_ref(c.part[u]).store(to, kRelaxed);
+  c.bw[to] += w;
+  c.bw[from] -= w;
+  c.part[u] = to;
   for (int64_t e = c.xadj[u]; e < c.xadj[u + 1]; ++e) {
-    const int32_t v = c.adjncy[e];
-    std::atomic_ref(c.conn[(int64_t)v * c.k + from])
-        .fetch_sub(c.edge_w[e], kRelaxed);
-    std::atomic_ref(c.conn[(int64_t)v * c.k + to])
-        .fetch_add(c.edge_w[e], kRelaxed);
+    const int64_t v = c.adjncy[e];
+    c.conn[v * c.k + from] -= c.edge_w[e];
+    c.conn[v * c.k + to] += c.edge_w[e];
   }
-  return true;
 }
 
 // The batch's candidate moves, popped in descending (gain, tie, node,
@@ -274,35 +292,65 @@ struct Move {
   int64_t gain;
 };
 
-// one localized batch (LocalizedFMRefiner::run_batch); returns committed
-// gain.  `owner` claims keep concurrent regions disjoint.
-int64_t run_batch(Ctx& c, Delta& d, std::atomic<int32_t>* owner,
-                  int32_t my_id, const std::vector<int64_t>& seeds,
-                  double alpha, int64_t num_fruitless, int use_adaptive,
-                  Rng& rng, std::vector<int64_t>& scratch) {
-  d.clear();
-  MoveQueue pq;
-  std::vector<int64_t> touched;
+// A region on one thread: the pass's owner array, where the batch holds
+// what it claims until it ends.
+struct OwnedRegion {
+  std::vector<int32_t>& owner;
+  int32_t id;
+  std::vector<int64_t> touched;  // claimed, released by release()
 
-  auto claim = [&](int64_t u) {
-    int32_t expect = kFree;
-    return owner[u].compare_exchange_strong(expect, my_id, kRelaxed);
-  };
+  bool mine(int64_t u) const { return owner[u] == id; }
+  // u is in the region afterwards (claimed now or before)
+  bool join(int64_t u) {
+    if (owner[u] == id) return true;
+    if (owner[u] != kFree) return false;
+    owner[u] = id;
+    touched.push_back(u);
+    return true;
+  }
+  void release() {
+    for (const int64_t u : touched)
+      if (owner[u] == id) owner[u] = kFree;
+  }
+};
+
+// A region of a round: the worker's own marks (stamp = the batch), so
+// regions of one round may overlap; a node moved in an earlier round of
+// the pass is taken.
+struct StampedRegion {
+  std::vector<int32_t>& mark;
+  const std::vector<int32_t>& owner;
+  int32_t stamp;
+
+  bool mine(int64_t u) const { return mark[u] == stamp; }
+  bool join(int64_t u) {
+    if (mark[u] == stamp) return true;
+    if (owner[u] == kMoved) return false;
+    mark[u] = stamp;
+    return true;
+  }
+};
+
+// grow one localized region from its seeds against the delta
+// (LocalizedFMRefiner::run_batch): its tentative moves into `moves`, and
+// the length of their best prefix returned
+template <class Region>
+size_t search(const Ctx& c, Delta& d, Region& region,
+              const std::vector<int64_t>& seeds, const Params& p, Rng& rng,
+              int64_t* scratch, std::vector<Move>& moves) {
+  d.clear();
+  moves.clear();
+  MoveQueue pq;
   auto push = [&](int64_t u) {
-    auto [g, t] = best_move(d, u, rng, scratch.data());
+    auto [g, t] = best_move(d, u, rng, scratch);
     if (t >= 0) pq.push({g, rng.tie(), u, t});
   };
   for (int64_t s : seeds) {
-    // seeds arrive pre-claimed by the seed poller
-    touched.push_back(s);
+    region.join(s);
     push(s);
   }
-  if (pq.empty()) {
-    for (int64_t u : touched) owner[u].store(kFree, kRelaxed);
-    return 0;
-  }
+  if (pq.empty()) return 0;
 
-  std::vector<Move> moves;
   int64_t cur = 0, best = 0;
   size_t best_len = 0;
   int64_t fruitless = 0;
@@ -312,11 +360,11 @@ int64_t run_batch(Ctx& c, Delta& d, std::atomic<int32_t>* owner,
 
   while (!pq.empty() && moves.size() < max_moves) {
     auto [g, tie, u, t] = pq.pop();
-    if (owner[u].load(kRelaxed) != my_id) continue;  // lost to a commit
+    if (!region.mine(u)) continue;
     // stale check: gains shift as the region moves.  Re-queue only on a
     // GAIN change — the target may legitimately differ on ties (random
     // tie-break per query), and re-queuing on target alone could cycle
-    auto [g2, t2] = best_move(d, u, rng, scratch.data());
+    auto [g2, t2] = best_move(d, u, rng, scratch);
     if (t2 < 0) continue;
     if (g2 != g) {
       pq.push({g2, rng.tie(), u, t2});
@@ -331,21 +379,13 @@ int64_t run_batch(Ctx& c, Delta& d, std::atomic<int32_t>* owner,
       best = cur;
       best_len = moves.size();
     }
-    // expand: adjacent unclaimed nodes join the region
+    // expand: adjacent free nodes join the region
     for (int64_t e = c.xadj[u]; e < c.xadj[u + 1]; ++e) {
       const int32_t v = c.adjncy[e];
-      const int32_t o = owner[v].load(kRelaxed);
-      if (o == kFree) {
-        if (claim(v)) {
-          touched.push_back(v);
-          push(v);
-        }
-      } else if (o == my_id) {
-        push(v);
-      }
+      if (region.join(v)) push(v);
     }
     // stopping policies (stopping_policies.h:16)
-    if (use_adaptive) {
+    if (p.use_adaptive) {
       ++steps;
       const double dlt = (double)g - mean;
       mean += dlt / (double)steps;
@@ -353,28 +393,186 @@ int64_t run_batch(Ctx& c, Delta& d, std::atomic<int32_t>* owner,
       if (steps >= 2) {
         const double variance = m2 / (double)(steps - 1);
         if (mean < 0 &&
-            (double)steps * mean * mean > alpha * variance + 10.0)
+            (double)steps * mean * mean > p.alpha * variance + 10.0)
           break;
       }
     } else {
       fruitless = (g > 0) ? 0 : fruitless + 1;
-      if (fruitless >= num_fruitless) break;
+      if (fruitless >= p.num_fruitless) break;
     }
   }
+  return best_len;
+}
 
-  // commit the best prefix globally; release the rest.  A cap re-check
-  // failure aborts the remainder of the prefix (the delta gains beyond
-  // a skipped move are no longer meaningful).
-  int64_t committed_gain = 0;
-  size_t i = 0;
-  for (; i < best_len; ++i) {
-    if (!commit_move(c, moves[i].u, moves[i].from, moves[i].to)) break;
-    owner[moves[i].u].store(kMoved, kRelaxed);
-    committed_gain += moves[i].gain;
+// one thread: batch after batch, each committing its best prefix at once
+int64_t refine_sequential(Ctx& c, const Params& p,
+                          const std::vector<int64_t>& border,
+                          std::vector<int32_t>& owner, Rng& rng,
+                          Counters& cnt) {
+  Delta d(c);
+  std::vector<int64_t> scratch(c.k), seeds;
+  std::vector<Move> moves;
+  int64_t gain = 0;
+  int32_t id = 0;
+  size_t head = 0;
+  for (;;) {
+    seeds.clear();
+    while ((int64_t)seeds.size() < p.num_seeds && head < border.size()) {
+      const int64_t u = border[head++];
+      if (owner[u] == kFree) seeds.push_back(u);
+    }
+    if (seeds.empty()) return gain;
+    ++cnt.batches;
+    OwnedRegion region{owner, ++id, {}};
+    const size_t best_len =
+        search(c, d, region, seeds, p, rng, scratch.data(), moves);
+    // the delta is exact: the prefix fits and its gains are the cut's
+    for (size_t i = 0; i < best_len; ++i) {
+      const Move& m = moves[i];
+      apply_move(c, m.u, m.from, m.to);
+      owner[m.u] = kMoved;
+      gain += m.gain;
+    }
+    cnt.committed += (int64_t)best_len;
+    region.release();
   }
-  for (int64_t u : touched)
-    if (owner[u].load(kRelaxed) == my_id) owner[u].store(kFree, kRelaxed);
-  return committed_gain;
+}
+
+struct Batch {
+  std::vector<int64_t> seeds;
+  std::vector<Move> moves;
+  size_t best_len = 0;
+};
+
+// commit one batch of a round to the global state (one thread, in batch
+// order): the prefix up to a node an earlier batch moved or a move the
+// cap refuses, with exact gains, cut back to its best sub-prefix
+int64_t commit_batch(Ctx& c, std::vector<int32_t>& owner, const Batch& b,
+                     Counters& cnt) {
+  int64_t run = 0, best = 0;
+  size_t keep = 0, i = 0;
+  for (; i < b.best_len; ++i) {
+    const Move& m = b.moves[i];
+    if (owner[m.u] == kMoved || c.part[m.u] != m.from) break;
+    if (!fits(c, m.u, m.to)) {
+      ++cnt.cap_refusals;
+      break;
+    }
+    run += c.conn_at(m.u, m.to) - c.conn_at(m.u, m.from);
+    apply_move(c, m.u, m.from, m.to);
+    if (run > best) {
+      best = run;
+      keep = i + 1;
+    }
+    cnt.estimated += m.gain;
+  }
+  for (size_t j = i; j-- > keep;)
+    apply_move(c, b.moves[j].u, b.moves[j].to, b.moves[j].from);
+  for (size_t j = 0; j < keep; ++j) owner[b.moves[j].u] = kMoved;
+  cnt.committed += (int64_t)keep;
+  cnt.undone += (int64_t)(i - keep);
+  return best;
+}
+
+// T threads: the pass in rounds (the header)
+int64_t refine_rounds(Ctx& c, const Params& p,
+                      const std::vector<int64_t>& border,
+                      std::vector<int32_t>& owner, int64_t T, uint64_t seed,
+                      int64_t pass, Counters& cnt) {
+  std::vector<Batch> round(kRoundBatches);
+  size_t size = 0, head = 0;
+  uint64_t first = 0;  // the pass's index of round[0]
+  std::atomic<size_t> next{0};
+  int64_t gain = 0;
+
+  // the next round's batches: seeds in border order, skipping moved ones
+  auto form = [&] {
+    first += size;
+    size = 0;
+    while (size < round.size() && head < border.size()) {
+      Batch& b = round[size];
+      b.seeds.clear();
+      while ((int64_t)b.seeds.size() < p.num_seeds && head < border.size()) {
+        const int64_t u = border[head++];
+        if (owner[u] != kMoved) b.seeds.push_back(u);
+      }
+      if (!b.seeds.empty()) ++size;
+    }
+    next.store(0, kRelaxed);
+  };
+  auto commit = [&]() noexcept {
+    for (size_t i = 0; i < size; ++i)
+      gain += commit_batch(c, owner, round[i], cnt);
+    cnt.batches += (int64_t)size;
+    form();
+  };
+  form();
+  std::barrier<decltype(commit)> sync((std::ptrdiff_t)T, commit);
+
+  auto worker = [&] {
+    Delta d(c);
+    std::vector<int32_t> mark(c.n, 0);
+    std::vector<int64_t> scratch(c.k);
+    int32_t stamp = 0;
+    // `size` changes only in `commit`, which every worker waits for
+    while (size > 0) {
+      for (size_t i; (i = next.fetch_add(1, kRelaxed)) < size;) {
+        Batch& b = round[i];
+        StampedRegion region{mark, owner, ++stamp};
+        // the batch's own stream: the same whichever worker runs it
+        Rng rng(seed ^ (0x9E3779B97F4A7C15ULL * (uint64_t)(pass + 1)) ^
+                (0xD1B54A32D192ED03ULL * (first + i + 1)));
+        b.best_len =
+            search(c, d, region, b.seeds, p, rng, scratch.data(), b.moves);
+      }
+      sync.arrive_and_wait();
+    }
+  };
+  std::vector<std::thread> pool;
+  pool.reserve(T);
+  for (int64_t t = 0; t < T; ++t) pool.emplace_back(worker);
+  for (auto& th : pool) th.join();
+  return gain;
+}
+
+// the dense engine's passes; returns cut_in - cut_out
+int64_t refine_dense(Ctx& c, const Params& p, int64_t num_iterations,
+                     int64_t T, uint64_t seed, Counters& cnt,
+                     int64_t& passes) {
+  Rng rng(seed);
+  std::vector<int32_t> owner(c.n);
+  std::vector<int64_t> border;
+  int64_t total = 0;
+  int64_t first_pass_gain = 0;
+  for (int64_t pass = 0; pass < std::max<int64_t>(1, num_iterations);
+       ++pass) {
+    // border nodes: nonzero external connection
+    border.clear();
+    for (int64_t u = 0; u < c.n; ++u) {
+      const int64_t own = c.conn_at(u, c.part[u]);
+      int64_t deg_w = 0;
+      for (int64_t b = 0; b < c.k; ++b) deg_w += c.conn_at(u, b);
+      if (deg_w > own) border.push_back(u);
+    }
+    if (border.empty()) break;
+    for (int64_t i = (int64_t)border.size() - 1; i > 0; --i)
+      std::swap(border[i], border[(int64_t)(rng.next() % (uint64_t)(i + 1))]);
+
+    std::fill(owner.begin(), owner.end(), kFree);
+    const int64_t pg =
+        T == 1 ? refine_sequential(c, p, border, owner, rng, cnt)
+               : refine_rounds(c, p, border, owner, T, seed, pass, cnt);
+    ++passes;
+    total += pg;
+    if (pg <= 0) break;
+    // improvement abortion (initial_fm_refiner improvement_abortion
+    // lineage): later passes chase diminishing returns at full pass cost
+    if (pass == 0)
+      first_pass_gain = pg;
+    else if (pg * 20 < first_pass_gain)
+      break;
+  }
+  return total;
 }
 
 // ---------------------------------------------------------------------------
@@ -650,9 +848,9 @@ struct SparseDelta {
   }
 };
 
-// commit with cap re-check (mirrors dense commit_move); a saturated
-// neighbor row is rebuilt exactly (single-threaded path — the sparse
-// configuration runs T=1, see kmp_fm_refine)
+// commit with cap re-check; a saturated neighbor row is rebuilt exactly
+// (single-threaded path — the sparse configuration runs T=1, see
+// kmp_fm_refine)
 bool commit_move(SparseCtx& c, int64_t u, int32_t from, int32_t to) {
   const int64_t w = c.node_w[u];
   std::atomic_ref bw_to(c.bw[to]);
@@ -768,12 +966,11 @@ int64_t refine(int64_t n, const int64_t* xadj, const int32_t* adjncy,
                const int64_t* max_bw, int32_t* part,
                int64_t num_iterations, int64_t num_seed_nodes,
                double alpha, int64_t num_fruitless_moves,
-               int32_t use_adaptive, uint64_t seed) {
+               int32_t use_adaptive, uint64_t seed, int64_t* stats) {
   // the packed tag field holds block+1 in 16 bits (max tag = k).
   // INT64_MIN is the REFUSAL sentinel — the caller must distinguish "FM
   // did not run" from "FM found no improvement" (ADVICE round 5 low #3),
-  // and a small negative value would be ambiguous: with threads > 1 a
-  // cap-race-aborted commit prefix can legitimately sum negative.
+  // and a small negative value would be ambiguous.
   if (k > 0xFFFF) return INT64_MIN;
   SparseCtx c{n, k, xadj, adjncy, node_w, edge_w, max_bw, part,
               {}, {}, {}, {}};
@@ -818,11 +1015,17 @@ int64_t refine(int64_t n, const int64_t* xadj, const int32_t* adjncy,
     }
 
     total += pass_gain;
+    if (stats) ++stats[kStatPasses];
     if (pass_gain <= 0) break;
     if (pass == 0)
       first_pass_gain = pass_gain;
     else if (pass_gain * 20 < first_pass_gain)
       break;
+  }
+  if (stats) {
+    // one thread: the delta is exact, the estimate is the cut's change
+    stats[kStatThreads] = 1;
+    stats[kStatEstimatedGain] = stats[kStatExactGain] = total;
   }
   return total;
 }
@@ -839,11 +1042,13 @@ extern "C" int64_t kmp_fm_refine_sparse(
     const int64_t* node_w, const int64_t* edge_w, int64_t k,
     const int64_t* max_bw, int32_t* part, int64_t num_iterations,
     int64_t num_seed_nodes, double alpha, int64_t num_fruitless_moves,
-    int32_t use_adaptive, uint64_t seed, int64_t /*num_threads*/) {
+    int32_t use_adaptive, uint64_t seed, int64_t /*num_threads*/,
+    int64_t* stats) {
+  if (stats) std::fill(stats, stats + kNumStats, 0);
   if (n <= 0 || k <= 1) return 0;
   return sparse_fm::refine(n, xadj, adjncy, node_w, edge_w, k, max_bw,
                            part, num_iterations, num_seed_nodes, alpha,
-                           num_fruitless_moves, use_adaptive, seed);
+                           num_fruitless_moves, use_adaptive, seed, stats);
 }
 
 extern "C" int64_t kmp_fm_refine(
@@ -851,7 +1056,9 @@ extern "C" int64_t kmp_fm_refine(
     const int64_t* node_w, const int64_t* edge_w, int64_t k,
     const int64_t* max_bw, int32_t* part, int64_t num_iterations,
     int64_t num_seed_nodes, double alpha, int64_t num_fruitless_moves,
-    int32_t use_adaptive, uint64_t seed, int64_t num_threads) {
+    int32_t use_adaptive, uint64_t seed, int64_t num_threads,
+    int64_t* stats) {
+  if (stats) std::fill(stats, stats + kNumStats, 0);
   if (n <= 0 || k <= 1) return 0;
   if (n * k > (int64_t)3e8) {
     // large k: the dense (n, k) table is unaffordable — run the sparse
@@ -860,89 +1067,30 @@ extern "C" int64_t kmp_fm_refine(
     // is not written for concurrent writers.
     return sparse_fm::refine(n, xadj, adjncy, node_w, edge_w, k, max_bw,
                              part, num_iterations, num_seed_nodes, alpha,
-                             num_fruitless_moves, use_adaptive, seed);
+                             num_fruitless_moves, use_adaptive, seed, stats);
   }
   Ctx c{n, k, xadj, adjncy, node_w, edge_w, max_bw, part, {}, {}};
   c.conn.resize(n * k);
   c.bw.resize(k);
-  Rng rng(seed);
   build_conn(c);
 
   const int64_t T = std::max<int64_t>(1, num_threads);
-  std::unique_ptr<std::atomic<int32_t>[]> owner(
-      new std::atomic<int32_t>[n]);
-
-  int64_t total = 0;
-  int64_t first_pass_gain = 0;
-  std::vector<int64_t> border;
-  for (int64_t pass = 0; pass < std::max<int64_t>(1, num_iterations);
-       ++pass) {
-    // border nodes: nonzero external connection
-    border.clear();
-    for (int64_t u = 0; u < n; ++u) {
-      const int64_t own = c.conn_at(u, c.part[u]);
-      int64_t deg_w = 0;
-      for (int64_t b = 0; b < k; ++b) deg_w += c.conn_at(u, b);
-      if (deg_w > own) border.push_back(u);
-    }
-    if (border.empty()) break;
-    for (int64_t i = (int64_t)border.size() - 1; i > 0; --i)
-      std::swap(border[i], border[(int64_t)(rng.next() % (uint64_t)(i + 1))]);
-
-    for (int64_t u = 0; u < n; ++u) owner[u].store(kFree, kRelaxed);
-    const int64_t nseeds = std::max<int64_t>(1, num_seed_nodes);
-    std::atomic<size_t> head{0};
-    std::atomic<int64_t> pass_gain{0};
-    std::atomic<int32_t> next_batch_id{0};
-
-    auto worker = [&](int64_t tid) {
-      Delta d(c);
-      Rng wrng(seed ^ (0x9E3779B9ULL * (uint64_t)(pass * T + tid + 1)));
-      // thread 0 on a single-thread run reuses the pass RNG so the
-      // sequential state sequence matches the pre-threading code
-      Rng& r = (T == 1) ? rng : wrng;
-      std::vector<int64_t> scratch(k);
-      std::vector<int64_t> seeds;
-      for (;;) {
-        // allocate the batch id FIRST so seed claims are uniquely
-        // tagged from the start (a provisional shared tag could make a
-        // foreign region adopt the seed)
-        const int32_t my_id = next_batch_id.fetch_add(1, kRelaxed) + 1;
-        seeds.clear();
-        while ((int64_t)seeds.size() < nseeds) {
-          const size_t i = head.fetch_add(1, kRelaxed);
-          if (i >= border.size()) break;
-          const int64_t u = border[i];
-          int32_t expect = kFree;
-          if (owner[u].compare_exchange_strong(expect, my_id, kRelaxed))
-            seeds.push_back(u);
-        }
-        if (seeds.empty()) break;
-        pass_gain.fetch_add(
-            run_batch(c, d, owner.get(), my_id, seeds, alpha,
-                      num_fruitless_moves, use_adaptive, r, scratch),
-            kRelaxed);
-      }
-    };
-
-    if (T == 1) {
-      worker(0);
-    } else {
-      std::vector<std::thread> pool;
-      pool.reserve(T);
-      for (int64_t t = 0; t < T; ++t) pool.emplace_back(worker, t);
-      for (auto& th : pool) th.join();
-    }
-
-    const int64_t pg = pass_gain.load(kRelaxed);
-    total += pg;
-    if (pg <= 0) break;
-    // improvement abortion (initial_fm_refiner improvement_abortion
-    // lineage): later passes chase diminishing returns at full pass cost
-    if (pass == 0)
-      first_pass_gain = pg;
-    else if (pg * 20 < first_pass_gain)
-      break;
+  const Params p{std::max<int64_t>(1, num_seed_nodes), alpha,
+                 num_fruitless_moves, use_adaptive};
+  Counters cnt;
+  int64_t passes = 0;
+  const int64_t total =
+      refine_dense(c, p, num_iterations, T, seed, cnt, passes);
+  if (stats) {
+    stats[kStatThreads] = T;
+    stats[kStatPasses] = passes;
+    stats[kStatBatches] = cnt.batches;
+    stats[kStatCommitted] = cnt.committed;
+    stats[kStatCapRefusals] = cnt.cap_refusals;
+    stats[kStatUndoneMoves] = cnt.undone;
+    // one thread: the delta is exact, the estimate is the cut's change
+    stats[kStatEstimatedGain] = T == 1 ? total : cnt.estimated;
+    stats[kStatExactGain] = total;
   }
   return total;
 }

@@ -9,6 +9,7 @@ equivalents are the bulk-sync LP knobs on LabelPropagationContext.
 
 from __future__ import annotations
 
+import os
 from typing import Dict, Set
 
 from .context import (
@@ -89,6 +90,32 @@ def create_strong_context() -> Context:
     # intermediate extensions get single-round Jet and skip FM; the
     # final extension's refine at each level is the real polish
     ctx.partitioning.light_intermediate_refinement = True
+    return ctx
+
+
+def host_worker_count() -> int:
+    """The host cores this process may run on, less one for the thread
+    that dispatches to the device; at least 1."""
+    try:
+        cores = len(os.sched_getaffinity(0))
+    except AttributeError:  # no affinity mask on this platform
+        cores = os.cpu_count() or 1
+    return max(1, cores - 1)
+
+
+def create_strong_parallel_context() -> Context:
+    """`strong` on every host core, as the reference runs `-P strong -t
+    <cores>`: the host k-way FM's worker pool (native/fm.cpp) at every
+    level and k, and the host extend's bipartition pool
+    (partitioning/deep.py), get `host_worker_count()` threads.  Neither
+    pool's answer depends on its size or timing, so a replay returns the
+    same partition; it is another partition than `strong`'s (FM grows a
+    round's regions against one state, the extend draws per-block
+    seeds).  Two callers: the benchmark's `delaunay-n17-strong-parallel`
+    configuration, and any user who passes the name."""
+    ctx = create_strong_context()
+    ctx.preset_name = "strong-parallel"
+    ctx.parallel.num_workers = host_worker_count()
     return ctx
 
 
@@ -274,6 +301,7 @@ _PRESETS = {
     "default": create_default_context,
     "fast": create_fast_context,
     "strong": create_strong_context,
+    "strong-parallel": create_strong_parallel_context,
     "fm": create_strong_context,
     "largek": create_largek_context,
     "largek-fast": create_largek_fast_context,

@@ -12,8 +12,10 @@ engines:
 * **native** (native/fm.cpp, the engine a build with a toolchain runs):
   the reference's *localized batch* FM — regions grown from
   `num_seed_nodes` border seeds against a delta gain overlay, each
-  region's best prefix committed — on `threads` workers (1 in every
-  preset: one thread replays bitwise).
+  region's best prefix committed — on `threads` workers
+  (`ctx.parallel.num_workers`: 1 in every preset but `strong-parallel`;
+  more grow a round's regions in parallel and commit them in order with
+  exact gains, so the labels do not depend on the thread count).
 * **numpy** (`_fm_pass` below; the fallback twin where the library is
   unavailable, and `KAMINPAR_TPU_NO_NATIVE_FM=1`): the reference's
   *sequential* FM structure with a global gain PQ over border nodes,
@@ -30,11 +32,18 @@ or `fm-numpy`, named by the engine that ran (both where the native call
 gave up and the twin took over; a native refusal returns from
 `fm-native` at once, runs no twin and leaves the partition unchanged),
 then `partition-upload` (the padded labels going back to the device).
+
+`fm_account` keeps two of the native engine's counters (`ACCOUNTED`)
+for the life of the process, with telemetry off, summed per request
+ordinal as `telemetry/compile_account` numbers the requests: what the
+benchmark's `fm_cap_refusals` and `fm_undone_moves` read.
 """
 
 from __future__ import annotations
 
 import heapq
+import threading
+from collections import OrderedDict
 from typing import Optional
 
 import numpy as np
@@ -42,9 +51,48 @@ import numpy as np
 from ..context import FMRefinementContext
 from ..graphs.csr import DeviceGraph, host_graph_from_device
 from ..graphs.host import HostGraph
+from ..telemetry import compile_account
 from ..telemetry import progress as progress_mod
 from ..utils.timer import scoped_timer
 from .gains import create_host_gain_cache
+
+_KEEP_REQUESTS = 64  # the last so many requests' sums
+
+#: the native counters the account keeps: what the benchmark's
+#: `fm_cap_refusals` and `fm_undone_moves` read
+ACCOUNTED = ("cap_refusals", "undone_moves")
+
+
+class FMAccount:
+    """The native FM calls' `ACCOUNTED` counters, summed per request
+    ordinal (0 outside any request)."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._by_request: "OrderedDict[int, dict]" = OrderedDict()
+
+    def record(self, stats: dict) -> None:
+        ordinal = compile_account.open_request()
+        with self._lock:
+            entry = self._by_request.get(ordinal)
+            if entry is None:
+                entry = self._by_request[ordinal] = dict.fromkeys(
+                    ACCOUNTED, 0)
+                while len(self._by_request) > _KEEP_REQUESTS:
+                    self._by_request.popitem(last=False)
+            for name in ACCOUNTED:
+                entry[name] += stats.get(name, 0)
+
+    def summary(self) -> dict:
+        """`{"requests": requests begun, "by_request": {ordinal: {...}}}`;
+        a request without an FM call has no entry."""
+        with self._lock:
+            return {"requests": compile_account.requests_begun(),
+                    "by_request": {k: dict(v)
+                                   for k, v in self._by_request.items()}}
+
+
+fm_account = FMAccount()
 
 
 def fm_refine_host(
@@ -117,13 +165,17 @@ def fm_refine_host(
 
             t0 = progress_mod.now()
             # native localized BATCH FM (fm.cpp — the reference's
-            # parallel localized scheme minus threads: seeded regions
-            # grown against a delta gain overlay, best prefixes
-            # committed); refines `part` in place
+            # parallel localized scheme: seeded regions grown against a
+            # delta gain overlay, best prefixes committed, on `threads`
+            # workers); refines `part` in place
+            stats = {}
             with scoped_timer("fm-native"):
                 improvement = native.fm_refine(
-                    graph, part, k, max_bw, ctx, seed, threads=threads
+                    graph, part, k, max_bw, ctx, seed, threads=threads,
+                    stats=stats,
                 )
+            if stats:
+                fm_account.record(stats)
             if improvement is None:
                 raise NativeUnavailable(
                     "native FM library unavailable (build failed or "

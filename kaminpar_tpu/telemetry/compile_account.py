@@ -330,6 +330,19 @@ def request():
                     _requests["last"].append(entry)
 
 
+def open_request() -> int:
+    """The ordinal of the request open now, 0 outside any (the tag other
+    process-level accounts give what they record)."""
+    with _lock:
+        return _requests["ordinal"] if _requests["depth"] else 0
+
+
+def requests_begun() -> int:
+    """How many requests the process has entered (the last ordinal)."""
+    with _lock:
+        return _requests["ordinal"]
+
+
 def snapshot() -> dict:
     """The run report's `compile` section."""
     with _lock:

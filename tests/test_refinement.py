@@ -623,7 +623,8 @@ def _fm_replay_case(name):
 #: (graph, k, seed) -> (sha1 of the refined int32 labels, returned gain),
 #: taken from the engine of PR 32 (`Delta` over an `unordered_map`) before
 #: PR 33 touched fm.cpp: whatever the engine's bookkeeping becomes, one
-#: thread returns these bytes.
+#: thread returns these bytes.  Seed 3 is PR 36's engine, before PR 38's
+#: guards for more threads.
 FM_GOLDEN = {
     ("delaunay-2000", 2, 1): ("ca8372f102bee2efaafb2c048303137eddb0f0a9", 19),
     ("delaunay-2000", 2, 7): ("a4f947bebfdfe488c51a795d2d0117c7add8d0f4", 19),
@@ -645,6 +646,15 @@ FM_GOLDEN = {
     ("grid-40x40", 4, 7): ("797ff19506c3755bf1ff7ba5834afd869f737cb8", 14666744),
     ("grid-40x40", 16, 1): ("cd2c9ab05bc879deedfec3c25825b75b72bc899a", 84136595),
     ("grid-40x40", 16, 7): ("06a11524498b2e8cd99a47d881c8626cc098cfa5", 65955219),
+    ("delaunay-2000", 2, 3): ("bc38c61cc2415b75523ba322a96729665cf07e16", 19),
+    ("delaunay-2000", 4, 3): ("1bbee5305ae73879aad66e36063479ea6ca6137f", 27),
+    ("delaunay-2000", 16, 3): ("3608aa9b64a2a40525b681096f58a1271b071be9", 107),
+    ("rmat-1024", 2, 3): ("d599f44b30f64080c2881ead70479d78a32296f4", 1917),
+    ("rmat-1024", 4, 3): ("e630e02937dfee9a8a3904ce688b84a47f0bb973", 2062),
+    ("rmat-1024", 16, 3): ("cb10e2225160f43fcc02d11fbf7799afa77ce5c4", 1447),
+    ("grid-40x40", 2, 3): ("e7cc219caf6a3499ec2e50c150efa87de3bbb758", 881680),
+    ("grid-40x40", 4, 3): ("6dc90959cfb7a2f80a4ed287802ee11bb0c2fe96", 13259944),
+    ("grid-40x40", 16, 3): ("c04aa6fc9cd4c9e7c38ea4f4bc08f0fcf8989caa", 88665830),
 }
 
 
