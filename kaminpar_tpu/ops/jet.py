@@ -52,16 +52,18 @@ from .segments import (
     prune_candidates_to_budget,
 )
 
-# From this many edge slots on, Jet PRUNES its candidates to a row buffer
-# of m_pad // 4 slots (prune_candidates_to_budget: a different move set,
-# a different cut), so its afterburner always runs over that buffer
-# (_rows_filter), and takes the smaller coarse iteration budget (mirrors
-# ops/lp.DELTA_MIN_EDGE_SLOTS).  Under it nothing is pruned: an
-# iteration runs the same row afterburner through CONN_DELTA_DIVISOR's
-# buffer when its candidates' rows fit it, and the edge-wide one
-# (_edges_filter) when they do not; the partition is the same either
-# way.  A reconcile through _conn_step does not ask the gate: that takes
-# CONN_DELTA_DIVISOR's buffer at every size.
+# From this many edge slots on, Jet never leaves its row afterburner
+# (_rows_filter) and takes the smaller coarse iteration budget (mirrors
+# ops/lp.DELTA_MIN_EDGE_SLOTS): an iteration whose candidates' rows fit
+# CONN_DELTA_DIVISOR's buffer runs through it, one whose rows overflow
+# it PRUNES its candidates to a buffer of m_pad // 4 slots
+# (prune_candidates_to_budget: a different move set, a different cut,
+# where the prune drops any) and runs through that.  Under the gate
+# nothing is pruned: an iteration runs the same row afterburner through
+# CONN_DELTA_DIVISOR's buffer when its candidates' rows fit it, and the
+# edge-wide one (_edges_filter) when they do not; the partition is the
+# same either way.  A reconcile through _conn_step does not ask the
+# gate: that takes CONN_DELTA_DIVISOR's buffer at every size.
 DELTA_MIN_EDGE_SLOTS = 1 << 22
 
 # Under the gate an iteration runs its afterburner over the candidates'
@@ -88,11 +90,13 @@ def _delta_slots(graph: DeviceGraph) -> int | None:
 
 def iteration_path(graph: DeviceGraph, k: int) -> str:
     """Which iteration `jet_refine` runs on `graph` at `k`, from the shapes
-    alone: `jet-rows` (candidates pruned to a row buffer, the afterburner
-    always over that buffer), `jet-edges` (nothing pruned; the afterburner
-    over the candidates' rows in the iterations where they fit
-    `_conn_slots`, edge-wide in the others: the `rows` column of the
-    progress series says which, the name follows the shapes) or `jet-lp`
+    alone: `jet-rows` (the afterburner always over the candidates' rows:
+    through `_conn_slots` in the iterations where they fit it, pruned to
+    and through `_delta_slots` in the others: the `wide` column of the
+    progress series says which), `jet-edges` (nothing pruned; the
+    afterburner over the candidates' rows in the iterations where they
+    fit `_conn_slots`, edge-wide in the others: the `rows` column says
+    which; the name follows the shapes) or `jet-lp`
     (no dense table: LP refinement rounds).  The refiner names a timer
     scope after it, so a trace of a run with telemetry off still says
     which Jet a level ran."""
@@ -103,7 +107,7 @@ def iteration_path(graph: DeviceGraph, k: int) -> str:
 
 def _conn_slots(graph: DeviceGraph) -> int:
     """Row-buffer width of a _conn_step reconcile, whatever the path, and
-    of the row afterburner under the gate."""
+    of the row afterburner wherever the candidates' rows fit it."""
     return graph.src.shape[0] // CONN_DELTA_DIVISOR
 
 
@@ -304,6 +308,57 @@ def _edges_filter(
     )
 
 
+def _candidate_slots(graph: DeviceGraph, candidate: jax.Array) -> jax.Array:
+    """Edge slots the candidates' CSR rows take (an n-wide reduce)."""
+    # degree total <= m_pad < 2^31 (device layout)
+    # tpulint: disable=R3
+    return jnp.sum(jnp.where(candidate, graph.degrees, 0), dtype=jnp.int32)
+
+
+def _gated_rows_filter(
+    graph: DeviceGraph,
+    conn: jax.Array,
+    part: jax.Array,
+    best: jax.Array,
+    gain: jax.Array,
+    candidate: jax.Array,
+    k: int,
+    salt: jax.Array,
+    dslots: int,
+    conn_slots: int,
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The row afterburner past the gate: through `conn_slots` when the
+    candidates' degrees sum to at most that, else through `dslots` after
+    pruning the candidates to the best-gain subset that fits it.  The
+    prune keeps a set that fits as it is, and its salt is derived, not
+    drawn: both branches give the same bits where both hold the rows.
+    Returns (accept, conn, pruned, wide) with wide 1 where the
+    candidates were pruned to and filtered through `dslots`."""
+
+    def narrow(conn, candidate):
+        next_part = jnp.where(candidate, best, part)
+        accept, jet_conn = _rows_filter(
+            graph, conn, part, next_part, gain, candidate, k, conn_slots
+        )
+        return accept, jet_conn, jnp.zeros((), ACC_DTYPE)
+
+    def pruned_wide(conn, candidate):
+        kept = prune_candidates_to_budget(
+            candidate, gain, graph.degrees, salt ^ 0x5BD1E995, dslots
+        )
+        next_part = jnp.where(kept, best, part)
+        accept, jet_conn = _rows_filter(
+            graph, conn, part, next_part, gain, kept, k, dslots
+        )
+        return accept, jet_conn, jnp.sum(candidate & ~kept, dtype=ACC_DTYPE)
+
+    if conn_slots == 0:
+        return (*pruned_wide(conn, candidate), jnp.int32(1))
+    wide = _candidate_slots(graph, candidate) > conn_slots
+    return (*lax.cond(wide, pruned_wide, narrow, conn, candidate),
+            wide.astype(jnp.int32))
+
+
 def _jet_iteration(
     graph: DeviceGraph,
     part: jax.Array,
@@ -317,7 +372,7 @@ def _jet_iteration(
     conn: jax.Array | None = None,
 ) -> Tuple[jax.Array, ...]:
     """One Jet move round.  Returns (new_part, new_lock, ext_sum,
-    new_conn, conn_delta, pruned, rows) where ext_sum = sum over real
+    new_conn, conn_delta, pruned, rows, wide) where ext_sum = sum over real
     nodes of (weighted degree - connection to own block) in the INPUT
     partition — the rating table
     gives the input partition's edge cut for free as ext_sum / 2, saving
@@ -336,9 +391,12 @@ def _jet_iteration(
     the movers' rows or a rebuild (lax.cond picks, see _conn_step).
     conn_delta counts the iteration's two reconciles that re-scattered
     rows: 0, 1 or 2; pruned the candidates prune_candidates_to_budget
-    dropped (0 under the gate, which has no budget); rows is 1 where the
-    afterburner ran over the candidates' rows (always past the gate;
-    under it where they fit _conn_slots) and 0 where it ran edge-wide."""
+    dropped (0 under the gate, which has no budget, and where the
+    candidates' rows fit _conn_slots); rows is 1 where the afterburner
+    ran over the candidates' rows (always past the gate; under it where
+    they fit _conn_slots) and 0 where it ran edge-wide; wide is 1 where
+    the candidates were pruned to and filtered through _delta_slots (past
+    the gate, where their rows overflow _conn_slots) and 0 elsewhere."""
     dslots = _delta_slots(graph)
     conn_slots = _conn_slots(graph)
 
@@ -360,47 +418,46 @@ def _jet_iteration(
     # Only edges of CANDIDATE rows contribute to the filter, so it runs
     # over those rows wherever a buffer holds them (_rows_filter: every
     # pass at buffer width, the table updated from the same buffer).
-    # Past the gate the candidates are first PRUNED to the best-gain
-    # subset whose rows fit m_pad // 4 (two-stage candidate pruning;
-    # pruned candidates compete again next iteration), so they always
-    # fit.  Under it nothing is pruned: lax.cond takes the rows through
-    # _conn_step's buffer when the candidates' degrees sum to at most
-    # its width (a number already in hand, an n-wide reduce) and the
-    # edge-wide filter with a rebuild otherwise.  adj_gain of a
-    # non-candidate differs between the two and is masked by `candidate`
-    # in both; a candidate's is the same integers summed over the same
-    # row, and the packed / exact guard reads candidates' gains only:
-    # the partition and the table are bitwise the same whichever ran.
+    # lax.cond takes the rows through _conn_step's buffer when the
+    # candidates' degrees sum to at most its width (an n-wide reduce).
+    # Where they do not: past the gate the candidates are PRUNED to the
+    # best-gain subset whose rows fit m_pad // 4 (two-stage candidate
+    # pruning; pruned candidates compete again next iteration) and
+    # filtered through that buffer; under it nothing is pruned and the
+    # filter runs edge-wide with a rebuild.  The prune keeps a set that
+    # fits as it is, _rows_filter gives the same integers at any width
+    # that holds the rows, and adj_gain of a non-candidate differs
+    # between the row and the edge-wide filter and is masked by
+    # `candidate` in both (a candidate's is the same integers summed
+    # over the same row, and the packed / exact guard reads candidates'
+    # gains only): the partition and the table are bitwise the same
+    # whichever ran.
     pruned = jnp.int32(0)
-    if dslots is not None:
-        found = candidate
-        candidate = prune_candidates_to_budget(
-            candidate, gain, graph.degrees, salt ^ 0x5BD1E995, dslots
-        )
-        pruned = jnp.sum(found & ~candidate, dtype=ACC_DTYPE)
-    next_part = jnp.where(candidate, best, part)
-    filter_args = (part, next_part, gain, candidate)
+    wide = jnp.int32(0)
     if dslots is not None:
         rows = jnp.bool_(True)
-        accept, jet_conn = _rows_filter(graph, conn, *filter_args, k, dslots)
-    elif conn_slots == 0:
-        rows = jnp.bool_(False)
-        accept, jet_conn = _edges_filter(graph, *filter_args, k)
+        accept, jet_conn, pruned, wide = _gated_rows_filter(
+            graph, conn, part, best, gain, candidate, k, salt, dslots,
+            conn_slots,
+        )
+        # every accepted move is a kept candidate's, bound for `best`
+        next_part = best
     else:
-        # degree total <= m_pad < 2^31 (device layout)
-        # tpulint: disable=R3
-        cand_edges = jnp.sum(
-            jnp.where(candidate, graph.degrees, 0), dtype=jnp.int32
-        )
-        rows = cand_edges <= conn_slots
-        accept, jet_conn = lax.cond(
-            rows,
-            lambda conn, *args: _rows_filter(
-                graph, conn, *args, k, conn_slots
-            ),
-            lambda conn, *args: _edges_filter(graph, *args, k),
-            conn, *filter_args,
-        )
+        next_part = jnp.where(candidate, best, part)
+        filter_args = (part, next_part, gain, candidate)
+        if conn_slots == 0:
+            rows = jnp.bool_(False)
+            accept, jet_conn = _edges_filter(graph, *filter_args, k)
+        else:
+            rows = _candidate_slots(graph, candidate) <= conn_slots
+            accept, jet_conn = lax.cond(
+                rows,
+                lambda conn, *args: _rows_filter(
+                    graph, conn, *args, k, conn_slots
+                ),
+                lambda conn, *args: _edges_filter(graph, *args, k),
+                conn, *filter_args,
+            )
     # the row filter serves the Jet moves' reconcile from its buffer
     rows = rows.astype(jnp.int32)
     new_part = jnp.where(accept, next_part, part)
@@ -448,7 +505,7 @@ def _jet_iteration(
         (jet_conn, new_part, bal_part),
     )
     return (bal_part, new_lock, ext_sum, new_conn, rows + bal_delta, pruned,
-            rows)
+            rows, wide)
 
 
 @partial(
@@ -507,8 +564,8 @@ def _jet_chunk(
         salt = (
             seed.astype(jnp.int32) * 31321 + rnd * 2221 + i * 1566083941
         ) & 0x7FFFFFFF
-        (new_part, lock, ext_sum, conn, conn_delta, pruned,
-         rows) = _jet_iteration(
+        (new_part, lock, ext_sum, conn, conn_delta, pruned, rows,
+         wide) = _jet_iteration(
             graph,
             part,
             lock,
@@ -549,10 +606,11 @@ def _jet_chunk(
             # rows instead of a rebuild (0..2); pruned = candidates the
             # row budget dropped (they compete again next iteration);
             # rows = 1 where the afterburner ran over the candidates'
-            # rows, 0 where it ran edge-wide
+            # rows, 0 where it ran edge-wide; wide = 1 where they were
+            # pruned to and filtered through _delta_slots
             stats = progress_mod.record(
                 stats, i, cut, jnp.sum(lock), fruitless, conn_delta, pruned,
-                rows,
+                rows, wide,
             )
         return (j + 1, fruitless, new_part, lock, best, best_cut, conn,
                 stats)
@@ -673,7 +731,7 @@ def _jet_refine_impl(
             conn = _jet_build_conn(graph, part, k)
         # per-round progress buffer, row-indexed by the global iteration
         # so it rides across host-driven chunks without a host pull
-        stats = progress_mod.new_buffer(max_iterations, 6) if rec else None
+        stats = progress_mod.new_buffer(max_iterations, 7) if rec else None
         t0 = progress_mod.now()
         i = 0
         closed = False
@@ -717,7 +775,8 @@ def _jet_refine_impl(
             # driver's fruitless readback already synced the stream)
             progress_mod.emit(
                 "jet",
-                ("cut", "moved", "fruitless", "conn_delta", "pruned", "rows"),
+                ("cut", "moved", "fruitless", "conn_delta", "pruned", "rows",
+                 "wide"),
                 stats, t0, round=rnd, best_cut=int(best_cut),
             )
         # rollback to best (jet_refiner.cc:221-227): the round continues

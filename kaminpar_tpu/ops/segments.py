@@ -794,12 +794,13 @@ def prune_candidates_to_budget(
     """Restrict `candidate` to the best-(gain, hashed tie) subset whose
     total degree fits `budget` edge slots.
 
-    The two-stage candidate pruning of the Jet refiner: the gain
-    temperature admits most border nodes on fine RMAT levels, so the
-    candidate rows overflow the delta buffer and every pass falls back
-    to full edge width (the round-2 wall-clock whale).  Keeping the
-    top-gain candidates that fit guarantees the row-compacted path
-    always fires; pruned candidates stay unlocked and compete again next
+    The two-stage candidate pruning of the Jet refiner past its edge-slot
+    gate, for the iterations whose candidate rows overflow the narrow
+    row buffer: the gain temperature admits most border nodes on fine
+    RMAT levels, and without a prune such a pass would fall back to
+    full edge width.  Keeping the top-gain candidates that fit the wide
+    buffer keeps every such iteration on the row-compacted path;
+    pruned candidates stay unlocked and compete again next
     iteration, so over a Jet round's 8-16 iterations the move order
     approaches the reference's gain-ordered afterburner sequence
     (jet_refiner.cc:133-170) rather than changing what can move.

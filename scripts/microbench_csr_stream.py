@@ -21,9 +21,18 @@ smallest: what ops/jet.CONN_DELTA_DIVISOR rests on.  With
 ops/jet._jet_iteration on both paths, from the same graph, the same
 partition (a random one settled by SETTLE edge-wide iterations), the same
 table and the same salt: `jet-rows` as the program runs it from
-jet.DELTA_MIN_EDGE_SLOTS slots on, and `jet-edges` with that gate raised
-past the shape for this process alone; ms, and what each iteration
-counted (movers, conn_delta, pruned, rows).  At a shape UNDER that gate
+jet.DELTA_MIN_EDGE_SLOTS slots on, `jet-edges` with that gate raised
+past the shape for this process alone, and `wide`, the `jet-rows`
+iteration without its narrow branch (the candidates always pruned to and
+filtered through m_pad // 4, whether or not their rows fit _conn_slots);
+ms, and what each iteration counted (movers, conn_delta, pruned, rows,
+wide).
+Past the gate the row also times the filter alone from the iteration's
+find step (jet._gated_rows_filter): as the program runs it
+(`narrow_filter_ms`: through _conn_slots where the candidates' rows fit
+it, `filter_wide` 0) against the prune and the afterburner through
+m_pad // 4 (`wide_filter_ms`), both checked to give the same accepts,
+table and `pruned`.  At a shape UNDER that gate
 the pair is the one the iteration chooses between by itself: the row
 afterburner through _conn_slots (jet._rows_filter) against the edge-wide
 one followed by _conn_step (the iteration as it was before PR 35) from the
@@ -235,31 +244,66 @@ def parent_filter(graph, conn, part, next_part, gain, candidate, k, slots):
     return accept, jet._conn_step(graph, conn, part, moved, k, slots)[0]
 
 
-def jet_step(graph, k, caps, gate, rows_filter=None):
-    """One jitted _jet_iteration that resolves its path under `gate` and
-    filters its rows through `rows_filter` (default: the program's).
-    Both are read while tracing; the program's own are back after every
-    call."""
+def always_wide(graph, conn, part, best, gain, candidate, k, salt, dslots,
+                conn_slots, gated=jet._gated_rows_filter):
+    """jet._gated_rows_filter without its narrow branch: the prune and the
+    buffer of `dslots` whatever the candidates' rows (_conn_step keeps its
+    own buffer)."""
+    return gated(graph, conn, part, best, gain, candidate, k, salt, dslots, 0)
+
+
+def jet_step(graph, k, caps, gate, **stand_ins):
+    """One jitted _jet_iteration that resolves its path under `gate`, with
+    the functions of ops/jet named in `stand_ins` replaced.  Both are read
+    while tracing; the program's own are back after every call."""
     step = jax.jit(lambda g, part, lock, conn, salt: jet._jet_iteration(
         g, part, lock, k, caps, jnp.float32(0.25), salt, 4, conn=conn))
 
     def call(part, lock, conn, salt):
-        shipped = jet.DELTA_MIN_EDGE_SLOTS, jet._rows_filter
+        shipped = {name: getattr(jet, name)
+                   for name in ("DELTA_MIN_EDGE_SLOTS", *stand_ins)}
         jet.DELTA_MIN_EDGE_SLOTS = gate
-        jet._rows_filter = rows_filter or jet._rows_filter
+        for name, fn in stand_ins.items():
+            setattr(jet, name, fn)
         try:
             return step(graph, part, lock, conn, salt)
         finally:
-            jet.DELTA_MIN_EDGE_SLOTS, jet._rows_filter = shipped
+            for name, value in shipped.items():
+                setattr(jet, name, value)
 
     return call
+
+
+def filter_row(graph, part, lock, conn, k, salt):
+    """ms of jet._gated_rows_filter from one find step: as the program
+    runs it (through _conn_slots where the candidates' rows fit it) and
+    without its narrow branch (pruned to and through _delta_slots); the
+    accepts, the table and `pruned` of both compared."""
+    best, gain, _, candidate = jet._find_moves(
+        graph, conn, part, lock, k, jnp.float32(0.25), salt)
+    conn_slots, dslots = jet._conn_slots(graph), jet._delta_slots(graph)
+
+    def gated(slots):
+        return lambda g, conn, part, best, gain, candidate: (
+            jet._gated_rows_filter(g, conn, part, best, gain, candidate, k,
+                                   salt, dslots, slots))
+
+    args = (graph, conn, part, best, gain, candidate)
+    got, want = jax.jit(gated(conn_slots))(*args), jax.jit(gated(0))(*args)
+    return dict(
+        candidate_edges=int(jet._candidate_slots(graph, candidate)),
+        filter_wide=int(got[3]),
+        narrow_filter_ms=timeit(gated(conn_slots), *args),
+        wide_filter_ms=timeit(gated(0), *args),
+        same_filter=all(bool(jnp.all(a == b))
+                        for a, b in zip(got[:3], want[:3])))
 
 
 def candidate_edges(graph, part, lock, conn, k, salt):
     """Summed degree of the candidates jet_step's iteration finds."""
     *_, candidate = jet._find_moves(
         graph, conn, part, lock, k, jnp.float32(0.25), salt)
-    return int(jnp.sum(jnp.where(candidate, graph.degrees, 0)))
+    return int(jet._candidate_slots(graph, candidate))
 
 
 def jet_iteration_row(rng, graph, k):
@@ -274,9 +318,13 @@ def jet_iteration_row(rng, graph, k):
     under_gate = m_pad < jet.DELTA_MIN_EDGE_SLOTS
     if under_gate:
         paths = {"rows": jet_step(graph, k, caps, 2 * m_pad),
-                 "edges": jet_step(graph, k, caps, 2 * m_pad, parent_filter)}
+                 "edges": jet_step(graph, k, caps, 2 * m_pad,
+                                   _rows_filter=parent_filter)}
     else:
-        paths = {"rows": jet_step(graph, k, caps, jet.DELTA_MIN_EDGE_SLOTS),
+        gate = jet.DELTA_MIN_EDGE_SLOTS
+        paths = {"rows": jet_step(graph, k, caps, gate),
+                 "wide": jet_step(graph, k, caps, gate,
+                                  _gated_rows_filter=always_wide),
                  "edges": jet_step(graph, k, caps, 2 * m_pad)}
     lock = jnp.zeros(n_pad, jnp.int32)
     conn = jet._full_ratings(graph, part, k)
@@ -297,18 +345,23 @@ def jet_iteration_row(rng, graph, k):
         lock = jnp.asarray(locked)
         row["candidate_edges"] = candidate_edges(
             graph, part, lock, conn, k, salts[-1])
+    else:
+        row.update(filter_row(graph, part, lock, conn, k, salts[-1]))
     after, table = {}, {}
     for name, step in paths.items():
         args = (part, lock, conn, salts[-1])
         (after[name], new_lock, _, table[name], conn_delta, pruned,
-         rows) = step(*args)
+         rows, wide) = step(*args)
         row[f"{name}_ms"] = best_ms(step, *args)
         row[name] = dict(
             accepted=int(new_lock.sum()),
             changed=int((after[name] != part).sum()),
-            conn_delta=int(conn_delta), pruned=int(pruned), rows=int(rows))
-    row["same_partition"] = bool(jnp.all(after["rows"] == after["edges"]))
-    row["same_table"] = bool(jnp.all(table["rows"] == table["edges"]))
+            conn_delta=int(conn_delta), pruned=int(pruned), rows=int(rows),
+            wide=int(wide))
+    row["same_partition"] = all(
+        bool(jnp.all(after["rows"] == p)) for p in after.values())
+    row["same_table"] = all(
+        bool(jnp.all(table["rows"] == t)) for t in table.values())
     return row
 
 

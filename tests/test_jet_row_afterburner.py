@@ -114,8 +114,8 @@ def _iterate(g, k: int, slots: int, steps: int = 2):
     conn = jet_mod._full_ratings(g, part, k)
     outs = []
     for _ in range(steps):
-        part, lock, ext_sum, conn, delta, pruned, rows = _step(k, slots)(
-            g, part, lock, conn, caps, wdeg)
+        part, lock, ext_sum, conn, delta, pruned, rows, _ = _step(
+            k, slots)(g, part, lock, conn, caps, wdeg)
         outs.append(SimpleNamespace(
             part=np.asarray(part), lock=np.asarray(lock),
             ext_sum=int(ext_sum), conn=np.asarray(conn),
@@ -195,7 +195,7 @@ def _refine(g, k: int, part, caps):
     finally:
         telemetry.enable() if was_enabled else telemetry.disable()
     assert all(list(s) == ["cut", "moved", "fruitless", "conn_delta",
-                           "pruned", "rows"] for s in series)
+                           "pruned", "rows", "wide"] for s in series)
     return (out, [r for s in series for r in s["rows"]],
             [d for s in series for d in s["conn_delta"]])
 

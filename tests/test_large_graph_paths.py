@@ -3,9 +3,10 @@ the facade, against the plain host reference: the benchmark cell
 `rmat-s17.k2` (Graph500-style R-MAT, n = 2^17, 2.2 M directed slots,
 `m_pad` 2^22) is this on the chip.
 
-From `DELTA_MIN_EDGE_SLOTS` slots on a Jet iteration prunes its
-candidates to a row buffer and runs the afterburner over that buffer
-(`jet-rows`), the coarse budget is 8, and LP takes delta rounds.  The
+From `DELTA_MIN_EDGE_SLOTS` slots on a Jet iteration always runs its
+afterburner over the candidates' rows (`jet-rows`): through `_conn_slots`
+where they fit it, pruned to and through `_delta_slots` where they do
+not; the coarse budget is 8, and LP takes delta rounds.  The
 tests lower both gates to the graph's own `m_pad`, so every level of the
 small graph is "large".
 """
@@ -62,12 +63,15 @@ def _tree(node, path=""):
     return out
 
 
-def _partition(graph, k: int, seed: int) -> SimpleNamespace:
+def _partition(graph, k: int, seed: int, gated=None) -> SimpleNamespace:
     """One request through the facade with telemetry on: the partition,
     what the program reports of it, its timer tree, the `_delta_slots` of
-    every graph Jet was called on, and the `jet` progress series."""
+    every graph Jet was called on, the `jet` progress series, and what
+    `gated` (a list the fixture's `_gated_rows_filter` appends to) took
+    during the request."""
     resolved = []
     real = jet_mod.jet_refine
+    start = len(gated) if gated is not None else 0
 
     def recording(g, *args, **kwargs):
         resolved.append(jet_mod._delta_slots(g))
@@ -84,13 +88,34 @@ def _partition(graph, k: int, seed: int) -> SimpleNamespace:
             k=k, epsilon=EPSILON, seed=seed))
         series = [(s.attrs["level"], dict(s.series))
                   for s in telemetry.progress_series("jet")]
+        jax.effects_barrier()
     finally:
         jet_mod.jet_refine = real
         telemetry.enable() if was_enabled else telemetry.disable()
     return SimpleNamespace(
         part=part, reported=solver.result_metrics(graph, part),
         tree=_tree(timer.GLOBAL_TIMER.root), resolved=resolved,
-        series=series)
+        series=series, gated=list(gated[start:]) if gated else [])
+
+
+def _recording_gated_filter(gated: list):
+    """`_gated_rows_filter` that appends, for every iteration it runs,
+    (the candidates' summed degree, `_conn_slots`, wide, pruned)."""
+    real = jet_mod._gated_rows_filter
+
+    def record(candidate, degrees, conn_slots, wide, pruned):
+        gated.append((int(np.where(candidate, degrees, 0).sum()),
+                      int(conn_slots), int(wide), int(pruned)))
+
+    def recording(graph, conn, part, best, gain, candidate, k, salt, dslots,
+                  conn_slots):
+        out = real(graph, conn, part, best, gain, candidate, k, salt,
+                   dslots, conn_slots)
+        jax.debug.callback(record, candidate, graph.degrees,
+                           jnp.int32(conn_slots), out[3], out[2])
+        return out
+
+    return recording
 
 
 @pytest.fixture(scope="module", params=[(2, 1), (2, 2), (4, 1), (4, 2)],
@@ -100,16 +125,26 @@ def case(request):
     graph = _rmat(2 + seed)
     m_pad = device_graph_from_host(graph).src.shape[0]
     patch = pytest.MonkeyPatch()
+    gated = []
+    real_conn_slots = jet_mod._conn_slots
     try:
         closed = _partition(graph, k, seed)
         _gates(patch, m_pad)
-        opened = _partition(graph, k, seed)
-        replay = _partition(graph, k, seed)
+        patch.setattr(jet_mod, "_gated_rows_filter",
+                      _recording_gated_filter(gated))
+        opened = _partition(graph, k, seed, gated)
+        replay = _partition(graph, k, seed, gated)
+        # past the gate, no narrow branch: every iteration prunes to and
+        # filters through `_delta_slots`, as before the branch existed
+        patch.setattr(jet_mod, "_conn_slots", lambda g: (
+            0 if jet_mod._delta_slots(g) is not None else real_conn_slots(g)))
+        jax.clear_caches()
+        wide = _partition(graph, k, seed, gated)
     finally:
         patch.undo()
         jax.clear_caches()
     return SimpleNamespace(k=k, graph=graph, closed=closed, opened=opened,
-                           replay=replay)
+                           replay=replay, wide=wide)
 
 
 def test_rows_path_partition_against_the_host_reference(case):
@@ -155,8 +190,11 @@ def test_every_jet_scope_names_the_iteration_it_resolved_to(case):
 
 def test_pruned_rides_the_progress_series(case):
     assert all(set(s["pruned"]) == {0} for _, s in case.closed.series)
-    assert all(len(s) == 6 and min(s["pruned"]) >= 0
+    assert all(len(s) == 7 and min(s["pruned"]) >= 0
                for _, s in case.opened.series)
+    # a prune runs only where the candidates overflow `_conn_slots`
+    assert all(w == 1 for _, s in case.opened.series
+               for p, w in zip(s["pruned"], s["wide"]) if p > 0)
     if case.k == 4:
         # the coarse call's first iteration finds more rows than
         # m_pad // 4 slots hold (at k = 2 only by the luck of the seed)
@@ -177,11 +215,21 @@ def test_conn_delta_rides_the_progress_series(case):
 
 def test_rows_rides_the_progress_series(case):
     """The sixth column: 1 in every iteration of a `jet-rows` call (the
-    pruned candidates always fit their buffer); under the gate 1 where
-    the candidates' rows fit `_conn_slots` and 0 where the afterburner
-    ran edge-wide, and a row iteration counts its own reconcile."""
-    assert all(list(s)[5] == "rows" for _, s in case.opened.series)
+    candidates fit `_conn_slots` or are pruned to `_delta_slots`); under
+    the gate 1 where the candidates' rows fit `_conn_slots` and 0 where
+    the afterburner ran edge-wide, and a row iteration counts its own
+    reconcile.  The seventh, `wide`: 0 under the gate; past it 1 exactly
+    where the candidates' rows overflow `_conn_slots`."""
+    assert all(list(s)[5:] == ["rows", "wide"] for _, s in case.opened.series)
     assert all(set(s["rows"]) == {1} for _, s in case.opened.series)
+    assert all(set(s["wide"]) == {0} for _, s in case.closed.series)
+    opened = [w for _, s in case.opened.series for w in s["wide"]]
+    assert sorted(opened) == sorted(w for _, _, w, _ in case.opened.gated)
+    assert all(w == int(edges > slots)
+               for edges, slots, w, _ in case.opened.gated)
+    if case.k == 4:
+        assert 1 in opened  # the coarse call's pruning iteration
+    assert opened == [w for _, s in case.replay.series for w in s["wide"]]
     closed = [s for _, s in case.closed.series]
     assert all(set(s["rows"]) <= {0, 1} for s in closed)
     assert {r for s in closed for r in s["rows"]} == {0, 1}
@@ -189,6 +237,20 @@ def test_rows_rides_the_progress_series(case):
                for d, r in zip(s["conn_delta"], s["rows"]))
     assert ([s["rows"] for _, s in case.opened.series]
             == [s["rows"] for _, s in case.replay.series])
+
+
+def test_the_narrow_branch_leaves_the_partition_bitwise(case):
+    """Past the gate an iteration whose candidates' rows fit `_conn_slots`
+    skips the prune and filters through that buffer: the labels, the cut
+    and the prune's count are those of the run that always prunes to and
+    filters through `_delta_slots` (`conn_delta`, `rows` and `wide` may
+    differ: that run's reconciles rebuild)."""
+    np.testing.assert_array_equal(case.opened.part, case.wide.part)
+    for column in ("cut", "pruned"):
+        assert ([s[column] for _, s in case.opened.series]
+                == [s[column] for _, s in case.wide.series])
+    assert all(set(s["wide"]) == {1} for _, s in case.wide.series)
+    assert all(w == 1 for _, _, w, _ in case.wide.gated)
 
 
 def _iteration(graph, k):
@@ -200,10 +262,13 @@ def _iteration(graph, k):
         jnp.int32(5), 4)
 
 
-@pytest.mark.parametrize("budget", ["edge-wide", "full-width", "tight"])
+@pytest.mark.parametrize("budget",
+                         ["edge-wide", "narrow", "full-width", "tight"])
 def test_pruned_counts_what_the_budget_drops(monkeypatch, budget):
-    """0 on the edge-wide path and where every candidate fits the row
-    buffer; candidates before less candidates after with a tight one."""
+    """0 on the edge-wide path, and where the candidates' rows fit
+    `_conn_slots` (`narrow`: the prune never runs, `wide` is 0); past
+    that, where every candidate fits the pruning buffer, and candidates
+    before less candidates after with a tight one (`wide` 1)."""
     graph = device_graph_from_host(
         factories.make_rmat(1 << 10, 12_000, seed=13))
     m_pad = graph.src.shape[0]
@@ -212,19 +277,28 @@ def test_pruned_counts_what_the_budget_drops(monkeypatch, budget):
 
     def recording(candidate, *args):
         kept = real(candidate, *args)
-        seen.append((int(candidate.sum()), int(kept.sum())))
+        # the prune runs inside a lax.cond branch: count what it ran on
+        jax.debug.callback(
+            lambda c, kp: seen.append((int(c.sum()), int(kp.sum()))),
+            candidate, kept)
         return kept
 
     monkeypatch.setattr(jet_mod, "prune_candidates_to_budget", recording)
-    slots = {"edge-wide": None, "full-width": m_pad, "tight": m_pad // 64}
+    slots = {"edge-wide": None, "narrow": m_pad, "full-width": m_pad,
+             "tight": m_pad // 64}
     monkeypatch.setattr(jet_mod, "_delta_slots", lambda g: slots[budget])
-    pruned = int(_iteration(graph, 4)[5])
-    if budget == "edge-wide":
-        assert not seen and pruned == 0
+    if budget == "narrow":
+        monkeypatch.setattr(jet_mod, "_conn_slots", lambda g: m_pad)
+    out = _iteration(graph, 4)
+    jax.effects_barrier()
+    pruned, wide = int(out[5]), int(out[7])
+    if budget in ("edge-wide", "narrow"):
+        assert not seen and pruned == 0 and wide == 0
         return
     ((before, after),) = seen
     assert pruned == before - after
     assert (pruned > 0) == (budget == "tight")
+    assert wide == 1
 
 
 def test_iteration_path_follows_the_shapes(monkeypatch):

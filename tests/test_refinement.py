@@ -332,8 +332,9 @@ def test_conn_reconciles_take_their_own_buffer_at_every_size(
         monkeypatch, rows):
     """Every reconcile _conn_step takes (the balancer's, on both sides
     of the gate) goes through m_pad // CONN_DELTA_DIVISOR slots, whatever
-    the afterburner's buffer is; the afterburner's is m_pad // 4 past
-    the gate and that same sixteenth under it."""
+    the afterburner's buffer is; the afterburner's is that same sixteenth
+    where the candidates' rows fit it, and past the gate m_pad // 4 (after
+    the prune) where they do not: one lax.cond traces both."""
     import kaminpar_tpu.ops.jet as jet_mod
 
     g, cap, p0 = _jet_case("grid", 4, False)
@@ -359,7 +360,7 @@ def test_conn_reconciles_take_their_own_buffer_at_every_size(
     jet_mod._jet_iteration(
         g, p0, jnp.zeros_like(p0), 4, cap, jnp.float32(0.75), jnp.int32(5), 4)
     assert widths == [want]
-    assert filters == [m_pad // 4 if rows else want]
+    assert sorted(filters) == ([want, m_pad // 4] if rows else [want])
 
 
 @pytest.mark.parametrize("over", [0, 1], ids=["fits", "one-over"])
@@ -385,11 +386,11 @@ def test_conn_step_threshold(over):
 
 
 def test_jet_conn_delta_counter_rides_only_the_stats_buffer():
-    """The conn_delta, pruned and rows counters are the fourth, fifth and
-    sixth column of the `jet` progress series; with telemetry off
+    """The conn_delta, pruned, rows and wide counters are the fourth to
+    seventh column of the `jet` progress series; with telemetry off
     _jet_chunk's loop has the carries it had before the counters (j,
     fruitless, part, lock, best, best_cut, conn) and with it on exactly
-    one more, the stats buffer, one column wider for `rows`."""
+    one more, the stats buffer, one column wider for `wide`."""
     import jax
 
     import kaminpar_tpu.ops.jet as jet_mod
@@ -416,11 +417,11 @@ def test_jet_conn_delta_counter_rides_only_the_stats_buffer():
 
     off = loop(chunk(None))
     assert len(off.outvars) == 7
-    assert len(loop(chunk(progress_mod.new_buffer(4, 6))).outvars) == 8
-    # the buffer is as wide as the series has names: one of five columns
-    # (the series before `rows`) does not take the record
+    assert len(loop(chunk(progress_mod.new_buffer(4, 7))).outvars) == 8
+    # the buffer is as wide as the series has names: one of six columns
+    # (the series before `wide`) does not take the record
     with pytest.raises(ValueError):
-        chunk(progress_mod.new_buffer(4, 5))
+        chunk(progress_mod.new_buffer(4, 6))
     # telemetry off, every carry is a scalar, a node-wide vector or the
     # table: nothing of the stats rides the loop
     assert {v.aval.shape for v in off.outvars} == {
