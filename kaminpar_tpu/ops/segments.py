@@ -562,11 +562,16 @@ def conn_table_streams(k: int, n_pad: int, m_pad: int) -> bool:
 
 def count_conn_engine(graph, k: int) -> None:
     """Host-side record of the engine csr_block_ratings takes for one
-    refiner call on `graph` (utils/statistics; free when disabled)."""
+    refiner call on `graph` (utils/statistics, free when disabled; a
+    scatter also in telemetry/request_account's `shape_account`, kept
+    with telemetry off)."""
+    from ..telemetry.request_account import shape_account
     from ..utils import statistics
 
     streams = conn_table_streams(k, graph.n_pad, graph.m_pad)
     statistics.count("conn_streamed" if streams else "conn_scattered")
+    if not streams:
+        shape_account.add({"conn_scatter_calls": 1})
 
 
 def csr_block_ratings(graph, labels: jax.Array, num_blocks: int) -> jax.Array:

@@ -339,6 +339,9 @@ class Coarsener:
         self.current = coarse.graph
         self.current_n = c_n
         from .. import telemetry
+        from ..telemetry.request_account import shape_account
+
+        shape_account.add({"coarsening_levels": 1})
 
         # per-level resident-buffer accounting (perf.memory.levels):
         # padded shapes and total device-array bytes of the coarse CSR —

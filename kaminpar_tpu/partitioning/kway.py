@@ -236,12 +236,16 @@ class KWayMultilevelPartitioner:
                 ctx, np.asarray(dump_part)[:dump_n], dump_level
             )
 
-        # strict balance backstop on the finest level
-        partition = refiner.enforce_balance_host(
-            dgraph, partition, np.asarray(ctx.partition.max_block_weights),
-            where="kway",
-        )
+        # strict balance backstop on the finest level; readback scopes
+        # as deep's: the overload check is the first host read after the
+        # last refiner, the download the last of the request
+        with timer.scoped_timer("balance-check", sync=True):
+            partition = refiner.enforce_balance_host(
+                dgraph, partition,
+                np.asarray(ctx.partition.max_block_weights), where="kway",
+            )
         # quality: coarsening floors + per-level attribution from the
         # final partition (telemetry/quality.py)
         quality_mod.finalize_device(qh, dgraph, partition, graph.n)
-        return np.asarray(partition)[: graph.n]
+        with timer.scoped_timer("partition-download", sync=True):
+            return np.asarray(partition)[: graph.n]

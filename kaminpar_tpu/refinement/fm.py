@@ -42,8 +42,6 @@ benchmark's `fm_cap_refusals` and `fm_undone_moves` read.
 from __future__ import annotations
 
 import heapq
-import threading
-from collections import OrderedDict
 from typing import Optional
 
 import numpy as np
@@ -51,45 +49,26 @@ import numpy as np
 from ..context import FMRefinementContext
 from ..graphs.csr import DeviceGraph, host_graph_from_device
 from ..graphs.host import HostGraph
-from ..telemetry import compile_account
 from ..telemetry import progress as progress_mod
+from ..telemetry.request_account import RequestAccount
 from ..utils.timer import scoped_timer
 from .gains import create_host_gain_cache
-
-_KEEP_REQUESTS = 64  # the last so many requests' sums
 
 #: the native counters the account keeps: what the benchmark's
 #: `fm_cap_refusals` and `fm_undone_moves` read
 ACCOUNTED = ("cap_refusals", "undone_moves")
 
 
-class FMAccount:
+class FMAccount(RequestAccount):
     """The native FM calls' `ACCOUNTED` counters, summed per request
-    ordinal (0 outside any request)."""
+    ordinal (0 outside any request); a request without an FM call has
+    no entry."""
 
     def __init__(self) -> None:
-        self._lock = threading.Lock()
-        self._by_request: "OrderedDict[int, dict]" = OrderedDict()
+        super().__init__(ACCOUNTED)
 
     def record(self, stats: dict) -> None:
-        ordinal = compile_account.open_request()
-        with self._lock:
-            entry = self._by_request.get(ordinal)
-            if entry is None:
-                entry = self._by_request[ordinal] = dict.fromkeys(
-                    ACCOUNTED, 0)
-                while len(self._by_request) > _KEEP_REQUESTS:
-                    self._by_request.popitem(last=False)
-            for name in ACCOUNTED:
-                entry[name] += stats.get(name, 0)
-
-    def summary(self) -> dict:
-        """`{"requests": requests begun, "by_request": {ordinal: {...}}}`;
-        a request without an FM call has no entry."""
-        with self._lock:
-            return {"requests": compile_account.requests_begun(),
-                    "by_request": {k: dict(v)
-                                   for k, v in self._by_request.items()}}
+        self.add({name: stats.get(name, 0) for name in ACCOUNTED})
 
 
 fm_account = FMAccount()

@@ -139,6 +139,8 @@ def test_csr_block_ratings_is_dense_block_ratings(shape, k, max_w):
     (16, 1 << 17, 1 << 20, True), (32, 1 << 17, 1 << 20, False),
     (16, 1 << 16, 1 << 22, True), (32, 1 << 16, 1 << 22, False),
     (2, 1 << 21, 1 << 22, False),
+    # rmat-s16-kway.k64's coarse levels (PERF.md): 8 steps stream
+    (64, 1 << 15, 1 << 20, True), (64, 1 << 14, 1 << 20, True),
 ])
 def test_conn_table_rule_on_the_benchmark_shapes(k, n_pad, m_pad, streams):
     assert conn_table_streams(k, n_pad, m_pad) == streams
