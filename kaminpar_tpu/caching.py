@@ -9,7 +9,10 @@ serving layer, multi-chip scale-out) shares used to be scattered:
     levels, graphs, and requests.  ``pad_size`` (previously
     ``utils/math.pad_size``; re-exported there for its existing callers)
     is THE policy: next power of two with a granularity floor, giving
-    O(log n) distinct compiled shapes per graph.  ``bucket_key``
+    O(log n) distinct compiled shapes per graph.  The request's own
+    graph (level 0) alone rounds finer, to an eighth of that bucket
+    (``input_pad_size``), and every path decision reads the bucket
+    (``shape_bucket``) rather than the width.  ``bucket_key``
     compacts a request's (n, m, k) into the executable-identity triple
     the jit cache effectively keys on, so executable *reuse* becomes an
     observable hit-rate instead of an invisible property of jax
@@ -94,6 +97,35 @@ def pad_size(x: int, granularity: int = 256) -> int:
     return _ceil2(x)
 
 
+def input_pad_size(x: int, granularity: int = 256) -> int:
+    """The shape bucket of the request's own graph (level 0): pad_size's
+    power-of-two bucket p cut into eighths, the smallest multiple of
+    p // 16 that holds x.  x in (p/2, p] has eight such buckets, so an
+    edge-wide pass of level 0 pays for at most p/16 pad slots instead of
+    up to p/2, while shape_bucket() of the result is p: every gate and
+    row buffer that reads shape_bucket() sees the bucket pad_size gives.
+    At or below the floor, and under the "tight" pad policy, this is
+    pad_size: a level 0 under the floor shares its floor-wide programs
+    with the coarse levels.  Coarse levels, subgraphs and re-uploads
+    keep pad_size: the eighths cost one set of executables per eighth
+    an input falls in, and only the input graph is uploaded once per
+    request."""
+    p = pad_size(x, granularity)
+    if p <= granularity or pad_policy() == "tight":
+        return p
+    step = max(p // 16, 1)
+    return -(-x // step) * step
+
+
+def shape_bucket(width: int) -> int:
+    """The power-of-two bucket of a padded width: the width itself on
+    every pad_size shape, the pad_size bucket of an input_pad_size
+    shape.  Path gates and buffer widths read this, never the array
+    width, so an eighth-octave level 0 takes the paths and buffers of
+    its power-of-two bucket."""
+    return _ceil2(width)
+
+
 def pad_k(k: int) -> int:
     """The block-count bucket: k rounded up to a power of two (>= 2).
     Mirrors ops/segments.pad_k_bucket, which additionally builds the
@@ -107,8 +139,11 @@ def bucket_key(n: int, m: int, k: int) -> Tuple[int, int, int]:
     padded edge slots, padded block count.  Two requests with the same
     bucket key drive the device phases through the same compiled
     programs (same shapes, same k tables) — the serving layer counts
-    reuse of these keys as its executable-cache hit rate."""
-    return (pad_size(int(n) + 1), pad_size(max(int(m), 1)), pad_k(k))
+    reuse of these keys as its executable-cache hit rate.  The node and
+    edge slots are the input graph's (input_pad_size): the request's
+    level-0 executables key on them."""
+    return (input_pad_size(int(n) + 1), input_pad_size(max(int(m), 1)),
+            pad_k(k))
 
 
 def record_padding(n=None, n_pad=None, m=None, m_pad=None,

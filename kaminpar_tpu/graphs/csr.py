@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..caching import pad_size
+from ..caching import input_pad_size, pad_size, shape_bucket
 from ..utils.timer import scoped_timer
 from .host import HostGraph
 
@@ -70,6 +70,19 @@ class DeviceGraph:
     @property
     def m_pad(self) -> int:
         return self.src.shape[0]
+
+    @property
+    def n_bucket(self) -> int:
+        """Power-of-two bucket of n_pad (caching.shape_bucket): what
+        shape gates read, so the input graph's eighth-octave pad takes
+        the paths of its bucket."""
+        return shape_bucket(self.n_pad)
+
+    @property
+    def m_bucket(self) -> int:
+        """Power-of-two bucket of m_pad (caching.shape_bucket): what
+        shape gates and row-buffer widths read."""
+        return shape_bucket(self.m_pad)
 
     @property
     def degrees(self) -> jax.Array:
@@ -164,6 +177,26 @@ def device_graph_from_host(
         n=put(np.int32(n)),
         m=put(np.int32(m)),
     )
+
+
+def upload_input_graph(graph) -> DeviceGraph:
+    """Upload the request's own graph, a HostGraph or a
+    CompressedHostGraph, at level 0's shape bucket
+    (caching.input_pad_size: an eighth of the power-of-two bucket over
+    the floors), and count its real and padded edge slots in
+    telemetry/request_account's `shape_account`.  Every scheme's entry
+    uploads through here; coarse levels and subgraphs keep pad_size."""
+    from ..telemetry.request_account import shape_account
+    from .compressed import CompressedHostGraph
+
+    n_floor, m_floor = shape_floors()
+    n_pad = input_pad_size(graph.n + 1, n_floor)
+    m_pad = input_pad_size(max(graph.m, 1), m_floor)
+    shape_account.add({"level0_edge_slots": int(graph.m),
+                       "level0_padded_edge_slots": m_pad})
+    if isinstance(graph, CompressedHostGraph):
+        return device_graph_from_compressed(graph, n_pad=n_pad, m_pad=m_pad)
+    return device_graph_from_host(graph, n_pad=n_pad, m_pad=m_pad)
 
 
 def device_graph_from_compressed(

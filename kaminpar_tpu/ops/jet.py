@@ -52,11 +52,14 @@ from .segments import (
     prune_candidates_to_budget,
 )
 
+# The gate and both buffers read a level's power-of-two bucket
+# (DeviceGraph.m_bucket: m_pad itself on every level but the input
+# graph's, which uploads at an eighth of its bucket).
 # From this many edge slots on, Jet never leaves its row afterburner
 # (_rows_filter) and takes the smaller coarse iteration budget (mirrors
 # ops/lp.DELTA_MIN_EDGE_SLOTS): an iteration whose candidates' rows fit
 # CONN_DELTA_DIVISOR's buffer runs through it, one whose rows overflow
-# it PRUNES its candidates to a buffer of m_pad // 4 slots
+# it PRUNES its candidates to a buffer of m_bucket // 4 slots
 # (prune_candidates_to_budget: a different move set, a different cut,
 # where the prune drops any) and runs through that.  Under the gate
 # nothing is pruned: an iteration runs the same row afterburner through
@@ -67,7 +70,7 @@ from .segments import (
 DELTA_MIN_EDGE_SLOTS = 1 << 22
 
 # Under the gate an iteration runs its afterburner over the candidates'
-# CSR rows while their degrees sum to at most m_pad // CONN_DELTA_DIVISOR
+# CSR rows while their degrees sum to at most m_bucket // CONN_DELTA_DIVISOR
 # slots; _conn_step updates the conn table from the movers' rows through
 # a buffer of the same width, and rebuilds it otherwise, on either side
 # of the gate.  Either costs per slot of the buffer, full or not: 8
@@ -82,7 +85,7 @@ JET_DENSE_MAX_ENTRIES = 1 << 28
 
 
 def _delta_slots(graph: DeviceGraph) -> int | None:
-    m_slots = graph.src.shape[0]
+    m_slots = graph.m_bucket
     if m_slots < DELTA_MIN_EDGE_SLOTS:
         return None
     return m_slots // 4
@@ -100,7 +103,7 @@ def iteration_path(graph: DeviceGraph, k: int) -> str:
     (no dense table: LP refinement rounds).  The refiner names a timer
     scope after it, so a trace of a run with telemetry off still says
     which Jet a level ran."""
-    if graph.n_pad * k > JET_DENSE_MAX_ENTRIES:
+    if graph.n_bucket * k > JET_DENSE_MAX_ENTRIES:
         return "jet-lp"
     return "jet-edges" if _delta_slots(graph) is None else "jet-rows"
 
@@ -108,7 +111,7 @@ def iteration_path(graph: DeviceGraph, k: int) -> str:
 def _conn_slots(graph: DeviceGraph) -> int:
     """Row-buffer width of a _conn_step reconcile, whatever the path, and
     of the row afterburner wherever the candidates' rows fit it."""
-    return graph.src.shape[0] // CONN_DELTA_DIVISOR
+    return graph.m_bucket // CONN_DELTA_DIVISOR
 
 
 def _full_ratings(graph: DeviceGraph, part: jax.Array, k: int) -> jax.Array:
@@ -709,10 +712,10 @@ def _jet_refine_impl(
     best = part
     # scale the iteration chunk down with edge count so each launch
     # stays short (see segments.MAX_FUSED_EDGE_SLOTS)
-    m_pad = graph.src.shape[0]
-    if m_pad > MAX_FUSED_EDGE_SLOTS:
+    m_bucket = graph.m_bucket
+    if m_bucket > MAX_FUSED_EDGE_SLOTS:
         chunk = 1
-    elif m_pad > MAX_FUSED_EDGE_SLOTS // 2:
+    elif m_bucket > MAX_FUSED_EDGE_SLOTS // 2:
         chunk = min(chunk, 2)
     rec = progress_mod.capture()
     for rnd in range(num_rounds):
@@ -855,7 +858,7 @@ def jet_refine(
         max_iterations = ctx.num_iterations
     elif is_coarse:
         max_iterations = (
-            8 if graph.src.shape[0] >= DELTA_MIN_EDGE_SLOTS else 12
+            8 if graph.m_bucket >= DELTA_MIN_EDGE_SLOTS else 12
         )
     else:
         max_iterations = 8

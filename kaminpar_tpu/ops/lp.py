@@ -149,7 +149,9 @@ def _delta_slots(graph: DeviceGraph, cfg: LPConfig, engine: str) -> int | None:
         return None
     if engine not in ("sort2", "dense", "scatter"):
         return None
-    m_slots = graph.src.shape[0]
+    # the power-of-two bucket, not the width: an eighth-octave input
+    # graph gates and sizes its buffer as its bucket does
+    m_slots = graph.m_bucket
     # the scatter engine's per-round cost is segment-op bound, which
     # shrinks with buffer width immediately — its delta crossover sits
     # far lower than the sort engines' (measured in the round-9 CPU
@@ -196,7 +198,7 @@ def lp_round(
     C = cluster_weights.shape[0]
     cap = jnp.broadcast_to(max_cluster_weight, (C,))
     engine = _select_engine(
-        cfg, C, graph.m_pad, communities is not None, n_pad=n_pad
+        cfg, C, graph.m_bucket, communities is not None, n_pad=graph.n_bucket
     )
     if rows is not None and engine not in ("sort2", "dense", "scatter"):
         raise ValueError(f"delta rounds are not supported by engine {engine}")
@@ -237,7 +239,9 @@ def lp_round(
         # On dense coarse levels (hundreds of adjacent clusters, most
         # near the weight cap) a deeper candidate list keeps merges
         # flowing — the reads are n-wide gathers, essentially free.
-        avg_degree = graph.m_pad / max(C, 1)
+        avg_degree = graph.m_bucket / max(
+            graph.n_bucket if C == n_pad else C, 1
+        )
         K = cfg.topk if avg_degree <= 32 else max(cfg.topk, 16)
         nb = jnp.where(valid, labels[dst_b], -1) if rows is not None else (
             labels[dst_b]
@@ -547,7 +551,7 @@ def _round_with_delta(
     work-skipping (label_propagation.h:507-513)."""
     C = weights.shape[0]
     engine = _select_engine(
-        cfg, C, graph.m_pad, communities is not None, n_pad=graph.n_pad
+        cfg, C, graph.m_bucket, communities is not None, n_pad=graph.n_bucket
     )
     dslots = _delta_slots(graph, cfg, engine)
     if dslots is None:
@@ -781,7 +785,7 @@ def lp_cluster(
     iters = (
         num_iterations if num_iterations is not None else cfg.num_iterations
     )
-    if graph.src.shape[0] > MAX_FUSED_EDGE_SLOTS and iters > 1:
+    if graph.m_bucket > MAX_FUSED_EDGE_SLOTS and iters > 1:
         # watchdog guard: the dispatch must stay OUTSIDE jit — the
         # chunked loop reads the convergence flag back per round
         return _lp_cluster_chunked(
@@ -836,7 +840,7 @@ def lp_refine(
         # never runs with clustering semantics (tie moves, no positive-gain
         # restriction); replace() preserves the caller's engine settings
         cfg = replace(cfg, allow_tie_moves=False, refinement=True)
-    if graph.src.shape[0] > MAX_FUSED_EDGE_SLOTS and iters > 1:
+    if graph.m_bucket > MAX_FUSED_EDGE_SLOTS and iters > 1:
         from ..caching import record_transfer
         from ..telemetry import ledger
 
@@ -1009,7 +1013,7 @@ def two_hop_cluster(
     # its neighbors' labels, so own-exclusion is harmless here)
     neighbor_cluster = labels[graph.dst]
     engine = _select_engine(
-        cfg, cluster_weights.shape[0], graph.m_pad, n_pad=n_pad
+        cfg, cluster_weights.shape[0], graph.m_bucket, n_pad=graph.n_bucket
     )
     if engine == "scatter":
         # favored cluster = unconstrained best rated cluster from the

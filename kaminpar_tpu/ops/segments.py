@@ -565,10 +565,13 @@ def count_conn_engine(graph, k: int) -> None:
     refiner call on `graph` (utils/statistics, free when disabled; a
     scatter also in telemetry/request_account's `shape_account`, kept
     with telemetry off)."""
+    from ..caching import shape_bucket
     from ..telemetry.request_account import shape_account
     from ..utils import statistics
 
-    streams = conn_table_streams(k, graph.n_pad, graph.m_pad)
+    streams = conn_table_streams(
+        k, shape_bucket(graph.n_pad), shape_bucket(graph.m_pad)
+    )
     statistics.count("conn_streamed" if streams else "conn_scattered")
     if not streams:
         shape_account.add({"conn_scatter_calls": 1})
@@ -584,14 +587,17 @@ def csr_block_ratings(graph, labels: jax.Array, num_blocks: int) -> jax.Array:
     loop: HLO size is constant in k, temporaries are bounded by
     CONN_STREAM_STEP_WORDS, and no (m, k) table ever exists (lane
     padding)."""
-    n_pad, m_pad = graph.n_pad, graph.m_pad
-    if not conn_table_streams(num_blocks, n_pad, m_pad):
+    # the engine and the step width read the power-of-two buckets, so
+    # an eighth-octave input graph streams as its bucket does
+    n_bucket, m_bucket = graph.n_bucket, graph.m_bucket
+    if not conn_table_streams(num_blocks, n_bucket, m_bucket):
         return dense_block_ratings(
-            graph.src, graph.dst, graph.edge_w, labels, n_pad, num_blocks
+            graph.src, graph.dst, graph.edge_w, labels, graph.n_pad,
+            num_blocks,
         )
     return _stream_block_ratings(
         graph, labels, num_blocks,
-        min(num_blocks, conn_stream_columns(m_pad)),
+        min(num_blocks, conn_stream_columns(m_bucket)),
     )
 
 

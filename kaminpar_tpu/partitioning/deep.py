@@ -30,6 +30,7 @@ from ..graphs.csr import (
     WEIGHT_DTYPE,
     device_graph_from_host,
     host_graph_from_device,
+    upload_input_graph,
 )
 from ..graphs.host import HostGraph, extract_block_subgraphs
 from ..initial import InitialMultilevelBipartitioner
@@ -104,15 +105,10 @@ class DeepMultilevelPartitioner:
             # O(n); the extend path must then avoid full-graph readbacks
             # (see _extend_partition)
             self._streamed_input = isinstance(graph, CompressedHostGraph)
-            if isinstance(graph, CompressedHostGraph):
-                # TeraPart compute parity: stream the decode chunk-by-
-                # chunk to the device — the flat CSR never exists on the
-                # host (graphs/csr.device_graph_from_compressed)
-                from ..graphs.csr import device_graph_from_compressed
-
-                dgraph = device_graph_from_compressed(graph)
-            else:
-                dgraph = device_graph_from_host(graph)
+            # a compressed input streams its decode chunk by chunk to
+            # the device (TeraPart compute parity: the flat CSR never
+            # exists on the host; graphs/csr.device_graph_from_compressed)
+            dgraph = upload_input_graph(graph)
 
         # --- coarsen (deep_multilevel.cc:69-183) ---
         coarsener = Coarsener(ctx, dgraph, graph.n)
@@ -561,7 +557,7 @@ class DeepMultilevelPartitioner:
         extra per-block launch floors are what TeraPart parity costs."""
         span_limit = 128 if getattr(self, "_streamed_input", False) else 64
         if (
-            dgraph.m_pad >= DEVICE_EXTEND_MIN_EDGE_SLOTS
+            dgraph.m_bucket >= DEVICE_EXTEND_MIN_EDGE_SLOTS
             and len(spans) <= span_limit
         ):
             return self._extend_partition_device(

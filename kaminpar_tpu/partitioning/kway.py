@@ -17,8 +17,8 @@ from ..dtypes import WEIGHT_DTYPE, WMAX
 from ..context import Context
 from ..graphs.csr import (
     DeviceGraph,
-    device_graph_from_host,
     host_graph_from_device,
+    upload_input_graph,
 )
 from ..graphs.host import HostGraph
 from ..utils import rng as rng_mod
@@ -66,7 +66,7 @@ class KWayMultilevelPartitioner:
         from ..resilience import checkpoint as ckpt
         from ..telemetry import quality as quality_mod
         with timer.scoped_timer("device-upload"):
-            dgraph = device_graph_from_host(graph)
+            dgraph = upload_input_graph(graph)
 
         max_bw = jnp.asarray(
             np.minimum(ctx.partition.max_block_weights, WMAX),

@@ -13,7 +13,7 @@ import numpy as np
 
 from ..dtypes import WEIGHT_DTYPE, WMAX
 from ..context import Context
-from ..graphs.csr import device_graph_from_host
+from ..graphs.csr import upload_input_graph
 from ..graphs.host import HostGraph
 from ..utils import rng as rng_mod
 from ..utils import timer
@@ -42,7 +42,7 @@ class RBMultilevelPartitioner:
         proceed = ckpt.barrier("rb-toplevel", scheme="rb")
         if proceed and ctx.partitioning.rb_enable_kway_toplevel_refinement:
             with timer.scoped_timer("toplevel-refinement"):
-                dgraph = device_graph_from_host(graph)
+                dgraph = upload_input_graph(graph)
                 padded = np.zeros(dgraph.n_pad, dtype=np.int32)
                 padded[: graph.n] = part
                 max_bw = jnp.asarray(

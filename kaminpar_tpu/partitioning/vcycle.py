@@ -17,7 +17,7 @@ import numpy as np
 
 from ..dtypes import WEIGHT_DTYPE, WMAX
 from ..context import Context
-from ..graphs.csr import device_graph_from_host
+from ..graphs.csr import upload_input_graph
 from ..graphs.host import HostGraph
 from ..ops.contraction import contract_clustering
 from ..ops.lp import LPConfig, lp_cluster
@@ -143,7 +143,7 @@ class VcycleDeepMultilevelPartitioner:
 
         ctx = self.ctx
         k = ctx.partition.k
-        dgraph = device_graph_from_host(graph)
+        dgraph = upload_input_graph(graph)
         padded = np.zeros(dgraph.n_pad, dtype=np.int32)
         padded[: graph.n] = part
         partition = jnp.asarray(padded)

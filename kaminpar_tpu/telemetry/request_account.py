@@ -12,9 +12,13 @@ already holds: adding one reads nothing back from the device.
     hierarchy's depth under `deep` and `kway`);
   * `conn_scatter_calls`: refiner calls whose conn table took the
     `segment_sum` scatter rather than the stream
-    (`ops/segments.count_conn_engine`).
+    (`ops/segments.count_conn_engine`);
+  * `level0_edge_slots` and `level0_padded_edge_slots`: the real and
+    the padded edge slots of the request's own graph as uploaded
+    (`graphs/csr.upload_input_graph`).
 
-The benchmark's `coarsening_levels` and `conn_scatter_calls` read it;
+The benchmark's `coarsening_levels`, `conn_scatter_calls` and
+`level0_pad_share` read it;
 `refinement/fm.fm_account` is the same kind of account for host FM.
 """
 
@@ -58,4 +62,7 @@ class RequestAccount:
                                    for k, v in self._by_request.items()}}
 
 
-shape_account = RequestAccount(("coarsening_levels", "conn_scatter_calls"))
+shape_account = RequestAccount((
+    "coarsening_levels", "conn_scatter_calls",
+    "level0_edge_slots", "level0_padded_edge_slots",
+))

@@ -27,6 +27,14 @@ iteration without its narrow branch (the candidates always pruned to and
 filtered through m_pad // 4, whether or not their rows fit _conn_slots);
 ms, and what each iteration counted (movers, conn_delta, pruned, rows,
 wide).
+With --level0-pad, and none of the above, for each shape and k: the same
+real graph uploaded at the shape's power-of-two widths and at the input
+graph's eighth-octave widths (caching.input_pad_size over the chip's
+floors, what graphs/csr.upload_input_graph gives level 0): ms of one
+scatter clustering round (ops/lp.lp_round, the shape's slots, every node
+active) and of one Jet iteration as the program runs it at the bucket
+(the same path at both widths: the gates read the bucket), each checked
+to give the same labels, partition and table at both widths.
 Past the gate the row also times the filter alone from the iteration's
 find step (jet._gated_rows_filter): as the program runs it
 (`narrow_filter_ms`: through _conn_slots where the candidates' rows fit
@@ -49,6 +57,8 @@ Usage: python scripts/microbench_csr_stream.py [--shapes coarse,fine,mesh]
     [--shapes fine,coarse,mesh,large] [--ks 2,16]
     python scripts/microbench_csr_stream.py --jet-iteration
     [--shapes large,fine,mesh] [--ks 2,16]
+    python scripts/microbench_csr_stream.py --level0-pad
+    [--shapes fine,large,mesh] [--ks 2]
 (TPU; a CPU run only proves the script runs.)  Writes
 chiprun_out/microbench_csr_stream.json.
 """
@@ -72,8 +82,10 @@ configure_compile_cache()
 import jax.numpy as jnp
 import numpy as np
 
+from kaminpar_tpu import caching
 from kaminpar_tpu.graphs.csr import device_graph_from_host
 from kaminpar_tpu.ops import jet
+from kaminpar_tpu.ops import lp
 from kaminpar_tpu.ops import segments as seg
 
 REPS = 5
@@ -93,7 +105,10 @@ SHAPES = {
 }
 # slots a pass of the scatter rating at a shape: the preset's 32, doubled
 # by the coarsener on rmat-s16's level 0 (average degree 26 > 16)
-SLOTS = {"coarse": 32, "fine": 64, "tiny": 32}
+SLOTS = {"coarse": 32, "fine": 64, "large": 64, "mesh": 32, "tiny": 32}
+# the chip's shape floors (graphs/csr.shape_floors), which the input
+# graph's eighth-octave bucket rounds over
+CHIP_FLOORS = (1 << 13, 1 << 20)
 CONN_DIVISORS = (4, 8, 16, 32)
 # iterations that settle the random start of --jet-iteration (the coarse
 # budget): the timed one then moves what an iteration of a refiner call
@@ -365,6 +380,65 @@ def jet_iteration_row(rng, graph, k):
     return row
 
 
+def level0_pad_row(rng, host, n_pad, m_pad, k, num_slots):
+    """ms of one scatter clustering round and one Jet iteration on the
+    same real graph at its power-of-two widths (n_pad, m_pad) and at the
+    input graph's eighth-octave widths; both answers compared."""
+    n, m = host.n, host.m
+    # the chip's floors, or the shape's own pads where those lie under
+    # them (the CPU rehearsal's tiny shape)
+    n_floor, m_floor = min(CHIP_FLOORS[0], n_pad), min(CHIP_FLOORS[1], m_pad)
+    widths = {"pow2": (n_pad, m_pad),
+              "eighth": (caching.input_pad_size(n + 1, n_floor),
+                         caching.input_pad_size(m, m_floor))}
+    graphs = {name: device_graph_from_host(host, n_pad=np_, m_pad=mp)
+              for name, (np_, mp) in widths.items()}
+    cfg = lp.LPConfig(rating="scatter", num_slots=num_slots)
+    cap = jnp.int32(max(1, int(host.node_weight_array().sum()) // 4096))
+    salt = jnp.int32(0x2545F491)
+
+    def cluster(g, labels, weights, active):
+        return lp.lp_round(g, labels, weights, cap, active, salt, cfg)
+
+    row = dict(op="level0_pad", k=k, n=n, m=m, num_slots=num_slots,
+               pow2=list(widths["pow2"]), eighth=list(widths["eighth"]))
+    settled = None
+    answers = {}
+    for name, g in graphs.items():
+        width = g.n_pad
+        labels = jnp.arange(width, dtype=jnp.int32)
+        args = (g, labels, g.node_w.astype(jnp.int32),
+                jnp.arange(width) < n)
+        row[f"lp_round_{name}_ms"] = timeit(cluster, *args)
+        new_labels = jax.jit(cluster)(*args)[0]
+
+        node_w = np.asarray(g.node_w)
+        caps = jnp.full(k, int(1.03 * np.ceil(node_w.sum() / k)), jnp.int32)
+        step = jet_step(g, k, caps, jet.DELTA_MIN_EDGE_SLOTS)
+        if settled is None:  # a partition settled on the first width
+            part = np.where(np.arange(width) < n,
+                            rng.integers(0, k, width), 0).astype(np.int32)
+            part, lock = jnp.asarray(part), jnp.zeros(width, jnp.int32)
+            conn = jet._full_ratings(g, part, k)
+            for i in range(SETTLE):
+                part, lock, _, conn, *_ = step(
+                    part, lock, conn, jnp.int32(12345 + 7919 * i))
+            settled = (np.asarray(part)[:n], np.asarray(lock)[:n])
+        part = jnp.asarray(np.pad(settled[0], (0, width - n)))
+        lock = jnp.asarray(np.pad(settled[1], (0, width - n)))
+        conn = jet._full_ratings(g, part, k)
+        jet_args = (part, lock, conn, jnp.int32(0x3C6EF372))
+        row[f"jet_iteration_{name}_ms"] = best_ms(step, *jet_args)
+        after, _, _, table, *_ = step(*jet_args)
+        row[f"jet_path_{name}"] = jet.iteration_path(g, k)
+        answers[name] = (np.asarray(new_labels)[:n], np.asarray(after)[:n],
+                         np.asarray(table)[:n])
+    row["same_answers"] = all(
+        np.array_equal(a, b)
+        for a, b in zip(answers["pow2"], answers["eighth"]))
+    return row
+
+
 def ints(text):
     return [int(x) for x in text.split(",") if x]
 
@@ -378,6 +452,7 @@ def main():
     parser.add_argument("--slots", action="store_true")
     parser.add_argument("--conn-delta", action="store_true")
     parser.add_argument("--jet-iteration", action="store_true")
+    parser.add_argument("--level0-pad", action="store_true")
     args = parser.parse_args()
     jet_rows = [fn for flag, fn in (("conn_delta", conn_delta_row),
                                     ("jet_iteration", jet_iteration_row))
@@ -389,9 +464,17 @@ def main():
     rng = np.random.default_rng(0)
     for name in args.shapes.split(","):
         n, m, n_pad, m_pad = SHAPES[name]
-        make = symmetric_skewed_graph if jet_rows else skewed_graph
-        graph = device_graph_from_host(make(rng, n, m), n_pad=n_pad,
-                                       m_pad=m_pad)
+        make = (symmetric_skewed_graph if jet_rows or args.level0_pad
+                else skewed_graph)
+        host = make(rng, n, m)
+        if args.level0_pad:
+            for k in args.ks:
+                row = dict(shape=name, **level0_pad_row(
+                    rng, host, n_pad, m_pad, k, SLOTS.get(name, 32)))
+                out["rows"].append(row)
+                print(json.dumps(row), flush=True)
+            continue
+        graph = device_graph_from_host(host, n_pad=n_pad, m_pad=m_pad)
         values = jnp.asarray(
             rng.integers(0, 2**31 - 1, n_pad).astype(np.int32))
         shape = {"shape": name, "n_pad": n_pad, "m_pad": m_pad, "m": m}

@@ -200,9 +200,10 @@ def test_shape_account_reads_zero_where_no_request_ran():
     account = RequestAccount(shape_account.counters)
     summary = account.summary()
     assert summary["by_request"] == {}
-    for counter in ("coarsening_levels", "conn_scatter_calls"):
+    for counter in shape_account.counters:
         assert summary["by_request"].get(summary["requests"], {}).get(
             counter, 0) == 0
     account.add({"coarsening_levels": 2})
     assert account.summary()["by_request"][compile_account.open_request()] \
-        == {"coarsening_levels": 2, "conn_scatter_calls": 0}
+        == {"coarsening_levels": 2, "conn_scatter_calls": 0,
+            "level0_edge_slots": 0, "level0_padded_edge_slots": 0}
